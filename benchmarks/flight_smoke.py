@@ -1,5 +1,7 @@
 """Training flight-recorder smoke: a straggler rank, caught and cleared.
 
+CPU functional test: JAX_PLATFORMS=cpu in parent and children; no chip number.
+
 The acceptance loop for the per-rank trainer telemetry plane, end to
 end over real subprocess ranks:
 
@@ -35,9 +37,7 @@ job, a rank is slowed (delay-ms flag → demote: protective checkpoint)
 then KILLED (kill flag → evict), and the elastic controller reshards
 onto dp=3 via reshard-on-restore — finishing with the loss-curve A-B
 guard green against an uninterrupted dp=4 twin and strictly fewer
-lost steps than the restart-from-checkpoint baseline. Needs
-vma-tracking jax (the train step); no-vma boxes record
-``skipped(env: no-vma)``.
+lost steps than the restart-from-checkpoint baseline.
 
   python -m benchmarks.flight_smoke             # recorder leg
   python -m benchmarks.flight_smoke --elastic   # elastic leg
@@ -80,7 +80,6 @@ def worker_main(argv) -> int:
     jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from hadoop_tpu.obs.comm import comm_runtime
@@ -109,8 +108,8 @@ def worker_main(argv) -> int:
         g = {"w": t["w"] @ t["w"].T * 1e-3, "b": t["b"] * 0.5}
         return bucketed_psum(g, axes, 1 << 20)
 
-    step = jax.jit(shard_map(body, mesh=mesh, in_specs=(P(),),
-                             out_specs=P()))
+    step = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                                 out_specs=P()))
     rt = comm_runtime()
     deadline = time.monotonic() + args.max_seconds
     while time.monotonic() < deadline and \
@@ -450,16 +449,9 @@ def _elastic_body() -> dict:
 
 def elastic_child_main() -> int:
     """Subprocess entry: force the 8-device CPU mesh BEFORE jax loads,
-    then run the elastic body (or record the no-vma skip)."""
+    then run the elastic body."""
     from __graft_entry__ import _force_cpu_devices
     _force_cpu_devices(8)
-    import jax
-    if not hasattr(jax, "typeof"):
-        # this box's jax cannot trace the multichip train step (see
-        # __graft_entry__.dryrun precedent): record the skip, stay green
-        print("ELASTIC_SMOKE " + json.dumps(
-            {"skipped": "env: no-vma", "ok": True}))
-        return 0
     print("ELASTIC_SMOKE " + json.dumps(_elastic_body()))
     return 0
 

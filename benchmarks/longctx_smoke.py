@@ -1,6 +1,8 @@
 """Long-context serving smoke: a prompt 8x one chip's KV budget, end
 to end through the real door.
 
+CPU functional test: JAX_PLATFORMS=cpu in parent and children; no chip number.
+
 Runs in a SUBPROCESS with an 8-virtual-device CPU mesh (the
 minicluster philosophy: real protocols, simulated fleet) so the parent
 bench process keeps its own device topology. The contract, all
@@ -349,7 +351,7 @@ def main(argv=None) -> int:
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args(argv)
     if args.child:
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"
         result = child(quick=args.quick)
     else:
         result = run(quick=args.quick)

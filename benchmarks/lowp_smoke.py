@@ -1,5 +1,7 @@
 """CPU-mesh relaxed-parity smoke: loss-curve A-B + comm-byte contract.
 
+CPU functional test: JAX_PLATFORMS=cpu in parent and children; no chip number.
+
 Runs (in a SUBPROCESS, so the 8-virtual-device XLA flags are set before
 jax initializes — same trick as overlap_smoke) the relaxed parity
 tier's acceptance ladder on the tiny config:

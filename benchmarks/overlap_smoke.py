@@ -1,5 +1,7 @@
 """CPU-mesh overlap smoke: A-B step parity + async-save blocking time.
 
+CPU functional test: JAX_PLATFORMS=cpu in parent and children; no chip number.
+
 Runs (in a SUBPROCESS, so the 8-virtual-device XLA flags are set before
 jax initializes — same trick as the multichip dryrun) a dp2×tp2 train
 step with the communication-overlap pass on and off and asserts the

@@ -1,5 +1,7 @@
 """Run every storage/compute benchmark and record STORAGE_BENCH.json.
 
+CPU functional test: JAX_PLATFORMS=cpu in parent and children; no chip number.
+
   python -m benchmarks.run_all [--out STORAGE_BENCH.json]
 """
 
@@ -217,7 +219,11 @@ def main() -> None:
                     help="smaller sizes for smoke runs")
     args = ap.parse_args()
 
+    import os
     import sys
+    # before any suite imports jax: every in-process arm and every
+    # child process stays on the CPU backend
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
     from benchmarks import dfsio, nn_throughput, rpc_bench, terasort_bench
 
@@ -396,8 +402,7 @@ def main() -> None:
     # evict onto the largest healthy sub-mesh (dp 4→3, non-power-of-two)
     # with reshard-on-restore — loss-curve A-B guard vs an uninterrupted
     # twin must ACCEPT and the elastic arm must lose strictly fewer
-    # steps than restart-from-checkpoint. On a no-vma jax the child
-    # records skipped(env: no-vma) and stays green. Recorded-not-raised.
+    # steps than restart-from-checkpoint. Recorded-not-raised.
     try:
         from benchmarks import flight_smoke
         out["flight_elastic"] = flight_smoke.run_elastic(

@@ -1,5 +1,7 @@
 """Offline serving benchmark: throughput + TTFT on synthetic traffic.
 
+CPU functional test: JAX_PLATFORMS=cpu in parent and children; no chip number.
+
 Drives the continuous-batching engine the way a replica would see load:
 N requests submitted up front, the scheduler admitting them into the
 fixed slot batch as pages free up, prefill proceeding in fixed-size
@@ -39,12 +41,10 @@ Two workload modes:
   single-chip reference match; CP guards + compile-once + hit-tier
   counters asserted, TTFT-by-chips recorded.
 
-Runs under JAX_PLATFORMS=cpu (tiny preset) or on real hardware with a
-bigger preset. JSON output matches the BENCH_*.json shape::
+JSON output matches the BENCH_*.json shape::
 
-    JAX_PLATFORMS=cpu python benchmarks/serve_bench.py
-    JAX_PLATFORMS=cpu python benchmarks/serve_bench.py --shared-prefix
-    python benchmarks/serve_bench.py --preset flagship-420m --requests 64
+    python benchmarks/serve_bench.py
+    python benchmarks/serve_bench.py --shared-prefix
 """
 
 from __future__ import annotations
@@ -1400,6 +1400,9 @@ def run_churn_smoke() -> dict:
 
 
 def main(argv=None) -> int:
+    # before any jax import: the parent and every replica child it
+    # spawns stay on the CPU backend
+    os.environ["JAX_PLATFORMS"] = "cpu"
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="tiny")
     # None = mode-dependent default: the mixed/shared-prefix modes keep

@@ -306,6 +306,7 @@ class _Pipeline:
         self.window = window            # max unacked packets (0 = no cap)
         self._unacked: "queue.Queue[int]" = queue.Queue()
         self._acked_through = -1
+        self._last_seq = -1
         self._ack_cond = threading.Condition()
         self._error: Optional[Exception] = None
         try:
@@ -368,6 +369,13 @@ class _Pipeline:
         with self._ack_cond:
             if self._error is not None:
                 raise self._error
+            if self._last_seq < 0:
+                # seqs are stream-global, acks per pipeline: a fresh
+                # pipeline has nothing outstanding, whatever seq the
+                # stream has reached (else every block past the first
+                # ``window`` packets of a file stalls before its first
+                # send)
+                self._acked_through = pkt.seq - 1
             # outstanding-ack window: run at most ``window`` packets
             # ahead of the last ack — deep enough to keep every hop's
             # pipe full, bounded so a wedged DN surfaces as a pipeline
@@ -389,7 +397,7 @@ class _Pipeline:
         """Ref: DataStreamer.waitForAckedSeqno:872."""
         deadline = time.monotonic() + self.ACK_TIMEOUT_S
         with self._ack_cond:
-            while self._acked_through < getattr(self, "_last_seq", -1):
+            while self._acked_through < self._last_seq:
                 if self._error is not None:
                     raise self._error
                 remaining = deadline - time.monotonic()

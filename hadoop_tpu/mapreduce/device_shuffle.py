@@ -91,7 +91,6 @@ def device_group_reduce(mesh, axis: str, keys: jax.Array,
                          capacity_factor=capacity_factor,
                          sort_output=True)
     from functools import partial
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from hadoop_tpu.parallel.collectives import _PROGRAM_CACHE
@@ -104,7 +103,7 @@ def device_group_reduce(mesh, axis: str, keys: jax.Array,
     prog = _PROGRAM_CACHE.get(ck)
     if prog is None:
         body = partial(_segment_reduce_sorted, op=op)
-        prog = _PROGRAM_CACHE.setdefault(ck, jax.jit(shard_map(
+        prog = _PROGRAM_CACHE.setdefault(ck, jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(spec, vspec, spec),
             out_specs=(spec, vspec, spec))))
     k, v, first = prog(res.keys, res.values, res.valid)

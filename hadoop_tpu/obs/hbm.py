@@ -25,9 +25,12 @@ one broken surface must not take down the whole ledger.
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from typing import Callable, Dict, Optional, Tuple
+
+log = logging.getLogger(__name__)
 
 # The bounded component label set. Unknown components map to "other" so
 # a registration can never mint an unbounded Prometheus series. Keep in
@@ -41,24 +44,31 @@ HBM_COMPONENTS = ("weights", "weights_dequantized", "moe_experts",
 def device_memory_stats() -> Optional[Dict]:
     """Backend-reported device memory, where available. Never imports
     jax into a process that has not already paid for it (a DataNode
-    scraping this ledger must stay light)."""
+    scraping this ledger must stay light). Advisory off the chip (the
+    CPU simulator reports nothing); on a TPU backend the platform and
+    ``bytes_in_use`` are what the serving door's health check reads,
+    so a failure there is logged, not swallowed."""
     import sys
     if "jax" not in sys.modules:
         return None
+    import jax
+    platform = None
     try:
-        import jax
         devs = jax.local_devices()
         if not devs:
             return None
+        platform = devs[0].platform
         stats = devs[0].memory_stats() or {}
-        out = {"platform": devs[0].platform}
-        for key in ("bytes_in_use", "bytes_limit", "peak_bytes_in_use"):
-            if key in stats:
-                out[key] = int(stats[key])
-        return out if len(out) > 1 else None
-    except Exception:  # noqa: BLE001 — stats are advisory; a backend
-        # without them (CPU sim) must not break the ledger
+    except Exception:  # noqa: BLE001 — the ledger must outlive a
+        # backend that cannot report; off the chip that is the norm
+        if platform == "tpu":
+            log.exception("device memory_stats() failed on a TPU backend")
         return None
+    out = {"platform": platform}
+    for key in ("bytes_in_use", "bytes_limit", "peak_bytes_in_use"):
+        if key in stats:
+            out[key] = int(stats[key])
+    return out if len(out) > 1 else None
 
 
 class HbmLedger:

@@ -3,7 +3,9 @@
 The portable path is a jnp softmax-attention that XLA maps onto the MXU;
 the fused Pallas flash kernel in ``hadoop_tpu.ops.flash`` is selected
 automatically on TPU backends for qualifying shapes (see
-``causal_attention``'s ``impl`` arg).
+``causal_attention``'s ``impl`` arg). Which of the two a call site got is
+recorded at trace time (``attention_impl_traces``), so a run that meant
+to use the kernel can prove it did.
 
 Ring attention (sequence/context parallelism over the mesh) builds on
 ``chunk_attention`` + ``merge_attention``: each partial result is the
@@ -14,10 +16,44 @@ See ``hadoop_tpu.parallel.ring_attention``.
 
 from __future__ import annotations
 
+import logging
+
 import jax
 import jax.numpy as jnp
 
+from hadoop_tpu.metrics import metrics_system
+
+log = logging.getLogger(__name__)
+
 _NEG_INF = -1e30
+
+
+def _impl_counters():
+    """The four ``htpu_attention_impl_traces_total{site,impl}`` counters
+    (label values from these literal tuples — the bounded-set contract)."""
+    reg = metrics_system().source("attention")
+    for site in ("causal", "ring"):
+        for impl in ("flash", "ref"):
+            reg.counter(f"{site}_{impl}_traces",
+                        "attention call sites traced per implementation",
+                        prom_name="attention_impl_traces",
+                        prom_labels={"site": site, "impl": impl})
+    return reg
+
+
+def record_attention_impl(site: str, impl: str, q_shape, k_shape) -> None:
+    """Trace-time record of which implementation a call site got: the
+    fused Pallas kernel (``flash``) or the jnp path (``ref``)."""
+    _impl_counters().counter(f"{site}_{impl}_traces").incr()
+    log.debug("attention[%s] -> %s (q=%s k=%s)", site, impl,
+              tuple(q_shape), tuple(k_shape))
+
+
+def attention_impl_traces() -> dict:
+    """``{"causal_flash": n, "causal_ref": n, "ring_flash": n,
+    "ring_ref": n}`` — call sites traced so far in this process."""
+    snap = _impl_counters().snapshot()
+    return {k[:-len("_traces")]: v for k, v in snap.items()}
 
 
 def _repeat_kv(k: jnp.ndarray, n_rep: int) -> jnp.ndarray:
@@ -53,10 +89,13 @@ def causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     "impl='flash' forced but the fused kernel does not "
                     f"support q={q.shape} k={k.shape} q_offset={q_offset} "
                     f"kv_offset={kv_offset} (offsets must be static 0)")
+            record_attention_impl("causal", "flash", q.shape, k.shape)
             return flash.flash_attention(q, k, v, scale)
         if jax.default_backend() not in ("cpu", "gpu") and \
                 flash.supported(q.shape, k.shape, q_offset, kv_offset):
+            record_attention_impl("causal", "flash", q.shape, k.shape)
             return flash.flash_attention(q, k, v, scale)
+    record_attention_impl("causal", "ref", q.shape, k.shape)
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
     k = _repeat_kv(k, hq // hkv)

@@ -21,16 +21,9 @@ def vma_of(x) -> frozenset:  # lint: static-fn — vma is trace-time metadata
 
 
 def pvary_to(x, axes):
-    """Make x varying over at least ``axes`` (adds only missing ones).
-
-    On jax builds without the vma system (``lax.pcast`` absent) there
-    is no varying-axis tracking to satisfy, so the cast degrades to
-    identity instead of an AttributeError — shard_map still places
-    values correctly, it just cannot enforce carry agreement."""
+    """Make x varying over at least ``axes`` (adds only missing ones)."""
     missing = tuple(sorted(set(axes) - vma_of(x)))
     if not missing:
-        return x
-    if not hasattr(jax.lax, "pcast"):
         return x
     return jax.lax.pcast(x, missing, to="varying")
 

@@ -37,7 +37,6 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -186,7 +185,7 @@ def device_shuffle(mesh: Mesh, axis: str, keys: jax.Array,
     vspec = P(axis, *([None] * (values.ndim - 1)))
 
     def build():
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             partial(_exchange_local, partition=partition, n_dev=n_dev,
                     cap=cap, pad_key=pad_key, axis=axis,
                     sort_output=sort_output),
@@ -234,8 +233,9 @@ def sample_split_points(mesh: Mesh, axis: str, keys: jax.Array,
     prog = _PROGRAM_CACHE.get(ck)
     if prog is None:
         prog = _PROGRAM_CACHE.setdefault(
-            ck, jax.jit(shard_map(body, mesh=mesh, in_specs=(P(axis),),
-                                  out_specs=P())))
+            ck, jax.jit(jax.shard_map(body, mesh=mesh,
+                                      in_specs=(P(axis),),
+                                      out_specs=P())))
     return prog(keys)
 
 

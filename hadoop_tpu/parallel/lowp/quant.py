@@ -217,16 +217,9 @@ def _pvary_ct(ct, axes):
 
     The straight-through backwards implement the VMA transpose
     convention (psum of a varying value transposes to the identity-
-    valued pvary). Pre-vma jax has no pcast AND transposes psum as
-    psum(ct) — a ×N mismatch — but it also cannot trace the train
-    step at all (out_specs replication inference fails, the seed
-    parallel suite's gap), so the only pre-vma consumers are the
-    verify harness's deliberately patched runs, whose valid-plan
-    caveats live in .claude/skills/verify/SKILL.md."""
-    if hasattr(jax, "typeof"):
-        from hadoop_tpu.ops.vma import pvary_to
-        return pvary_to(ct, axes)
-    return ct
+    valued pvary)."""
+    from hadoop_tpu.ops.vma import pvary_to
+    return pvary_to(ct, axes)
 
 
 def _straight_through(fwd_impl, bwd_fn, x):

@@ -19,7 +19,8 @@ import jax
 import jax.numpy as jnp
 
 from hadoop_tpu.ops.attention import (_repeat_kv, chunk_attention,
-                                      merge_attention)
+                                      merge_attention,
+                                      record_attention_impl)
 
 
 def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -52,6 +53,9 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     use_flash = impl == "flash" or (
         impl == "auto" and jax.default_backend() not in ("cpu", "gpu")
         and flash.partial_supported(q.shape, k.shape))
+
+    record_attention_impl("ring", "flash" if use_flash else "ref",
+                          q.shape, k.shape)
 
     from hadoop_tpu.ops.vma import pvary_to, vma_of
     target = vma_of(q) | vma_of(k) | vma_of(v) | {axis_name}

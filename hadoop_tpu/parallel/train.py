@@ -44,12 +44,6 @@ from hadoop_tpu.parallel.overlap import (DEFAULT_OVERLAP, OverlapConfig,
                                          bucketed_psum,
                                          bucketed_psum_scatter)
 
-try:  # stable name first, experimental fallback
-    _shard_map_fn = jax.shard_map  # type: ignore[attr-defined]
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_fn
-
-
 def _smap(f, mesh, in_specs, out_specs):
     # check_vma=True (the default) is load-bearing for correctness: the
     # varying-manual-axes tracking is what makes collective TRANSPOSES
@@ -59,7 +53,7 @@ def _smap(f, mesh, in_specs, out_specs):
     # of replicated params come out fully reduced over every axis whose
     # ranks see different data — the only manual step left is the
     # mean-vs-sum scaling (see make_train_step).
-    return _shard_map_fn(f, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs)
 
 

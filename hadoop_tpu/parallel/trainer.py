@@ -37,6 +37,7 @@ from hadoop_tpu.parallel.overlap import DEFAULT_OVERLAP, OverlapConfig
 from hadoop_tpu.parallel.train import (init_sharded, make_data_sharding,
                                        make_train_step, zero1_layout)
 from hadoop_tpu.parallel.optimizer import AdamWState
+from hadoop_tpu.util.jaxcache import configure_compile_cache
 
 log = logging.getLogger(__name__)
 
@@ -55,6 +56,9 @@ class Trainer:
                  async_ckpt: bool = True, rank: int = 0,
                  elastic: Optional[ElasticConfig] = None,
                  doctor_poll=None):
+        # before the first compile: the flagship step takes about a
+        # minute to build, once per machine with the persistent cache
+        configure_compile_cache()
         self.cfg, self.plan, self.fs = cfg, plan, fs
         self.ckpt_dir = ckpt_dir
         self.ckpt_interval = ckpt_interval
@@ -168,7 +172,10 @@ class Trainer:
         self.step_fn = make_train_step(
             self.cfg, plan, self.mesh, lr=kw["lr"],
             optimizer=kw["optimizer"], zero1=kw["zero1"],
-            remat=kw["remat"], donate=False,
+            # params/opt are donated (the step's default): at flagship
+            # width two copies of the ~10 GB state do not fit one chip,
+            # and self.params/self.opt are rebound to the outputs
+            remat=kw["remat"],
             n_microbatches=n_microbatches,
             pipeline_schedule=kw["pipeline_schedule"],
             overlap=kw["overlap"], parity=kw["parity"])

@@ -108,7 +108,6 @@ class ContextParallelPrefiller:
 
     def _build(self):
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from hadoop_tpu.models.decoder import (ParallelCtx, embed_tokens,
@@ -139,7 +138,7 @@ class ContextParallelPrefiller:
             # DATA, post-RoPE, exactly the engine's pool row layout
             return h[0], ks[:, 0], vs[:, 0]
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(P(), P("sp")),
             out_specs=(P("sp", None), P(None, "sp", None, None),

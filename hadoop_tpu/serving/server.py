@@ -184,6 +184,10 @@ class ServingServer:
             # /prom exposition is process-wide, so an in-process fleet
             # can only tell replicas apart through this door)
             "prefill_backlog": eng.prefill_backlog,
+            # traces of the two step shapes — each must stay <= 1 for
+            # the replica's lifetime (a third trace is a retracing bug)
+            "compiles": {"decode": eng.decode_compiles,
+                         "prefill": eng.prefill_compiles},
             # prefix-reuse cache + chunked-prefill observability: the
             # router and ops dashboards read hit_rate/cached_blocks here
             "prefix_cache": eng.cache_stats(),

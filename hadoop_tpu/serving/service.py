@@ -433,6 +433,10 @@ def replica_main(argv: List[str],
     if args["registry"]:
         host, _, port = str(args["registry"]).rpartition(":")
         registry_addr = (host or "127.0.0.1", int(port))
+    # a fresh replica compiles both step shapes; the persistent cache
+    # makes that a once-per-machine cost (util/jaxcache.py)
+    from hadoop_tpu.util.jaxcache import configure_compile_cache
+    configure_compile_cache()
     replica = ServingReplica(
         conf, name=str(args["name"]), checkpoint=str(args["checkpoint"]),
         preset=str(args["preset"]), registry_addr=registry_addr,

@@ -32,10 +32,6 @@ from hadoop_tpu.testing.minicluster import MiniDFSCluster
 
 BATCH = 8
 
-requires_vma = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="multichip train step needs jax vma tracking (jax.typeof)")
-
 
 @pytest.fixture(scope="module")
 def cluster():
@@ -302,7 +298,6 @@ def test_step_exception_not_masked_by_write_failure(fs, token_file):
     ffs.disarm()
 
 
-@requires_vma
 def test_interval_crash_resumes_bit_exact_with_inflight(fs, token_file):
     """Kill the ASYNC interval save's writer mid-write during train();
     the run must surface the failure at the train-exit fence, restore

@@ -43,10 +43,6 @@ from hadoop_tpu.parallel.elastic.reshard import (MANIFEST_FORMAT,
                                                  zero1_state_to_global)
 from hadoop_tpu.parallel.optimizer import AdamWState
 
-requires_vma = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="multichip train step needs jax vma tracking (jax.typeof)")
-
 
 # ---------------------------------------------------- reshard layout math
 
@@ -415,7 +411,6 @@ def test_elastic_from_conf():
 
 # ------------------------------------------------- trainer integration
 
-@requires_vma
 def test_trainer_same_plan_restore_bit_identical(tmp_path):
     from hadoop_tpu.models import get_config
     from hadoop_tpu.parallel.trainer import Trainer
@@ -441,7 +436,6 @@ def test_trainer_same_plan_restore_bit_identical(tmp_path):
     tr2.close()
 
 
-@requires_vma
 def test_trainer_reshard_restore_across_plans(tmp_path):
     from hadoop_tpu.models import get_config
     from hadoop_tpu.parallel.mesh import param_specs

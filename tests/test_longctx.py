@@ -6,11 +6,6 @@ working-set decode reproduces the single-chip ``decoder.forward``
 greedy tokens EXACTLY at small shapes — with the A-B guard rejecting a
 deliberately broken ring hop, every longctx shape compiling exactly
 once, and the engine's fused-step path untouched beside it.
-
-CP tests are capability-gated like the seed parallel suite: they skip
-when the shard_map context-parallel machinery is unavailable on the
-installed jax (the non-CP pieces — paging, validation, routing, the
-router capacity gate — run everywhere).
 """
 
 import jax
@@ -22,37 +17,6 @@ from hadoop_tpu.models.config import get_config
 from hadoop_tpu.models.decoder import forward, init_params
 from hadoop_tpu.serving.engine import DecodeEngine, SamplingParams
 from hadoop_tpu.serving.metrics import ServingMetrics
-
-
-def _cp_supported() -> bool:
-    """One 2-device ring probe: CP tests skip (not fail) on jax builds
-    where the shard_map machinery can't run — the same capability the
-    seed parallel suite depends on."""
-    try:
-        from functools import partial
-
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh, PartitionSpec as P
-
-        from hadoop_tpu.parallel.ring_attention import ring_attention
-        mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
-        q = jnp.ones((1, 4, 2, 4), jnp.float32)
-
-        @partial(shard_map, mesh=mesh,
-                 in_specs=(P(None, "sp"),) * 3, out_specs=P(None, "sp"))
-        def ring(q, k, v):
-            return ring_attention(q, k, v, "sp", 2)
-
-        np.asarray(ring(q, q, q))
-        return True
-    except Exception:  # noqa: BLE001 — any failure means "not on this
-        # jax"; the skip reason is the gate, not the traceback
-        return False
-
-
-cp_only = pytest.mark.skipif(not _cp_supported(),
-                             reason="shard_map CP machinery "
-                                    "unavailable on this jax build")
 
 
 @pytest.fixture(scope="module")
@@ -143,7 +107,6 @@ def test_choose_sp_mode_validates_and_falls_back(tiny_model):
 
 # ------------------------------------------------------ CP prefill parity
 
-@cp_only
 @pytest.mark.parametrize("sp,mode", [(4, "ring"), (2, "ulysses")])
 def test_cp_prefill_exact_match(tiny_model, sp, mode):
     """Small-shape A-B: CP last-token logits vs single-chip
@@ -160,7 +123,6 @@ def test_cp_prefill_exact_match(tiny_model, sp, mode):
     assert report["sp_mode"] == mode
 
 
-@cp_only
 def test_cp_prefill_pinned_shape_compiles_once(tiny_model):
     """Different prompt lengths ride ONE padded executable — the
     compile-once contract of the longctx plane."""
@@ -175,7 +137,6 @@ def test_cp_prefill_pinned_shape_compiles_once(tiny_model):
     assert pre.head_compiles == 1
 
 
-@cp_only
 def test_guard_rejects_broken_ring_hop(tiny_model, monkeypatch):
     """A deliberately corrupted ring hop (one rank's attention output
     scaled) must be REJECTED by the exact guard — the A-B machinery is
@@ -202,7 +163,6 @@ def test_guard_rejects_broken_ring_hop(tiny_model, monkeypatch):
 
 # ------------------------------------------------------------ end to end
 
-@cp_only
 def test_longctx_end_to_end_matches_single_chip(tiny_model):
     """The whole lane: submit through the ENGINE (routing seam), CP
     prefill, KV streamed to the host ring, working-set decode — greedy
@@ -235,7 +195,6 @@ def test_longctx_end_to_end_matches_single_chip(tiny_model):
         eng.stop()
 
 
-@cp_only
 def test_streamed_chain_feeds_the_radix_path(tiny_model):
     """Interop: a SHORT prompt that is a prefix of a served monster
     prompt maps the longctx-streamed chain through the normal radix
@@ -260,7 +219,6 @@ def test_streamed_chain_feeds_the_radix_path(tiny_model):
         eng.stop()
 
 
-@cp_only
 def test_short_prompts_keep_the_fused_step(tiny_model):
     """Routing seam: below min_tokens the request rides the fused step
     exactly as before (compile-once intact), at/above it the plane
@@ -288,7 +246,6 @@ def test_short_prompts_keep_the_fused_step(tiny_model):
         eng.stop()
 
 
-@cp_only
 def test_engine_drain_finishes_longctx_request(tiny_model):
     params, cfg = tiny_model
     eng = DecodeEngine(params, cfg, max_batch=2, block_size=8,
@@ -329,7 +286,6 @@ def _run_decoder(params, cfg, eng, prompt, res, sampling, **kw):
     return out, dec
 
 
-@cp_only
 def test_pipelined_decode_is_token_identical_to_legacy(tiny_model):
     """The fused path's A-B vs the pre-pipelining loop it replaced:
     same chain, same tail, same sampler stream — identical tokens,
@@ -376,7 +332,6 @@ def test_pipelined_decode_is_token_identical_to_legacy(tiny_model):
         eng.stop()
 
 
-@cp_only
 def test_fused_family_compiles_once_across_tokens(tiny_model):
     """Compile-once on the fused family: a multi-token paged decode —
     across two decoder INSTANCES and both samplers — traces each of
@@ -402,7 +357,6 @@ def test_fused_family_compiles_once_across_tokens(tiny_model):
         eng.stop()
 
 
-@cp_only
 def test_int8_longctx_serves_and_guard_accepts(tiny_model):
     """int8-resident CP weights: the plane serves straight off the
     quantized tree (no dequantized second copy), the weight A-B guard

@@ -35,25 +35,14 @@ from hadoop_tpu.parallel.lowp.quant import (RelaxedQuant, capture_comm,
                                             psum_quantized,
                                             psum_scatter_quantized)
 
-requires_vma = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="multichip train step needs jax vma tracking "
-           "(jax.typeof); same gap that fails the seed parallel suite "
-           "on this jax")
-
 
 def _mesh(n=4):
     return Mesh(np.array(jax.devices()[:n]), ("x",))
 
 
 def _smap(f, mesh, in_specs, out_specs):
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _rq(codec="int8", group=64):
@@ -862,7 +851,6 @@ def test_sync_schedule_refuses_pipeline_plans_and_missing_state():
 
 # ------------------------------------------------- full-step A-B (vma)
 
-@requires_vma
 def test_relaxed_dp2_tp2_passes_loss_curve_guard_50_steps():
     """Acceptance rung: quantized tp reduces + chunked collective
     matmul, 50 steps, bounded trajectory divergence."""
@@ -874,7 +862,6 @@ def test_relaxed_dp2_tp2_passes_loss_curve_guard_50_steps():
     assert rep["relaxed_final"] < rep["relaxed_first"]
 
 
-@requires_vma
 def test_relaxed_zero1_dp8_guard_and_comm_contract_50_steps():
     """Acceptance rung: quantized ZeRO-1 reassembly, 50 steps, with the
     ≥2× collective-payload-byte reduction the ledger proves."""
@@ -885,7 +872,6 @@ def test_relaxed_zero1_dp8_guard_and_comm_contract_50_steps():
     assert rep["comm"]["ratio"] >= 2.0
 
 
-@requires_vma
 def test_relaxed_pp_grad_buckets_comm_contract():
     """Quantized gradient buckets ride the manual-schedule reduce; the
     payload contract holds there too."""
@@ -896,7 +882,6 @@ def test_relaxed_pp_grad_buckets_comm_contract():
     assert rep["comm"]["ratio"] >= 2.0
 
 
-@requires_vma
 def test_bitwise_parity_is_byte_identical_to_parity_unset():
     """parallel.parity=bitwise must build EXACTLY the unset graph:
     identical losses and parameters, bit for bit."""
@@ -932,7 +917,6 @@ def test_bitwise_parity_is_byte_identical_to_parity_unset():
         np.testing.assert_array_equal(a, b, err_msg=str(pa))
 
 
-@requires_vma
 def test_sync_schedule_periodic2_guard_and_ledger_50_steps():
     """Acceptance rung: partially synchronized activations at
     periodic:2 on dp2×tp2+sp — the 50-step loss-curve guard must
@@ -966,7 +950,6 @@ def test_sync_schedule_periodic2_guard_and_ledger_50_steps():
     assert rep_sync["relaxed_final"] < rep_sync["relaxed_first"]
 
 
-@requires_vma
 def test_sync_schedule_all_skipped_rejects():
     """Falsifiability: a schedule that skips EVERY tp sync must be
     REJECTED by the loss-curve guard — otherwise the guard is not
@@ -981,7 +964,6 @@ def test_sync_schedule_all_skipped_rejects():
         f"max_rel_div={rep.get('max_rel_div')}")
 
 
-@requires_vma
 def test_sync_schedule_stale_mode_guard_50_steps():
     """The stale mode: scheduled-off layers consume the previous
     step's reduced correction instead of skipping outright — the
@@ -1000,7 +982,6 @@ def test_sync_schedule_stale_mode_guard_50_steps():
     assert rep["relaxed_final"] < rep["relaxed_first"]
 
 
-@requires_vma
 def test_bitwise_with_sync_conf_is_byte_identical_full_step():
     """A step built with parity=bitwise while the sync-schedule conf
     keys are set must be bit-identical to parity-unset — the schedule
@@ -1035,7 +1016,6 @@ def test_bitwise_with_sync_conf_is_byte_identical_full_step():
     assert out["unset"] == out["bitwise+sched"]
 
 
-@requires_vma
 def test_chunked_matmul_compiles_only_under_relaxed(monkeypatch):
     """A poisoned chunked_matmul_reduce: the bitwise step never touches
     it, the relaxed step hits it at trace time."""

@@ -50,6 +50,26 @@ def test_multi_block_file(cluster, fs):
     assert len(locs["blocks"]) == 4
 
 
+def test_file_longer_than_the_ack_window_crosses_blocks(cluster):
+    """Packet seqs are stream-global but each block's pipeline counts
+    its own acks: a block that starts after the stream's first
+    ``max-packets-in-flight`` packets must still send (it used to stall
+    before its first packet — any file past 64 MB, e.g. a flagship
+    checkpoint shard)."""
+    from hadoop_tpu.dfs.client.filesystem import DistributedFileSystem
+    conf = Configuration(other=cluster.conf)
+    conf.set("dfs.client.write.max-packets-in-flight", "2")
+    wfs = DistributedFileSystem([cluster.nn_addr], conf)
+    try:
+        data = os.urandom(3 * 1024 * 1024 + 17)   # 4 blocks, 8 seqs
+        t0 = time.monotonic()
+        wfs.write_all("/window.bin", data)
+        assert time.monotonic() - t0 < 5.0         # no ack-timeout stall
+        assert wfs.read_all("/window.bin") == data
+    finally:
+        wfs.close()
+
+
 def test_replication_factor_honored(cluster, fs):
     with fs.create("/rep.bin", replication=2) as out:
         out.write(b"hello replication")

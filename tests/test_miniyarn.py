@@ -125,6 +125,28 @@ def test_tpu_chip_isolation(cluster, yc, tmp_path):
         assert len(seen) == 4
 
 
+def test_chipless_container_is_pinned_to_cpu(cluster, yc, monkeypatch):
+    """A chip belongs to one process: a container granted no chips is
+    launched with JAX_PLATFORMS=cpu (and none of the retired plug-in's
+    pool variables), so initializing JAX there cannot take the device
+    from the container that was granted it."""
+    launched = []
+    for nm in cluster.node_agents:
+        def spy(workdir, commands, env, _real=nm.executor.launch):
+            launched.append(dict(env))
+            return _real(workdir, commands, env)
+        monkeypatch.setattr(nm.executor, "launch", spy)
+    app_id = submit(cluster.rm_addr, ["bash", "-c", "true"], n=1,
+                    conf=Configuration(other=cluster.conf))
+    report = yc.wait_for_completion(app_id, timeout=60)
+    assert report.state == AppState.FINISHED, report.diagnostics
+    assert launched
+    for env in launched:
+        assert "HTPU_TPU_CHIPS" not in env
+        assert env.get("JAX_PLATFORMS") == "cpu"
+        assert not any("POOL_IPS" in key for key in env)
+
+
 def test_rm_restart_recovers_finished_state(cluster, yc, tmp_path):
     marker = str(tmp_path / "done")
     app_id = submit(cluster.rm_addr, ["bash", "-c", f"touch {marker}"], n=1,

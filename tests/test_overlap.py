@@ -28,27 +28,16 @@ from hadoop_tpu.parallel.overlap import (DEFAULT_OVERLAP, OVERLAP_OFF,
                                          overlap_from_conf,
                                          zero1_slice_meta)
 
-requires_vma = pytest.mark.skipif(
-    not hasattr(jax, "typeof"),
-    reason="multichip train step needs jax vma tracking "
-           "(jax.typeof); same gap that fails the seed parallel suite "
-           "on this jax")
-
 
 def _mesh(n=4):
     return Mesh(np.array(jax.devices()[:n]), ("x",))
 
 
 def _smap(f, mesh, in_specs, out_specs):
-    """Version-portable shard_map with replication checking off (the
-    primitive tests assert numerics, not spec inference)."""
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    """shard_map with vma checking off (the primitive tests assert
+    numerics, not spec inference)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # --------------------------------------------------------------- packing
@@ -278,26 +267,22 @@ def _assert_ab_bitexact(out):
         np.testing.assert_array_equal(a, b, err_msg=str(pa))
 
 
-@requires_vma
 def test_overlap_parity_dp2():
     from hadoop_tpu.parallel import MeshPlan
     _assert_ab_bitexact(_run_plan_ab(MeshPlan(dp=2)))
 
 
-@requires_vma
 def test_overlap_parity_dp2_tp2():
     from hadoop_tpu.parallel import MeshPlan
     _assert_ab_bitexact(_run_plan_ab(
         MeshPlan(dp=2, tp=2, megatron_sp=True)))
 
 
-@requires_vma
 def test_overlap_parity_zero1_dp8():
     from hadoop_tpu.parallel import MeshPlan
     _assert_ab_bitexact(_run_plan_ab(MeshPlan(dp=8), zero1=True))
 
 
-@requires_vma
 def test_overlap_zero1_manual_schedule_close():
     """zero1 under the manual 1F1B schedule reduce-scatters the grads;
     slice values are bitwise but the grad-NORM accumulates slice-wise,
