@@ -194,6 +194,7 @@ def _relaxed_qready(w, ctx: ParallelCtx) -> bool:
 
 # -------------------------------------------------------------- attention
 
+@jax.named_scope("attn")
 def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
                      return_kv: bool = False, relaxed_sync=None):
     """Pre-norm attention with residual. x: [B, S_local, D].
@@ -274,6 +275,7 @@ def _attention_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
 
 # -------------------------------------------------------------------- mlp
 
+@jax.named_scope("mlp")
 def _mlp_block(x, lp, cfg: ModelConfig, ctx: ParallelCtx,
                relaxed_sync=None):
     from hadoop_tpu.ops.collective_matmul import (reduce_row_parallel,
@@ -501,6 +503,7 @@ def run_layers(x, layers, cfg: ModelConfig, ctx: ParallelCtx, cos, sin,
 
 # ------------------------------------------------------------- embeddings
 
+@jax.named_scope("embed")
 def embed_tokens(params, tokens, cfg: ModelConfig, ctx: ParallelCtx):
     """Token (+ position) embedding; vocab-parallel under tp.
 

@@ -79,6 +79,7 @@ def _expert_ffn(xe: jnp.ndarray, lp, cfg: ModelConfig) -> jnp.ndarray:
     return jnp.einsum("ecf,efd->ecd", swiglu(gate, up), lp["w_down"])
 
 
+@jax.named_scope("moe")
 def moe_mlp(h: jnp.ndarray, lp, cfg: ModelConfig, ctx) -> jnp.ndarray:
     """Routed MLP. h: [B, S, D] (full sequence). Returns [B, S, D] —
     a *partial* sum over tp when expert weights are ff-sharded (caller

@@ -179,6 +179,7 @@ def _fwd(q, k, v, scale, block_q, block_k, interpret, causal=True):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -336,6 +337,7 @@ def _bwd(scale, block_q, block_k, interpret, residuals, g):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     if n_rep > 1:
         # Sum the replication group in float32 — the kernel kept f32
@@ -377,6 +379,7 @@ def _bwd(scale, block_q, block_k, interpret, residuals, g):
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

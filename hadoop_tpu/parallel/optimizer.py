@@ -40,6 +40,7 @@ def adamw_init(params) -> AdamWState:
                       jax.tree_util.tree_map(jnp.copy, zeros))
 
 
+@jax.named_scope("optimizer")
 def adamw_update(params, grads, state: AdamWState, lr: float,
                  b1: float = 0.9, b2: float = 0.95, eps: float = 1e-8,
                  weight_decay: float = 0.1, grad_clip: float = 1.0,
@@ -113,6 +114,7 @@ def zero1_init_local(local_shape, z: int):
     return jnp.zeros((k,), jnp.float32)
 
 
+@jax.named_scope("optimizer")
 def zero1_update(params, grads, state: AdamWState, lr: float, *,
                  leaf_axes, mesh_axis_sizes: Dict[str, int],
                  b1: float = 0.9, b2: float = 0.95, eps: float = 1e-8,

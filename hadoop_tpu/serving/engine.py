@@ -165,11 +165,19 @@ from hadoop_tpu.serving.weightplane import (EXPERT_STACKS, describe_tree,
                                             expert_weight_bytes,
                                             is_qtensor, is_quantized_tree,
                                             qdot, qedot, qhead, qrows)
-from hadoop_tpu.tracing.tracer import global_tracer
+from hadoop_tpu.tracing.tracer import global_tracer, phase
 
 log = logging.getLogger(__name__)
 
 _NEG_INF = -1e30
+
+# the phases of one scheduler iteration. They TILE it — nothing encloses
+# them — so that on a profiler trace each idle gap of the device falls in
+# exactly one (tracing.tracer.phase). ``engine.wait`` is the run loop's
+# park while there is nothing to do; the rest is ``step()``.
+PHASES = ("engine.wait", "engine.admit", "engine.propose", "engine.pages",
+          "engine.dispatch", "engine.readback", "engine.deliver",
+          "engine.publish")
 
 
 def _shard_expert_stacks(params, shards: int):
@@ -285,6 +293,12 @@ class GenRequest:
     error: Optional[str] = None
     submitted_at: float = field(default_factory=time.monotonic)
     first_token_at: Optional[float] = None
+    # the TTFT timeline (time.monotonic, each set once — a request
+    # preempted before its first token keeps its first stamps):
+    # submitted → admitted (a lane and pages) → first chunk (just before
+    # the step call that carries it) → first token
+    admitted_at: Optional[float] = None
+    first_chunk_at: Optional[float] = None
     # auth identity for door QoS: the fair admission queue orders
     # pending requests by the tenant's decayed usage share
     tenant: str = ""
@@ -568,6 +582,13 @@ class DecodeEngine:
         self.steps = 0
         self.tokens_generated = 0
         self.occupancy_log: List[int] = []      # active slots per step
+        # the loop's own time (PHASES): cumulative seconds per phase
+        # (monotone)
+        self.phase_s: Dict[str, float] = {}
+        self._phase_published: Dict[str, float] = {}    # to the counters
+        # (start, engine.wait so far, compiles so far) of the last step()
+        # that ran a device step: _log_iteration
+        self._iter_prev = None
         self._fused_compiles = 0                # [B + chunk]-row traces
         self._decode_only_compiles = 0          # [B]-row traces
         self._chunk_fill = 0                    # chunk rows used last step
@@ -656,6 +677,7 @@ class DecodeEngine:
         return self._wdot(gelu(self._wdot(x, lp["w_in"]) + lp["b_in"]),
                           lp["w_out"]) + lp["b_out"]
 
+    @jax.named_scope("moe")
     def _moe_mlp(self, x, lp):
         """Routed expert MLP inside the ONE fused step. The full row
         batch ``x [T, D]`` (decode lanes + any riding prefill chunk)
@@ -773,15 +795,16 @@ class DecodeEngine:
 
         hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         cos, sin = self._rope_tables()
-        if self._relaxed_weights and self._q_embed:
-            # quantized embedding gather (policy-selectable; norms and
-            # pos_embed never quantize)
-            h = qrows(params["embed"], tokens, cfg.jax_dtype)
-        else:
-            h = params["embed"][tokens]
-        if not cfg.use_rope:
-            h = h + params["pos_embed"][
-                jnp.clip(pos, 0, cfg.max_seq - 1)]
+        with jax.named_scope("embed"):
+            if self._relaxed_weights and self._q_embed:
+                # quantized embedding gather (policy-selectable; norms
+                # and pos_embed never quantize)
+                h = qrows(params["embed"], tokens, cfg.jax_dtype)
+            else:
+                h = params["embed"][tokens]
+            if not cfg.use_rope:
+                h = h + params["pos_embed"][
+                    jnp.clip(pos, 0, cfg.max_seq - 1)]
         blk = jnp.take_along_axis(
             tables, (pos // self.block_size)[:, None], axis=1)[:, 0]
         blk = jnp.where(active, blk, BlockPool.SCRATCH)
@@ -789,34 +812,43 @@ class DecodeEngine:
         scale = 1.0 / (dh ** 0.5)
         kpos = jnp.arange(self.s_max)
 
+        # the scopes below are the step's stable names on the device
+        # trace (metadata only: the compiled program is the same)
         def layer(h, xs):
             lp, kc, vc = xs
-            x = _norm(h, lp["attn_norm_w"], lp.get("attn_norm_b"), cfg)
-            q = self._wdot(x, lp["wq"]).reshape(t, hq, dh)
-            k = self._wdot(x, lp["wk"]).reshape(t, hkv, dh)
-            v = self._wdot(x, lp["wv"]).reshape(t, hkv, dh)
-            if cfg.use_rope:
-                q = _rope_at(q, cos, sin, pos)
-                k = _rope_at(k, cos, sin, pos)
-            kc = kc.at[blk, off].set(k.astype(kc.dtype))
-            vc = vc.at[blk, off].set(v.astype(vc.dtype))
-            # paged gather: each row pulls its own pages back into a
-            # contiguous [S_max] context view through the block table
-            kctx = kc[tables].reshape(t, self.s_max, hkv, dh)
-            vctx = vc[tables].reshape(t, self.s_max, hkv, dh)
-            kr = _repeat_kv(kctx, hq // hkv)
-            vr = _repeat_kv(vctx, hq // hkv)
-            logits = jnp.einsum(
-                "bhd,bkhd->bhk", q, kr,
-                preferred_element_type=jnp.float32) * scale
-            mask = kpos[None, :] <= pos[:, None]
-            logits = jnp.where(mask[:, None, :], logits, _NEG_INF)
-            probs = jax.nn.softmax(logits, axis=-1).astype(vr.dtype)
-            attn = jnp.einsum("bhk,bkhd->bhd", probs, vr)
-            h2 = h + self._wdot(attn.reshape(t, hq * dh),
-                                lp["wo"]).astype(h.dtype)
-            x2 = _norm(h2, lp["mlp_norm_w"], lp.get("mlp_norm_b"), cfg)
-            return h2 + self._mlp(x2, lp).astype(h.dtype), (kc, vc)
+            with jax.named_scope("attn_proj"):
+                x = _norm(h, lp["attn_norm_w"], lp.get("attn_norm_b"), cfg)
+                q = self._wdot(x, lp["wq"]).reshape(t, hq, dh)
+                k = self._wdot(x, lp["wk"]).reshape(t, hkv, dh)
+                v = self._wdot(x, lp["wv"]).reshape(t, hkv, dh)
+                if cfg.use_rope:
+                    q = _rope_at(q, cos, sin, pos)
+                    k = _rope_at(k, cos, sin, pos)
+            with jax.named_scope("kv_update"):
+                kc = kc.at[blk, off].set(k.astype(kc.dtype))
+                vc = vc.at[blk, off].set(v.astype(vc.dtype))
+            with jax.named_scope("kv_gather"):
+                # paged gather: each row pulls its own pages back into a
+                # contiguous [S_max] context view through the block table
+                kctx = kc[tables].reshape(t, self.s_max, hkv, dh)
+                vctx = vc[tables].reshape(t, self.s_max, hkv, dh)
+                kr = _repeat_kv(kctx, hq // hkv)
+                vr = _repeat_kv(vctx, hq // hkv)
+            with jax.named_scope("attn"):
+                logits = jnp.einsum(
+                    "bhd,bkhd->bhk", q, kr,
+                    preferred_element_type=jnp.float32) * scale
+                mask = kpos[None, :] <= pos[:, None]
+                logits = jnp.where(mask[:, None, :], logits, _NEG_INF)
+                probs = jax.nn.softmax(logits, axis=-1).astype(vr.dtype)
+                attn = jnp.einsum("bhk,bkhd->bhd", probs, vr)
+            with jax.named_scope("attn_proj"):
+                h2 = h + self._wdot(attn.reshape(t, hq * dh),
+                                    lp["wo"]).astype(h.dtype)
+            with jax.named_scope("mlp"):
+                x2 = _norm(h2, lp["mlp_norm_w"], lp.get("mlp_norm_b"),
+                           cfg)
+                return h2 + self._mlp(x2, lp).astype(h.dtype), (kc, vc)
 
         # comm_scale: the trace-time comm ledgers see one body trace of
         # the scan; the hardware runs it n_layers times per step — the
@@ -825,116 +857,117 @@ class DecodeEngine:
         with comm_scale(cfg.n_layers):
             h, (kp, vp) = jax.lax.scan(layer, h,
                                        (params["layers"], kp, vp))
-        h = _norm(h, params["final_norm_w"], params.get("final_norm_b"),
-                  cfg)
-        if self._relaxed_weights and self._q_head:
-            logits = qhead(params, h, cfg).astype(jnp.float32)
-        else:
-            logits = (h @ head_matrix(params, cfg, h.dtype)).astype(
-                jnp.float32)
+        with jax.named_scope("head_sample"):
+            h = _norm(h, params["final_norm_w"], params.get("final_norm_b"),
+                      cfg)
+            if self._relaxed_weights and self._q_head:
+                logits = qhead(params, h, cfg).astype(jnp.float32)
+            else:
+                logits = (h @ head_matrix(params, cfg, h.dtype)).astype(
+                    jnp.float32)
 
-        # ---- sample + verify (the key derives from the carried seed:
-        # identical to the old host-side PRNGKey(step_counter))
-        key = jax.random.PRNGKey(state["seed"])
-        c_first = None
-        if S == 0:
-            # no speculation: one sample per row, bitwise the
-            # pre-speculation engine (same _sample over the same rows
-            # with the same key)
-            sampled = _sample(logits, temps, topks, key)
-            out = sampled[:B][:, None]                      # [B, 1]
-            accept = jnp.zeros((B,), jnp.int32)
-            if chunk is not None:
-                c_first = sampled[B * G + c_n - 1]
-        else:
-            ku, kr_, kc_ = jax.random.split(key, 3)
-            dec_logits = logits[:B * G].reshape(B, G, -1)
-            V = dec_logits.shape[-1]
-            greedy_tok = jnp.argmax(dec_logits, axis=-1).astype(
-                jnp.int32)                                  # [B, G]
-            # target distribution per row: the exact _sample transform
-            # (top-k mask, temperature) in probability space
-            row_top = jnp.broadcast_to(topks_s[:, None], (B, G))
-            row_tmp = jnp.broadcast_to(temps_s[:, None], (B, G))
-            scaled = _mask_and_scale(dec_logits, row_tmp, row_top)
-            probs = jax.nn.softmax(scaled, axis=-1)         # [B, G, V]
-            # acceptance: greedy lanes by argmax equality; sampled
-            # lanes by rejection sampling — the n-gram draft is a point
-            # mass, so accept iff u < p_target(draft)
-            u = jax.random.uniform(ku, (B, S))
-            p_draft = jnp.take_along_axis(
-                probs[:, :S], drafts[..., None], axis=2)[..., 0]
-            greedy_lane = temps_s <= 0
-            ok = jnp.where(greedy_lane[:, None],
-                           drafts == greedy_tok[:, :S], u < p_draft)
-            ok = ok & (jnp.arange(S)[None, :] < draft_lens[:, None])
-            accept = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1),
-                             axis=1)                        # [B] 0..S
-            # the bonus token at group index `accept`: greedy lanes
-            # take the argmax; sampled lanes draw from the target with
-            # a rejected draft token removed and renormalized (exact
-            # speculative sampling — all-accepted lanes sample the
-            # unmodified target)
-            p_a = jnp.take_along_axis(
-                probs, jnp.broadcast_to(accept[:, None, None],
-                                        (B, 1, V)), axis=1)[:, 0]
-            g_a = jnp.take_along_axis(greedy_tok, accept[:, None],
-                                      axis=1)[:, 0]
-            rejected = accept < draft_lens
-            d_a = jnp.take_along_axis(
-                drafts, jnp.minimum(accept, S - 1)[:, None],
-                axis=1)[:, 0]
-            adj = jnp.where(rejected[:, None] &
-                            (jnp.arange(V)[None, :] == d_a[:, None]),
-                            0.0, p_a)
-            adj = adj / jnp.maximum(adj.sum(-1, keepdims=True), 1e-30)
-            samp_a = jax.random.categorical(
-                kr_, jnp.log(jnp.maximum(adj, 1e-38)),
-                axis=-1).astype(jnp.int32)
-            final = jnp.where(greedy_lane, g_a, samp_a)     # [B]
-            draft_pad = jnp.concatenate(
-                [drafts, jnp.zeros((B, 1), jnp.int32)], axis=1)
-            out = jnp.where(gj[None, :] < accept[:, None],
-                            draft_pad, final[:, None])      # [B, G]
-            if chunk is not None:
-                c_sampled = _sample(logits[B * G:], temps[B * G:],
-                                    topks[B * G:], kc_)
-                c_first = c_sampled[c_n - 1]
+            # ---- sample + verify (the key derives from the carried seed:
+            # identical to the old host-side PRNGKey(step_counter))
+            key = jax.random.PRNGKey(state["seed"])
+            c_first = None
+            if S == 0:
+                # no speculation: one sample per row, bitwise the
+                # pre-speculation engine (same _sample over the same rows
+                # with the same key)
+                sampled = _sample(logits, temps, topks, key)
+                out = sampled[:B][:, None]                      # [B, 1]
+                accept = jnp.zeros((B,), jnp.int32)
+                if chunk is not None:
+                    c_first = sampled[B * G + c_n - 1]
+            else:
+                ku, kr_, kc_ = jax.random.split(key, 3)
+                dec_logits = logits[:B * G].reshape(B, G, -1)
+                V = dec_logits.shape[-1]
+                greedy_tok = jnp.argmax(dec_logits, axis=-1).astype(
+                    jnp.int32)                                  # [B, G]
+                # target distribution per row: the exact _sample transform
+                # (top-k mask, temperature) in probability space
+                row_top = jnp.broadcast_to(topks_s[:, None], (B, G))
+                row_tmp = jnp.broadcast_to(temps_s[:, None], (B, G))
+                scaled = _mask_and_scale(dec_logits, row_tmp, row_top)
+                probs = jax.nn.softmax(scaled, axis=-1)         # [B, G, V]
+                # acceptance: greedy lanes by argmax equality; sampled
+                # lanes by rejection sampling — the n-gram draft is a point
+                # mass, so accept iff u < p_target(draft)
+                u = jax.random.uniform(ku, (B, S))
+                p_draft = jnp.take_along_axis(
+                    probs[:, :S], drafts[..., None], axis=2)[..., 0]
+                greedy_lane = temps_s <= 0
+                ok = jnp.where(greedy_lane[:, None],
+                               drafts == greedy_tok[:, :S], u < p_draft)
+                ok = ok & (jnp.arange(S)[None, :] < draft_lens[:, None])
+                accept = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1),
+                                 axis=1)                        # [B] 0..S
+                # the bonus token at group index `accept`: greedy lanes
+                # take the argmax; sampled lanes draw from the target with
+                # a rejected draft token removed and renormalized (exact
+                # speculative sampling — all-accepted lanes sample the
+                # unmodified target)
+                p_a = jnp.take_along_axis(
+                    probs, jnp.broadcast_to(accept[:, None, None],
+                                            (B, 1, V)), axis=1)[:, 0]
+                g_a = jnp.take_along_axis(greedy_tok, accept[:, None],
+                                          axis=1)[:, 0]
+                rejected = accept < draft_lens
+                d_a = jnp.take_along_axis(
+                    drafts, jnp.minimum(accept, S - 1)[:, None],
+                    axis=1)[:, 0]
+                adj = jnp.where(rejected[:, None] &
+                                (jnp.arange(V)[None, :] == d_a[:, None]),
+                                0.0, p_a)
+                adj = adj / jnp.maximum(adj.sum(-1, keepdims=True), 1e-30)
+                samp_a = jax.random.categorical(
+                    kr_, jnp.log(jnp.maximum(adj, 1e-38)),
+                    axis=-1).astype(jnp.int32)
+                final = jnp.where(greedy_lane, g_a, samp_a)     # [B]
+                draft_pad = jnp.concatenate(
+                    [drafts, jnp.zeros((B, 1), jnp.int32)], axis=1)
+                out = jnp.where(gj[None, :] < accept[:, None],
+                                draft_pad, final[:, None])      # [B, G]
+                if chunk is not None:
+                    c_sampled = _sample(logits[B * G:], temps[B * G:],
+                                        topks[B * G:], kc_)
+                    c_first = c_sampled[c_n - 1]
 
-        # ---- in-graph stop-condition scan: budget clamp, stop_token
-        # cut, lane retirement — the host reads the verdict, it does
-        # not compute it
-        remaining = jnp.maximum(maxn - outc, 0)
-        n_emit = jnp.minimum(accept + 1, remaining)
-        has_stop = stopt >= 0
-        stop_hits = (out == stopt[:, None]) & has_stop[:, None]
-        first_stop = jnp.min(
-            jnp.where(stop_hits, gj[None, :], G + 1), axis=1)
-        n_emit = jnp.minimum(n_emit, first_stop + 1)
-        n_emit = jnp.where(active_s, n_emit, 0)
-        stop_hit = first_stop < n_emit
-        finished = active_s & ((outc + n_emit >= maxn) | stop_hit)
-        last_idx = jnp.maximum(n_emit - 1, 0)
-        new_last = jnp.where(
-            active_s,
-            jnp.take_along_axis(out, last_idx[:, None], axis=1)[:, 0],
-            state["last"])
-        new_state = {
-            "tables": tables_s,
-            "positions": positions_s + n_emit,
-            "last": new_last,
-            "active": active_s & ~finished,
-            "temps": temps_s,
-            "topks": topks_s,
-            "outc": outc + n_emit,
-            "maxn": maxn,
-            "stopt": stopt,
-            "seed": state["seed"] + 1,
-        }
-        packed = jnp.concatenate(
-            [out, n_emit[:, None], finished.astype(jnp.int32)[:, None],
-             accept[:, None]],
-            axis=1)                                         # [B, G + 3]
+            # ---- in-graph stop-condition scan: budget clamp, stop_token
+            # cut, lane retirement — the host reads the verdict, it does
+            # not compute it
+            remaining = jnp.maximum(maxn - outc, 0)
+            n_emit = jnp.minimum(accept + 1, remaining)
+            has_stop = stopt >= 0
+            stop_hits = (out == stopt[:, None]) & has_stop[:, None]
+            first_stop = jnp.min(
+                jnp.where(stop_hits, gj[None, :], G + 1), axis=1)
+            n_emit = jnp.minimum(n_emit, first_stop + 1)
+            n_emit = jnp.where(active_s, n_emit, 0)
+            stop_hit = first_stop < n_emit
+            finished = active_s & ((outc + n_emit >= maxn) | stop_hit)
+            last_idx = jnp.maximum(n_emit - 1, 0)
+            new_last = jnp.where(
+                active_s,
+                jnp.take_along_axis(out, last_idx[:, None], axis=1)[:, 0],
+                state["last"])
+            new_state = {
+                "tables": tables_s,
+                "positions": positions_s + n_emit,
+                "last": new_last,
+                "active": active_s & ~finished,
+                "temps": temps_s,
+                "topks": topks_s,
+                "outc": outc + n_emit,
+                "maxn": maxn,
+                "stopt": stopt,
+                "seed": state["seed"] + 1,
+            }
+            packed = jnp.concatenate(
+                [out, n_emit[:, None], finished.astype(jnp.int32)[:, None],
+                 accept[:, None]],
+                axis=1)                                         # [B, G + 3]
         if chunk is None:
             return kp, vp, new_state, packed
         return kp, vp, new_state, packed, c_first
@@ -1108,12 +1141,40 @@ class DecodeEngine:
         step, retire finished requests. Returns the number of tokens
         emitted."""
         with self._sched_lock:
-            self._admit()
-            self._propose_drafts()
-            self._ensure_blocks()
+            began = (time.monotonic(),
+                     self.phase_s.get("engine.wait", 0.0),
+                     self._decode_only_compiles + self._fused_compiles)
+            steps = self.steps
+            with self._phase("engine.admit"):
+                self._admit()
+            if self.spec_k:
+                with self._phase("engine.propose"):
+                    self._propose_drafts()
+            with self._phase("engine.pages"):
+                self._ensure_blocks()
             emitted = self._run_step()
-            self._publish_metrics()
+            if self.steps != steps:
+                self._log_iteration(began)
+            with self._phase("engine.publish"):
+                self._publish_metrics()
             return emitted
+
+    def _phase(self, name: str) -> phase:
+        return phase(name, self.phase_s)
+
+    def _log_iteration(self, began) -> None:
+        """``began`` opens an iteration that ran a device step and closes
+        the one before it: the seconds from that one's start to this
+        one's, less ``engine.wait`` in between (an idle engine is not
+        stalled; a hole between two steps of a busy one is in here,
+        where ``decode_step`` cannot see it). An iteration in which a
+        step shape compiled is left out: the compile counters hold it."""
+        prev, self._iter_prev = self._iter_prev, began
+        if prev is None or not self.metrics:
+            return
+        (t0, waited0, compiled0), (t1, waited1, compiled1) = prev, began
+        if compiled0 == compiled1:
+            self.metrics.iteration_hist.add(t1 - t0 - (waited1 - waited0))
 
     def _propose_drafts(self) -> None:
         """Fill the per-lane draft buffers from each running request's
@@ -1264,8 +1325,12 @@ class DecodeEngine:
         # (table row, sampling params, budget, stop token) lands on
         # device ONCE here; the compiled step carries it from now on
         self._push_slot(slot, req)
+        if req.admitted_at is None:
+            req.admitted_at = time.monotonic()
         sp = self.tracer.span("serving.admit", parent=req.trace_ctx)
         sp.add_kv("request", str(req.id))
+        sp.add_kv("queue_wait_s",
+                  f"{req.admitted_at - req.submitted_at:.6f}")
         sp.add_kv("prompt_tokens", str(len(ctx)))
         sp.add_kv("prefix_tokens_reused", str(req.prefix_tokens_reused))
         sp.finish()
@@ -1428,47 +1493,61 @@ class DecodeEngine:
         self._push_slot(slot, None)    # release event: clear the lane
 
     def _run_step(self) -> int:
-        # oldest still-prefilling request gets this step's chunk budget
-        pre: Optional[GenRequest] = None
-        for r in self._slots:
-            if r is not None and r._prefill_pos is not None:
-                if pre is None or r._admit_seq < pre._admit_seq:
-                    pre = r
-        if pre is None and not self._active.any():
-            return 0
+        with self._phase("engine.dispatch"):
+            # oldest still-prefilling request gets this step's chunk budget
+            pre: Optional[GenRequest] = None
+            for r in self._slots:
+                if r is not None and r._prefill_pos is not None:
+                    if pre is None or r._admit_seq < pre._admit_seq:
+                        pre = r
+            if pre is None and not self._active.any():
+                return 0
+            proposed = int(self._draft_lens.sum()) if self.spec_k else 0
+            if proposed:
+                drafts_in, lens_in = self._draft_tokens, self._draft_lens
+            else:
+                # nothing proposed this step: dispatch the device-resident
+                # zero twins so an idle speculation lane uploads nothing
+                drafts_in, lens_in = self._dz_drafts, self._dz_lens
+            n_valid = 0
+            t0 = time.monotonic()
+            if pre is None:
+                # decode-only shape: no idle chunk rows to pay for — and
+                # with the state device-resident, NOTHING crosses
+                # host→device on this path (the steady-state contract the
+                # transfer-guard test pins)
+                self._kp, self._vp, self._dstate, packed = self._step_fn(
+                    self.params, self._kp, self._vp, self._dstate,
+                    drafts_in, lens_in, None)
+                c_first = None
+            else:
+                c = self.prefill_chunk
+                start = pre._prefill_pos
+                n_valid = min(c, len(pre._ctx) - start)
+                c_tokens = np.zeros((c,), np.int32)
+                c_tokens[:n_valid] = pre._ctx[start:start + n_valid]
+                c_ints = np.asarray([pre._slot, start, n_valid], np.int32)
+                if pre.first_chunk_at is None:
+                    pre.first_chunk_at = time.monotonic()
+                self._kp, self._vp, self._dstate, packed, c_first = \
+                    self._step_fn(self.params, self._kp, self._vp,
+                                  self._dstate, drafts_in, lens_in,
+                                  (c_tokens, c_ints))
+        with self._phase("engine.readback"):
+            # the ONE device→host read of the step: [B, G+3] =
+            # tokens | emit_count | finished | accept_len
+            packed = np.asarray(packed)
+        with self._phase("engine.deliver"):
+            return self._deliver_step(packed, pre, n_valid, c_first,
+                                      proposed, t0)
+
+    def _deliver_step(self, packed, pre: Optional[GenRequest],
+                      n_valid: int, c_first, proposed: int,
+                      t0: float) -> int:
+        """What the host does with a step's read-back bundle: advance
+        the mirrors, deliver each lane's tokens, retire what finished,
+        complete the prefill whose last chunk rode along."""
         G = self.spec_k + 1
-        proposed = int(self._draft_lens.sum()) if self.spec_k else 0
-        if proposed:
-            drafts_in, lens_in = self._draft_tokens, self._draft_lens
-        else:
-            # nothing proposed this step: dispatch the device-resident
-            # zero twins so an idle speculation lane uploads nothing
-            drafts_in, lens_in = self._dz_drafts, self._dz_lens
-        n_valid = 0
-        t0 = time.monotonic()
-        if pre is None:
-            # decode-only shape: no idle chunk rows to pay for — and
-            # with the state device-resident, NOTHING crosses
-            # host→device on this path (the steady-state contract the
-            # transfer-guard test pins)
-            self._kp, self._vp, self._dstate, packed = self._step_fn(
-                self.params, self._kp, self._vp, self._dstate,
-                drafts_in, lens_in, None)
-            c_first = None
-        else:
-            c = self.prefill_chunk
-            start = pre._prefill_pos
-            n_valid = min(c, len(pre._ctx) - start)
-            c_tokens = np.zeros((c,), np.int32)
-            c_tokens[:n_valid] = pre._ctx[start:start + n_valid]
-            c_ints = np.asarray([pre._slot, start, n_valid], np.int32)
-            self._kp, self._vp, self._dstate, packed, c_first = \
-                self._step_fn(self.params, self._kp, self._vp,
-                              self._dstate, drafts_in, lens_in,
-                              (c_tokens, c_ints))
-        # the ONE device→host read of the step: [B, G+3] =
-        # tokens | emit_count | finished | accept_len
-        packed = np.asarray(packed)
         self.steps += 1
         self._chunk_fill = n_valid
         emitted = 0
@@ -1588,18 +1667,27 @@ class DecodeEngine:
             req._proposer.append(tok)
         if first:
             ttft = req.first_token_at - req.submitted_at
+            # the three stages sum to ttft, request by request
+            stages = {
+                "queue": req.admitted_at - req.submitted_at,
+                "prefill_wait": req.first_chunk_at - req.admitted_at,
+                "prefill": req.first_token_at - req.first_chunk_at}
             if self.metrics:
-                self.metrics.ttft.add(ttft)
                 # a slow TTFT bucket's exemplar IS this request's trace
-                self.metrics.ttft_hist.add(
-                    ttft,
-                    exemplar_trace=req.trace_ctx.trace_id
-                    if req.trace_ctx is not None and
-                    req.trace_ctx.sampled else None)
+                exemplar = req.trace_ctx.trace_id \
+                    if req.trace_ctx is not None and \
+                    req.trace_ctx.sampled else None
+                self.metrics.ttft.add(ttft)
+                self.metrics.ttft_hist.add(ttft, exemplar_trace=exemplar)
+                for stage, secs in stages.items():
+                    self.metrics.ttft_stage_hist[stage].add(
+                        secs, exemplar_trace=exemplar)
             fsp = self.tracer.span("serving.first_token",
                                    parent=req.trace_ctx)
             fsp.add_kv("request", str(req.id))
             fsp.add_kv("ttft_s", f"{ttft:.6f}")
+            fsp.add_kv("prefill_wait_s", f"{stages['prefill_wait']:.6f}")
+            fsp.add_kv("prefill_service_s", f"{stages['prefill']:.6f}")
             fsp.finish()
         self._maybe_finish(req, tok)
         if req._slot is not None:
@@ -1630,6 +1718,10 @@ class DecodeEngine:
         m.prefix_cached_blocks.set(stats["cached_blocks"])
         m.chunk_occupancy.set(self._chunk_fill / self.prefill_chunk)
         m.prefill_backlog.set(self.prefill_backlog)
+        for name, secs in self.phase_s.items():
+            m.phase_seconds[name].incr(
+                secs - self._phase_published.get(name, 0.0))
+        self._phase_published = dict(self.phase_s)
 
     # --------------------------------------------------- replica lifecycle
 
@@ -1774,8 +1866,9 @@ class DecodeEngine:
                 # _local_idle, not idle: a busy longctx plane must not
                 # flip this predicate — step() would return 0 in a
                 # tight no-sleep loop for the whole monster request
-                while self._local_idle and not self._stop.is_set():
-                    self._cond.wait(0.05)
+                with self._phase("engine.wait"):
+                    while self._local_idle and not self._stop.is_set():
+                        self._cond.wait(0.05)
             if self._stop.is_set():
                 return
             try:

@@ -52,6 +52,22 @@ class ServingMetrics:
                              to CP prefill, KV blocks streamed to the
                              cold tiers, decode window page-ins, CP
                              width, prefill wall time
+    - ``phase_seconds{phase=engine.wait|…|engine.publish}`` cumulative
+                             seconds of the scheduler thread in each
+                             phase of its loop (one prom family,
+                             ``htpu_serving_engine_phase_seconds_total``;
+                             the phases tile an iteration, so their
+                             rates sum to ~1 and say where the host's
+                             time between device steps goes)
+    - ``iteration_seconds``  histogram of the time between the starts
+                             of two consecutive device steps of a busy
+                             engine (waiting for work and compiles left
+                             out) — a stall of the whole process shows
+                             here, where ``decode_step`` cannot see it
+    - ``ttft_stage_seconds{stage=queue|prefill_wait|prefill}``  the
+                             three intervals that sum to each request's
+                             time to first token: submitted → admitted
+                             → first chunk dispatched → first token
     - ``weight_bytes``       measured resident model weight bytes
                              (``htpu_weight_bytes`` on ``/prom`` — the
                              weight-plane capacity signal: int8 resident
@@ -80,6 +96,29 @@ class ServingMetrics:
             "decode_step", "one continuous-batching decode step")
         self.decode_step_hist = reg.histogram(
             "decode_step_seconds", "one continuous-batching decode step")
+        # the scheduler loop's own time, one family with the bounded
+        # label set of engine.PHASES (inline literals: the label lint
+        # proves the bound), and the TTFT timeline's three stages
+        self.phase_seconds = {
+            ph: reg.counter(
+                f"phase_seconds_{ph}",
+                "scheduler-thread seconds by phase of the engine loop",
+                prom_name="serving_engine_phase_seconds",
+                prom_labels={"phase": ph})
+            for ph in ("engine.wait", "engine.admit", "engine.propose",
+                       "engine.pages", "engine.dispatch",
+                       "engine.readback", "engine.deliver",
+                       "engine.publish")}
+        self.iteration_hist = reg.histogram(
+            "iteration_seconds",
+            "start of one device step to the next, of a busy engine")
+        self.ttft_stage_hist = {
+            stage: reg.histogram(
+                f"ttft_stage_seconds_{stage}",
+                "time to first token by stage of the request's timeline",
+                prom_name="serving_engine_ttft_stage_seconds",
+                prom_labels={"stage": stage})
+            for stage in ("queue", "prefill_wait", "prefill")}
         self.tokens_out = reg.counter(
             "tokens_out", "tokens generated (all requests)")
         self.requests = reg.counter("requests", "requests submitted")

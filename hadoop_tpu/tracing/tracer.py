@@ -158,6 +158,40 @@ def current_span() -> Optional[Span]:
     return _active.get()
 
 
+class phase:
+    """One phase of a loop that feeds the device: ``with phase(name,
+    sink)`` adds the elapsed ``time.monotonic()`` seconds to
+    ``sink[name]`` (a plain dict the caller owns). While a
+    ``jax.profiler`` session runs, the phase is also an event on the
+    calling thread's line of the profiler's host plane, so an idle gap
+    of the device can be named by what the host was doing in it.
+
+    Not a ``Span``: no ids, no sampling, no delivery, no ring — a phase
+    happens ten times a step and belongs to no request. Request-lifetime
+    spans never go to the profiler: they would cover every gap whole.
+    ``jax.profiler`` is imported here, on first use by a thread that
+    already drives JAX, so daemons that import ``tracing`` stay off it."""
+
+    __slots__ = ("name", "sink", "_t0", "_ann")
+
+    def __init__(self, name: str, sink: Dict[str, float]):
+        self.name = name
+        self.sink = sink
+
+    def __enter__(self) -> "phase":
+        from jax.profiler import TraceAnnotation
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dt = time.monotonic() - self._t0
+        self._ann.__exit__(*exc)
+        self.sink[self.name] = self.sink.get(self.name, 0.0) + dt
+        return False
+
+
 def current_context() -> Optional[SpanContext]:
     """Wire context of the active span, if any — what a client attaches
     to an outgoing RPC / data-transfer op / HTTP request."""
