@@ -5,7 +5,8 @@ the fused Pallas flash kernel in ``hadoop_tpu.ops.flash`` is selected
 automatically on TPU backends for qualifying shapes (see
 ``causal_attention``'s ``impl`` arg). Which of the two a call site got is
 recorded at trace time (``attention_impl_traces``), so a run that meant
-to use the kernel can prove it did.
+to use the kernel can prove it did; serving's paged decode attention
+(``hadoop_tpu.ops.paged_attention``) records its own choice the same way.
 
 Ring attention (sequence/context parallelism over the mesh) builds on
 ``chunk_attention`` + ``merge_attention``: each partial result is the
@@ -29,10 +30,10 @@ _NEG_INF = -1e30
 
 
 def _impl_counters():
-    """The four ``htpu_attention_impl_traces_total{site,impl}`` counters
+    """The six ``htpu_attention_impl_traces_total{site,impl}`` counters
     (label values from these literal tuples — the bounded-set contract)."""
     reg = metrics_system().source("attention")
-    for site in ("causal", "ring"):
+    for site in ("causal", "ring", "paged"):
         for impl in ("flash", "ref"):
             reg.counter(f"{site}_{impl}_traces",
                         "attention call sites traced per implementation",
@@ -51,7 +52,8 @@ def record_attention_impl(site: str, impl: str, q_shape, k_shape) -> None:
 
 def attention_impl_traces() -> dict:
     """``{"causal_flash": n, "causal_ref": n, "ring_flash": n,
-    "ring_ref": n}`` — call sites traced so far in this process."""
+    "ring_ref": n, "paged_flash": n, "paged_ref": n}`` — call sites
+    traced so far in this process."""
     snap = _impl_counters().snapshot()
     return {k[:-len("_traces")]: v for k, v in snap.items()}
 

@@ -19,12 +19,14 @@ Design (TPU-first, same rules as the trainer):
 - **Paged KV cache.** K/V live in a block pool of shape
   ``[L, num_blocks, block_size, Hkv, Dh]``; each running request owns a
   block table (list of pool indices). Each step scatters the new
-  tokens' K/V into ``table[pos // bs], pos % bs`` and gathers each
-  row's context back through its table — requests share one pool with
-  no per-request padding waste (the vLLM PagedAttention layout,
-  expressed as jnp scatter/gather so XLA keeps it fused). Block 0 is a
-  write-off scratch page: inactive rows and chunk padding scatter
-  there, so masking never needs dynamic shapes.
+  tokens' K/V into ``table[pos // bs], pos % bs`` and then attends
+  each row to its own live pages through its table
+  (``ops.paged_attention``: the walk ends at the step's longest live
+  context, not at ``max_context``; GQA groups share each K/V read; K/V
+  stay in the pool's dtype) — requests share one pool with no
+  per-request padding waste (the vLLM PagedAttention layout). Block 0
+  is a write-off scratch page: inactive rows and chunk padding scatter
+  there and attend to nothing, so masking never needs dynamic shapes.
 
 - **Prefix-reuse KV cache.** The pool is refcounted and a radix index
   (block-granular trie keyed by token chunks) remembers fully-filled
@@ -146,7 +148,7 @@ from hadoop_tpu.models.decoder import _norm, head_matrix
 from hadoop_tpu.models.moe import _expert_ffn, route
 from hadoop_tpu.models.moe import capacity as moe_capacity
 from hadoop_tpu.ops import gelu, rope_frequencies, swiglu
-from hadoop_tpu.ops.attention import _repeat_kv
+from hadoop_tpu.ops.paged_attention import paged_attention
 # BlockPool/PrefixCache live in the kvstore package now (the tiered
 # fleet-wide cache); re-exported here so `from serving.engine import
 # BlockPool` keeps working for every existing consumer
@@ -718,10 +720,11 @@ class DecodeEngine:
         consecutive positions, sharing the lane's block table row);
         when ``chunk`` rides along, the last ``prefill_chunk`` rows are
         consecutive positions of one request's prompt chunk.
-        Scatter-all-then-gather makes earlier rows' K/V visible to
-        later positions within the same step; the causal mask
-        ``kpos <= position`` does the rest — a draft row attends to the
-        drafts before it exactly as it would have sequentially.
+        Scatter-all-then-attend makes earlier rows' K/V visible to
+        later positions within the same step; each row's length
+        ``position + 1`` (0 for an inactive row) is its causal mask — a
+        draft row attends to the drafts before it exactly as it would
+        have sequentially.
 
         All lane state arrives in (and leaves through) the donated
         ``state`` dict: positions advance by the accepted length, the
@@ -810,7 +813,12 @@ class DecodeEngine:
         blk = jnp.where(active, blk, BlockPool.SCRATCH)
         off = pos % self.block_size
         scale = 1.0 / (dh ** 0.5)
-        kpos = jnp.arange(self.s_max)
+        # causal by length: a live row attends to positions <= its own;
+        # an inactive row attends to nothing and gets zeros
+        lens = jnp.where(active, pos + 1, 0)
+        # the Pallas kernel is a one-device program: a pool sharded over
+        # this engine's mesh takes the portable path under GSPMD
+        attn_impl = "auto" if self._mesh is None else "ref"
 
         # the scopes below are the step's stable names on the device
         # trace (metadata only: the compiled program is the same)
@@ -827,21 +835,11 @@ class DecodeEngine:
             with jax.named_scope("kv_update"):
                 kc = kc.at[blk, off].set(k.astype(kc.dtype))
                 vc = vc.at[blk, off].set(v.astype(vc.dtype))
-            with jax.named_scope("kv_gather"):
-                # paged gather: each row pulls its own pages back into a
-                # contiguous [S_max] context view through the block table
-                kctx = kc[tables].reshape(t, self.s_max, hkv, dh)
-                vctx = vc[tables].reshape(t, self.s_max, hkv, dh)
-                kr = _repeat_kv(kctx, hq // hkv)
-                vr = _repeat_kv(vctx, hq // hkv)
             with jax.named_scope("attn"):
-                logits = jnp.einsum(
-                    "bhd,bkhd->bhk", q, kr,
-                    preferred_element_type=jnp.float32) * scale
-                mask = kpos[None, :] <= pos[:, None]
-                logits = jnp.where(mask[:, None, :], logits, _NEG_INF)
-                probs = jax.nn.softmax(logits, axis=-1).astype(vr.dtype)
-                attn = jnp.einsum("bhk,bkhd->bhd", probs, vr)
+                # read AFTER the scatter: a draft or chunk row sees the
+                # rows before it in this very step
+                attn = paged_attention(q, kc, vc, tables, lens, scale,
+                                       impl=attn_impl)
             with jax.named_scope("attn_proj"):
                 h2 = h + self._wdot(attn.reshape(t, hq * dh),
                                     lp["wo"]).astype(h.dtype)
@@ -1510,6 +1508,11 @@ class DecodeEngine:
                 # zero twins so an idle speculation lane uploads nothing
                 drafts_in, lens_in = self._dz_drafts, self._dz_lens
             n_valid = 0
+            if pre is not None:
+                n_valid = min(self.prefill_chunk,
+                              len(pre._ctx) - pre._prefill_pos)
+            if self.metrics:
+                self._count_attn_pages(pre, n_valid)
             t0 = time.monotonic()
             if pre is None:
                 # decode-only shape: no idle chunk rows to pay for — and
@@ -1523,7 +1526,6 @@ class DecodeEngine:
             else:
                 c = self.prefill_chunk
                 start = pre._prefill_pos
-                n_valid = min(c, len(pre._ctx) - start)
                 c_tokens = np.zeros((c,), np.int32)
                 c_tokens[:n_valid] = pre._ctx[start:start + n_valid]
                 c_ints = np.asarray([pre._slot, start, n_valid], np.int32)
@@ -1540,6 +1542,26 @@ class DecodeEngine:
         with self._phase("engine.deliver"):
             return self._deliver_step(packed, pre, n_valid, c_first,
                                       proposed, t0)
+
+    def _count_attn_pages(self, pre: Optional[GenRequest],
+                          n_valid: int) -> None:
+        """The live-page share of this step's attention, from the host's
+        mirrors (no device read-back): pages its live rows attend to —
+        a lane's row ``j`` at position ``p + j`` reads the pages of
+        ``p + j + 1`` tokens, a chunk row likewise — against the pages
+        of every row's whole table."""
+        bs = self.block_size
+        j = np.arange(self.spec_k + 1)
+        lanes = np.flatnonzero(self._active)
+        lens = self._seq_lens[lanes, None] + 1 + j
+        lens = lens[j <= self._draft_lens[lanes, None]]
+        rows = self.max_batch * j.size
+        if pre is not None:
+            lens = np.concatenate(
+                [lens, pre._prefill_pos + 1 + np.arange(n_valid)])
+            rows += self.prefill_chunk
+        self.metrics.attn_pages_read.incr(int(np.sum(-(-lens // bs))))
+        self.metrics.attn_pages_dense.incr(rows * self.blocks_per_seq)
 
     def _deliver_step(self, packed, pre: Optional[GenRequest],
                       n_valid: int, c_first, proposed: int,

@@ -43,6 +43,10 @@ class ServingMetrics:
                              token counters (proposal vs verifier)
     - ``spec_accept_len``    log-bucketed accepted-draft-length
                              histogram per speculating lane-step
+    - ``attn_pages_read`` / ``attn_pages_dense``  KV pages the steps'
+                             live rows attended to, against the pages a
+                             walk of every row's whole table would have
+                             read; their ratio is the live-page share
     - ``qos_admitted`` / ``qos_shed``  door QoS gate outcomes (sheds
                              are 429 + Retry-After responses)
     - ``qos_tenants``        tenants tracked by the decay scheduler
@@ -183,6 +187,14 @@ class ServingMetrics:
         self.spec_accept_len = reg.histogram(
             "spec_accept_len",
             "accepted draft-prefix length per speculating lane-step")
+        # paged attention: how far its traffic follows the live contexts
+        # (counted on the host from the scheduler's mirrors, per step)
+        self.attn_pages_read = reg.counter(
+            "attn_pages_read",
+            "KV pages the steps' live rows attended to")
+        self.attn_pages_dense = reg.counter(
+            "attn_pages_dense",
+            "KV pages a walk of every row's whole block table would read")
         # door QoS: admissions vs sheds (429) and tracked tenants — the
         # autoscaler scrapes qos_shed off /prom as a scale-out signal
         # (a shedding fleet is past its SLO by definition)
