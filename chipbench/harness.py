@@ -51,6 +51,13 @@ class Cell:
         return self.config["harness"]
 
     @property
+    def family(self):
+        """The architecture's module, found by the file's ``model_type``:
+        ``ModelConfig``, weights, plain reference and the work needed."""
+        from chipbench import families
+        return families.load(self.model)
+
+    @property
     def platform(self) -> str:
         """A stand-in configuration for the CPU rehearsal says so."""
         return self.harness.get("platform", "tpu")
@@ -85,6 +92,7 @@ def open_cell(workload: str, benchmark: str):
     """What ``run`` and ``proof`` both start with: the cell, the compile
     cache, the chips, the traffic kind's driver."""
     cell = load_cell(workload, benchmark)
+    cell.family     # a model_type without a module ends the run here
     from hadoop_tpu.util.jaxcache import configure_compile_cache
     configure_compile_cache()
     import jax
@@ -92,6 +100,11 @@ def open_cell(workload: str, benchmark: str):
     # program that compiles in about a second is otherwise compiled anew
     # by every run, and set-up wanders with it
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # an executable loaded from the cache keeps the names it was compiled
+    # with, because the cache's key leaves metadata out: a program whose
+    # scopes alone changed would be read under its old ones (my chip run,
+    # PR 26: the fused step came back with no scope at all)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     from chipbench import kinds
     return cell, find_devices(cell), kinds.driver(cell.traffic["kind"])
 
@@ -165,9 +178,15 @@ class Tracer:
         somewhere else; on the CPU rehearsal there is nothing to read."""
         if self.dir is None:
             return None
-        from chipbench import trace
+        from chipbench import scopes, trace
         try:
             red = trace.reduce(trace.load_events(self.dir))
+            if red is not None:
+                # device seconds by compiled program and by the program's
+                # named scopes, for the ``trace-module-ms`` and
+                # ``trace-scope-share`` readers
+                red["scopes"] = scopes.reduce(scopes.load_dir(self.dir),
+                                              scopes.named_in_metrics())
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
         if devices[0].platform != "tpu":

@@ -9,13 +9,19 @@ read (the harness then leaves the metric out — never a 0).
 ``ratio``        obs[num] / obs[den] * scale (None when den is 0)
 ``mfu``          obs[flops] / obs[window] / (chips * peak bf16 FLOP/s), %
 ``trace-idle``   1 - device busy union / traced window, %
+``trace-module-ms``, ``trace-scope-share``   see ``scopes.py``
+
+A reader that is none of these is looked for in the ``READERS`` of the
+cell's family module (``chipbench/families/``), where an architecture's
+own kernel keeps the reader of its roofline share beside its operation
+and byte counts.
 """
 
 from __future__ import annotations
 
 from typing import Optional
 
-from chipbench import peaks
+from chipbench import peaks, scopes
 
 
 def _value(spec, out, cell) -> Optional[float]:
@@ -63,13 +69,17 @@ def _trace_idle(spec, out, cell) -> Optional[float]:
 
 
 READERS = {"value": _value, "mean": _mean, "percentile": _percentile,
-           "ratio": _ratio, "mfu": _mfu, "trace-idle": _trace_idle}
+           "ratio": _ratio, "mfu": _mfu, "trace-idle": _trace_idle,
+           **scopes.READERS}
 
 
 def read(spec: dict, out, cell) -> Optional[float]:
-    try:
-        reader = READERS[spec["reader"]]
-    except KeyError:
-        raise ValueError(f"metric reader {spec.get('reader')!r} unknown "
-                         f"(there are: {sorted(READERS)})") from None
+    name = spec.get("reader")
+    reader = READERS.get(name)
+    if reader is None:
+        own = getattr(cell.family, "READERS", {})
+        reader = own.get(name)
+        if reader is None:
+            raise ValueError(f"metric reader {name!r} unknown (there are: "
+                             f"{sorted({**READERS, **own})})")
     return reader(spec, out, cell)

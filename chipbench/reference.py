@@ -1,20 +1,15 @@
-"""The plain reference: Mistral / Mixtral in straightforward ``jax.numpy``,
-float32 at ``highest`` matmul precision, no kernels, no cache, no
-batching tricks. It imports nothing of the program and takes nothing the
-program made: weights come from ``chipbench.weights`` and the seed.
+"""What every family's plain reference is made of: the matmul at float32
+``highest`` with its ``quant`` control, RMSNorm, the scoring of served
+tokens under a head, chunked token cross-entropy, and AdamW as the
+configurations state it. No kernels, no cache, no batching tricks; it
+imports nothing of the program and takes nothing the program made. The
+layer equations, the leaf names and the loss of an architecture live in
+its family module (``chipbench/families/``), which calls these.
 
-Architecture (the published ones, huggingface ``MistralForCausalLM`` /
-``MixtralForCausalLM``): pre-norm RMSNorm, grouped-query causal
-attention with split-half rotary embeddings, SwiGLU MLP, untied head.
-Mixtral: softmax router, top-2 experts, gate weights renormalised over
-the chosen two, no token dropped. Training: mean token cross-entropy,
-global-norm clip at 1.0, AdamW (lr 3e-4, betas 0.9 / 0.95, eps 1e-8,
-decoupled decay 0.1 on matrices, none on norm vectors), parameters kept
-in the dtype the configuration states (bfloat16), moments in float32.
-
-Departures, each because the configuration states it: parameters are
-rounded to bfloat16 after every update; the sliding window is off
-(``sliding_window: null`` in both sources).
+Training: mean token cross-entropy, global-norm clip at 1.0, AdamW (lr
+3e-4, betas 0.9 / 0.95, eps 1e-8, decoupled decay 0.1 on matrices, none
+on norm vectors), parameters kept in the dtype the configuration states
+(bfloat16) and rounded to it after every update, moments in float32.
 
 ``quant`` switches the *control*: the same mathematics with every
 weight matmul's operands rounded to a lower precision (``int8``:
@@ -37,7 +32,6 @@ LR, B1, B2, EPS, DECAY, CLIP = 3e-4, 0.9, 0.95, 1e-8, 0.1, 1.0
 
 
 # ------------------------------------------------------------ primitives
-
 def _fake_quant(x, axis, quant):
     if quant == "int8":
         s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / 127.0
@@ -66,159 +60,43 @@ def rms_norm(x, w, eps):
     return x * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)
 
 
-def rope(x, theta):
-    """x [S, H, Dh] at positions 0..S-1, split-half rotation."""
-    s, _, dh = x.shape
-    inv = 1.0 / (theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    c, sn = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * c - x2 * sn, x1 * sn + x2 * c], axis=-1)
-
-
-def attention(q, k, v):
-    """One sequence. q [S, Hq, Dh], k/v [S, Hkv, Dh] -> [S, Hq, Dh].
-    One KV head's group at a time so the [S, S] scores stay small."""
-    s, hq, dh = q.shape
-    hkv = k.shape[1]
-    qg = q.reshape(s, hkv, hq // hkv, dh).transpose(1, 2, 0, 3)
-    kg, vg = k.transpose(1, 0, 2), v.transpose(1, 0, 2)
-    mask = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
-
-    @jax.checkpoint
-    def group(args):
-        qh, kh, vh = args               # [G,S,Dh], [S,Dh], [S,Dh]
-        sc = jnp.einsum("gqd,kd->gqk", qh, kh, precision=HI) / (dh ** 0.5)
-        sc = jnp.where(mask[None], sc, -jnp.inf)
-        p = jax.nn.softmax(sc, axis=-1)
-        return jnp.einsum("gqk,kd->gqd", p, vh, precision=HI)
-
-    out = jax.lax.map(group, (qg, kg, vg))      # [Hkv, G, S, Dh]
-    return out.transpose(2, 0, 1, 3).reshape(s, hq, dh)
-
-
-def mlp(h, lp, model, quant):
-    """h [T, D]."""
-    if not model.get("num_local_experts"):
-        g = mm(h, lp["w_gate"], quant)
-        u = mm(h, lp["w_up"], quant)
-        return mm(jax.nn.silu(g) * u, lp["w_down"], quant)
-    k = model["num_experts_per_tok"]
-    probs = jax.nn.softmax(mm(h, lp["router"], quant), axis=-1)
-    top_v, top_i = jax.lax.top_k(probs, k)
-    top_v = top_v / jnp.sum(top_v, axis=-1, keepdims=True)
-    n_exp = probs.shape[-1]
-    gate = jnp.sum(jax.nn.one_hot(top_i, n_exp) * top_v[..., None],
-                   axis=1)                                  # [T, E]
-
-    @jax.checkpoint
-    def expert(acc, xs):
-        wg, wu, wd, ge = xs
-        y = mm(jax.nn.silu(mm(h, wg, quant)) * mm(h, wu, quant), wd,
-               quant)
-        return acc + ge[:, None] * y, None
-
-    out, _ = jax.lax.scan(
-        expert, jnp.zeros_like(h),
-        (lp["w_gate"], lp["w_up"], lp["w_down"], gate.T))
-    return out
-
-
-def layer(x, lp, model, quant=None):
-    """x [B, S, D] float32, positions 0..S-1 in every row."""
-    m = W.dims(model)
-    b, s, d = x.shape
-    eps, theta = model["rms_norm_eps"], model["rope_theta"]
-    # float32 before anything closes over the weights, so that what a
-    # map or a scan accumulates for them it accumulates in float32
-    lp = {k: v.astype(jnp.float32) for k, v in lp.items()}
-
-    def one_row(xr):
-        h = rms_norm(xr, lp["attn_norm_w"], eps)
-        q = mm(h, lp["wq"], quant).reshape(s, m["Hq"], m["Dh"])
-        k = mm(h, lp["wk"], quant).reshape(s, m["Hkv"], m["Dh"])
-        v = mm(h, lp["wv"], quant).reshape(s, m["Hkv"], m["Dh"])
-        a = attention(rope(q, theta), rope(k, theta), v)
-        xr = xr + mm(a.reshape(s, m["Hq"] * m["Dh"]), lp["wo"], quant)
-        h = rms_norm(xr, lp["mlp_norm_w"], eps)
-        return xr + mlp(h, lp, model, quant)
-
-    return jax.lax.map(one_row, x)
-
-
 # ---------------------------------------------------------------- serving
 
-@functools.partial(jax.jit, static_argnames=("model_key", "quant"))
-def _serve_layer(x, key, lyr, model_key, quant):
-    model = dict(model_key)
-    lp = W.layer_params(model, key, lyr, jnp.bfloat16)
-    return layer(x, lp, model, quant)
-
-
-@functools.partial(jax.jit, static_argnames=("model_key",))
-def _top(key, model_key):
-    return W.top_params(dict(model_key), key, jnp.bfloat16)
-
-
-@functools.partial(jax.jit, static_argnames=("model_key", "quant"))
-def _head(x, idx, served, norm_w, head_w, model_key, quant):
-    model = dict(model_key)
+@functools.partial(jax.jit, static_argnames=("eps", "quant"))
+def _head(x, idx, served, norm_w, head_w, eps, quant):
     h = jnp.take_along_axis(x, idx[:, :, None], axis=1)
-    h = rms_norm(h, norm_w, model["rms_norm_eps"])
+    h = rms_norm(h, norm_w, eps)
     logits = mm(h, head_w, quant)                           # [N, P, V]
     got = jnp.take_along_axis(logits, served[:, :, None], axis=-1)[..., 0]
     return jnp.max(logits, axis=-1) - got, jnp.argmax(logits, axis=-1)
 
 
-def hidden_states(model: dict, seed: int, tokens, quant=None):
-    """Final-layer hidden states [N, S, D] of ``tokens`` [N, S] (each row
-    a prompt followed by what was served for it; padding after that is
-    never looked at, the mask is causal). Weights are regenerated from
-    the seed one layer at a time."""
-    mkey = W.freeze(model)
-    key = W.seed_key(seed)
-    embed = _top(key, mkey)["embed"]
-    x = embed[jnp.asarray(tokens, jnp.int32)].astype(jnp.float32)
-    for lyr in range(model["num_hidden_layers"]):
-        x = _serve_layer(x, key, jnp.int32(lyr), mkey, quant)
-    return x
-
-
-def score(model: dict, seed: int, x, positions, tokens_at, quant=None):
-    """At each of ``positions`` [N, P] (-1 = none), the best logit minus
-    the logit of ``tokens_at`` [N, P] (``gaps``, -1 where there is no
-    position), and the token this pass puts first there. The logits that
-    predict position p come from the hidden state at p - 1."""
+def score(x, positions, tokens_at, norm_w, head_w, eps, quant=None):
+    """Under a final RMSNorm and a head: at each of ``positions`` [N, P]
+    (-1 = none), the best logit minus the logit of ``tokens_at`` [N, P]
+    (``gaps``, -1 where there is no position), and the token this pass
+    puts first there. The logits that predict position p come from the
+    hidden state ``x`` [N, S, D] at p - 1."""
     import numpy as np
-    mkey = W.freeze(model)
-    top = _top(W.seed_key(seed), mkey)
     positions = np.asarray(positions)
     valid = positions >= 0
     idx = np.clip(positions - 1, 0, x.shape[1] - 1)
     gaps, first = _head(x, jnp.asarray(idx, jnp.int32),
                         jnp.asarray(np.where(valid, tokens_at, 0),
                                     jnp.int32),
-                        top["final_norm_w"], top["lm_head"], mkey, quant)
+                        norm_w, head_w, eps, quant)
     return (np.where(valid, np.asarray(gaps), -1.0),
             np.where(valid, np.asarray(first), -1))
 
 
 # --------------------------------------------------------------- training
 
-def loss_fn(params, tokens, targets, model, quant=None, ce_chunk=512,
-            keep=1.0):
-    """Mean cross-entropy of ``targets`` given ``tokens`` ([B, S]).
+def token_cross_entropy(x, head_w, targets, quant=None, ce_chunk=512,
+                        keep=1.0):
+    """Mean cross-entropy of ``targets`` [B, S] under ``head_w`` given the
+    normed hidden states ``x`` [B, S, D], a chunk of positions at a time.
     ``keep`` < 1 plants a fault for the proofs: only the first ``keep``
     of each row's positions count, the mean taken over those."""
-    x = params["embed"].astype(jnp.float32)[tokens]
-    head_w = params["lm_head"].astype(jnp.float32)
-
-    @jax.checkpoint
-    def body(x, lp):
-        return layer(x, lp, model, quant), None
-
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm_w"], model["rms_norm_eps"])
     b, s, d = x.shape
     n = s // ce_chunk if s % ce_chunk == 0 else 1
     xc = x.reshape(b, n, s // n, d).transpose(1, 0, 2, 3)
@@ -252,12 +130,14 @@ def leaf_norms(tree):
         tree)
 
 
-@functools.partial(jax.jit, static_argnames=("model_key", "quant", "keep"),
+@functools.partial(jax.jit, static_argnames=("loss_fn", "model_key", "quant",
+                                             "keep"),
                    donate_argnums=(0, 1, 2))
-def train_step(params, mu, nu, count, tokens, targets, model_key,
+def train_step(params, mu, nu, count, tokens, targets, loss_fn, model_key,
                quant=None, keep=1.0):
-    """One step. Returns (params, mu, nu, count, loss, per-leaf norms of
-    the clipped gradient)."""
+    """One step of ``loss_fn(params, tokens, targets, model, quant,
+    keep=)`` (a family's). Returns (params, mu, nu, count, loss, per-leaf
+    norms of the clipped gradient)."""
     model = dict(model_key)
     loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets,
                                               model, quant, keep=keep)
@@ -284,13 +164,15 @@ def train_step(params, mu, nu, count, tokens, targets, model_key,
     return pick(0), pick(1), pick(2), count, loss, pick(3)
 
 
-def follow(model: dict, seed: int, batches, quant=None, keep=1.0):
-    """Drive the reference from the seed through ``batches`` (a list of
-    (tokens, targets)). Returns each step's loss, the per-leaf norms of
-    the first clipped gradient, and the per-leaf norms of the
+def follow(make_tree, loss_fn, model: dict, seed: int, batches, quant=None,
+           keep=1.0):
+    """Drive a family's reference (``make_tree(model, key, dtype)`` its
+    whole tree, ``loss_fn`` its loss) from the seed through ``batches``
+    (a list of (tokens, targets)). Returns each step's loss, the per-leaf
+    norms of the first clipped gradient, and the per-leaf norms of the
     parameters' change over all the steps."""
     key = W.freeze(model)
-    params = make_params(W.seed_key(seed), key)
+    params = _make_params(W.seed_key(seed), key, make_tree)
     zeros = lambda: jax.tree_util.tree_map(         # noqa: E731
         lambda p: jnp.zeros(p.shape, jnp.float32), params)
     mu, nu = zeros(), zeros()
@@ -298,18 +180,20 @@ def follow(model: dict, seed: int, batches, quant=None, keep=1.0):
     losses, grad1 = [], None
     for tokens, targets in batches:
         params, mu, nu, count, loss, gn = train_step(
-            params, mu, nu, count, tokens, targets, key, quant, keep)
+            params, mu, nu, count, tokens, targets, loss_fn, key, quant,
+            keep)
         losses.append(float(loss))
         if grad1 is None:
             grad1 = jax.tree_util.tree_map(float, gn)
     del mu, nu
-    delta = change_norms(params, make_params(W.seed_key(seed), key))
+    delta = change_norms(params,
+                         _make_params(W.seed_key(seed), key, make_tree))
     return losses, grad1, jax.tree_util.tree_map(float, delta)
 
 
-@functools.partial(jax.jit, static_argnames=("model_key",))
-def make_params(key, model_key):
-    return W.make_params(dict(model_key), key, jnp.bfloat16)
+@functools.partial(jax.jit, static_argnames=("model_key", "make_tree"))
+def _make_params(key, model_key, make_tree):
+    return make_tree(dict(model_key), key, jnp.bfloat16)
 
 
 @jax.jit

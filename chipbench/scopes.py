@@ -20,12 +20,13 @@ body can reach — and as ``unscoped`` otherwise. Only leaf operations
 count, as ``trace._leaves`` defines a leaf.
 
 The two readers at the end read a run's reduction, which
-``harness.Tracer.reduce`` is to put under ``trace["scopes"]``.
+``harness.Tracer.reduce`` puts under ``trace["scopes"]``.
 """
 
 from __future__ import annotations
 
 import glob
+import json
 import os
 import re
 from collections import defaultdict
@@ -35,7 +36,8 @@ from chipbench import trace
 
 MODULES_LINE = "XLA Modules"
 # the names the program gives (hadoop_tpu: models/decoder.py, models/moe.py,
-# parallel/train.py, parallel/optimizer.py, serving/engine.py)
+# parallel/train.py, parallel/optimizer.py, serving/engine.py); a metric
+# file that names another adds it (``named_in_metrics``)
 SCOPES = frozenset((
     "embed", "attn", "mlp", "moe", "head_xent", "grad_norm", "optimizer",
     "attn_proj", "kv_update", "kv_gather", "head_sample"))
@@ -54,14 +56,28 @@ def module_name(name: str) -> str:
     return _MODULE.match(name.strip()).group(1)
 
 
-def scope_of(path: Optional[str]) -> str:
-    """The innermost of the program's names on an ``op_name`` path;
+def named_in_metrics() -> frozenset:
+    """``SCOPES`` and every scope a metric file names
+    (``chipbench/metrics/*.json`` with a ``scope`` key): a scope the program
+    gains later is known here from the day a file reads it."""
+    here = os.path.join(os.path.dirname(os.path.abspath(__file__)), "metrics")
+    found = set(SCOPES)
+    for path in glob.glob(os.path.join(here, "*.json")):
+        with open(path) as f:
+            scope = json.load(f).get("scope")
+        if scope not in (None, SCAN_CARRY, UNSCOPED):
+            found.add(scope)
+    return frozenset(found)
+
+
+def scope_of(path: Optional[str], names=SCOPES) -> str:
+    """The innermost of the program's ``names`` on an ``op_name`` path;
     failing one, whether the path lies on a loop."""
     parts = [m.group(1) for m in map(_BARE.match,
                                      (path or "").rstrip(":").split("/"))
              if m]
     for part in reversed(parts):
-        if part in SCOPES:
+        if part in names:
             return part
     return SCAN_CARRY if "while" in parts else UNSCOPED
 
@@ -195,7 +211,7 @@ def load_dir(trace_dir: str) -> List[dict]:
     return load_events(paths[0])
 
 
-def reduce(events: List[dict]) -> Optional[dict]:
+def reduce(events: List[dict], names=SCOPES) -> Optional[dict]:
     """``modules``: name -> {"count", "seconds"} of the program's WHOLE
     runs (seconds summed, both averaged over the chips): on each chip the
     first and the last event of the modules line are left out, because a
@@ -231,7 +247,7 @@ def reduce(events: List[dict]) -> Optional[dict]:
     unnamed: Dict[tuple, float] = defaultdict(float)
     for ops in chips.values():
         for e in trace._leaves(ops):
-            scope = scope_of(e.get("path"))
+            scope = scope_of(e.get("path"), names)
             by_scope[scope] += e["dur_ns"]
             if scope in (SCAN_CARRY, UNSCOPED):
                 unnamed[(trace.short_name(e["name"]),

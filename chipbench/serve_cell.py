@@ -26,9 +26,9 @@ from typing import List, Optional
 
 import numpy as np
 
-from chipbench import compare, flops, harness, traffic
+from chipbench import compare, harness, traffic
 from chipbench import weights as W
-from chipbench.train_cell import model_config
+from chipbench.families import Served
 
 FIRST_TOKEN_WAIT_S = 60.0
 SAMPLE = 4               # requests compared with the reference
@@ -187,12 +187,74 @@ def _sleep_until(t: float) -> None:
     time.sleep(max(0.0, t - time.monotonic()))
 
 
+# the engine's TTFT stages -> the observation each one's seconds go under
+STAGES = {"queue": "queue_wait_s", "prefill_wait": "prefill_wait_s",
+          "prefill": "prefill_service_s"}
+
+
 def _counters(engine) -> dict:
-    return {"steps": engine.steps, "seen": engine.prefix_tokens_seen,
-            "matched": engine.prefix_tokens_matched,
-            "occ": len(engine.occupancy_log),
-            "backlog": engine.queue_depth + engine.num_prefilling,
-            "compiles": engine.decode_compiles + engine.prefill_compiles}
+    """The engine's record at one moment. Beside the six counts every
+    engine has: seconds by phase of its loop, (sum, count) of each TTFT
+    stage's histogram, the iteration histogram's cumulative counts by
+    bound, and every number of its metrics registry by the registry's own
+    name — each only where the program keeps it, so a parent without one
+    reads nothing there and the metrics over it are left out."""
+    c = {"steps": engine.steps, "seen": engine.prefix_tokens_seen,
+         "matched": engine.prefix_tokens_matched,
+         "occ": len(engine.occupancy_log),
+         "backlog": engine.queue_depth + engine.num_prefilling,
+         "compiles": engine.decode_compiles + engine.prefill_compiles}
+    phase_s = getattr(engine, "phase_s", None)
+    if phase_s is not None:
+        c["phase_s"] = dict(phase_s)
+    m = getattr(engine, "metrics", None)
+    stages = getattr(m, "ttft_stage_hist", None)
+    if stages is not None:
+        c["stages"] = {s: h.buckets()[1:] for s, h in stages.items()}
+    iterations = getattr(m, "iteration_hist", None)
+    if iterations is not None:
+        c["iterations"] = iterations.buckets()[0]
+    snapshot = getattr(m, "snapshot", None)
+    if snapshot is not None:
+        c["snapshot"] = {k: v for k, v in snapshot().items()
+                         if isinstance(v, (int, float))
+                         and not isinstance(v, bool)}
+    return c
+
+
+def window_deltas(a: dict, b: dict) -> dict:
+    """What the engine's record gained between the window's opening
+    (``a``) and its close (``b``), under the names the metric files read.
+    ``counter.<name>`` is the gain of the registry's ``<name>`` (a gauge's
+    gain means little; a file reads the counters it knows)."""
+    obs: dict = {}
+    then, now = a.get("stages", {}), b.get("stages", {})
+    for stage, key in STAGES.items():
+        if stage in then and stage in now:
+            obs[key] = now[stage][0] - then[stage][0]
+    if "prefill" in then and "prefill" in now:
+        obs["first_tokens"] = now["prefill"][1] - then["prefill"][1]
+    then, now = a.get("phase_s"), b.get("phase_s")
+    if then is not None and now:
+        phases = {k: v - then.get(k, 0.0) for k, v in now.items()}
+        obs["phase_window_s"] = phases
+        obs["phase_busy_s"] = sum(v for k, v in phases.items()
+                                  if k != "engine.wait")
+        obs["phase_host_s"] = obs["phase_busy_s"] \
+            - phases.get("engine.readback", 0.0)
+    if "iterations" in a and "iterations" in b:
+        # the longest iteration of the window, as its bucket's upper bound
+        # (a ladder of doublings: 0.128, 0.256, ... s)
+        below = 0
+        for (bound, n0), (_, n1) in zip(a["iterations"], b["iterations"]):
+            if n1 - n0 > below:
+                obs["iteration_max_s"] = bound
+            below = n1 - n0
+    before = a.get("snapshot", {})
+    for name, v in b.get("snapshot", {}).items():
+        if name in before:
+            obs["counter." + name] = v - before[name]
+    return obs
 
 
 def run(cell, devices, *, seed, seconds, traced, t_start, control=None,
@@ -201,17 +263,18 @@ def run(cell, devices, *, seed, seconds, traced, t_start, control=None,
     import jax.numpy as jnp
 
     compiles = harness.CompileCounter()
-    cfg = model_config(cell)
+    family = cell.family
     model, tr = cell.model, cell.traffic
+    cfg = family.model_config(model, cell.harness)
     dtype = jnp.dtype(cfg.dtype)
-    params = jax.jit(lambda k: W.make_params(model, k, dtype))(
+    params = jax.jit(lambda k: family.make_params(model, k, dtype))(
         W.seed_key(seed))
     engine, server = build_replica(cell, params, cfg)
     del params
     engine.start()
     server.start()
     load = Load(server.port)
-    filler = traffic.Filler(tr, model["vocab_size"], seed)
+    filler = traffic.Filler(tr, cfg.vocab_size, seed)
     try:
         # ---- one short request per shared prefix: compiles both step
         # shapes, leaves the shared prompts in the prefix cache
@@ -286,26 +349,18 @@ def run(cell, devices, *, seed, seconds, traced, t_start, control=None,
     # ---- the window's numbers, from the clients' clocks
     ttft = [((r.times[0] if r.times else time.monotonic()) - r.due_t) * 1e3
             for r in due_in] if tr["kind"] == "open-loop" else []
-    itl, n_tok, dec_tok, dec_ctx, pre_tok, pre_len = [], 0, 0, 0.0, 0, []
-    for r in recs:
-        p = len(r.req.prompt)
-        for j, t in enumerate(r.times):
-            if not t0 <= t < t1:
-                continue
-            n_tok += 1
-            if j == 0:
-                pre_len.append(p)
-            else:
-                itl.append((t - r.times[j - 1]) * 1e3)
-                dec_tok += 1
-                dec_ctx += p + j
     seen = c_close["seen"] - c_open["seen"]
     matched = c_close["matched"] - c_open["matched"]
     steps = c_close["steps"] - c_open["steps"]
     hit = matched / seen if seen else 0.0
-    pre_tok = sum(pre_len) * (1.0 - hit)
-    pre_ctx = sum((p * (p + 1) - (hit * p) * (hit * p + 1)) / 2.0
-                  for p in pre_len)
+    itl, n_tok, served = [], 0, []
+    for r in recs:
+        inside = [j for j, t in enumerate(r.times) if t0 <= t < t1]
+        n_tok += len(inside)
+        itl += [(r.times[j] - r.times[j - 1]) * 1e3 for j in inside if j]
+        if inside:
+            served.append(Served(len(r.req.prompt), hit, inside))
+    work = family.serve_work(model, served)
     obs = {
         "setup_s": setup_s, "window_s": seconds, "steps": steps,
         "ttft_ms": ttft, "itl_ms": itl, "tokens_out": n_tok,
@@ -320,9 +375,13 @@ def run(cell, devices, *, seed, seconds, traced, t_start, control=None,
         "memory_peak_gb": peak / 1e9 if peak else None,
         "requests_due": len(due_in),
         "backlog_open": c_open["backlog"], "backlog_close": c_close["backlog"],
-        "model_flops": flops.serve_flops(model, pre_tok, pre_ctx, dec_tok,
-                                         dec_ctx, n_tok),
+        "model_flops": work["flops"], "model_bytes": work.get("bytes"),
     }
+    obs.update(window_deltas(c_open, c_close))
+
+    print(f"window: {len(due_in)} requests due, backlog "
+          f"{c_open['backlog']} -> {c_close['backlog']}, {steps} steps",
+          file=sys.stderr)
 
     # ---- correct: the served tokens against the plain reference
     checks = _compare(cell, seed, sample, obs, control)
@@ -353,7 +412,7 @@ def _pick(cands: List[Record], seed: int) -> List[Record]:
 
 
 def _compare(cell, seed, sample: List[Record], obs: dict, control) -> dict:
-    from chipbench import reference
+    family = cell.family
     model, tr = cell.model, cell.traffic
     limits = cell.harness["limits"]
     n = SAMPLE
@@ -374,8 +433,8 @@ def _compare(cell, seed, sample: List[Record], obs: dict, control) -> dict:
             print(f"answer cut short: request {r.req.slot.index} prompt "
                   f"{p} asked {r.req.max_new_tokens} got {len(r.tokens)} "
                   f"done={r.done} error={r.error!r}", file=sys.stderr)
-    x = reference.hidden_states(model, seed, tokens)
-    gaps, _ = reference.score(model, seed, x, positions, served)
+    x = family.hidden_states(model, seed, tokens)
+    gaps, _ = family.score(model, seed, x, positions, served)
     obs["served_tokens_compared"] = int((positions >= 0).sum())
     numbers = compare.served(gaps)
     obs["served_gap_stats"] = numbers
@@ -384,9 +443,9 @@ def _compare(cell, seed, sample: List[Record], obs: dict, control) -> dict:
     if control:
         obs["control"] = {}
         for q in control.split(","):
-            xq = reference.hidden_states(model, seed, tokens, q)
-            _, first = reference.score(model, seed, xq, positions, served, q)
-            cg, _ = reference.score(model, seed, x, positions,
+            xq = family.hidden_states(model, seed, tokens, q)
+            _, first = family.score(model, seed, xq, positions, served, q)
+            cg, _ = family.score(model, seed, x, positions,
                                     np.where(first >= 0, first, 0))
             obs["control"][q] = compare.served(cg)
     return checks

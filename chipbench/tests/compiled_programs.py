@@ -66,19 +66,20 @@ def train_step(chip):
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
 
-    from chipbench import harness, train_cell
+    from chipbench import harness
     from chipbench import weights as W
     from hadoop_tpu.parallel.mesh import MeshPlan, make_mesh, param_specs
     from hadoop_tpu.parallel.optimizer import AdamWState
     from hadoop_tpu.parallel.train import make_data_sharding, make_train_step
 
     cell = harness.load_cell("mistral-7b.train-4k")
-    cfg = train_cell.model_config(cell)
+    family = cell.family
+    cfg = family.model_config(cell.model, cell.harness)
     plan = MeshPlan(**cell.harness.get("mesh_plan", {}))
     mesh = make_mesh(plan, [chip])
     step = make_train_step(cfg, plan, mesh, **cell.harness["train_step"])
     shapes = jax.eval_shape(
-        lambda k: W.make_params(cell.model, k, jnp.dtype(cfg.dtype)),
+        lambda k: family.make_params(cell.model, k, jnp.dtype(cfg.dtype)),
         W.seed_key(1))
     params = jax.tree_util.tree_map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype,
@@ -110,8 +111,9 @@ def serving_steps(chip):
     from chipbench import weights as W
 
     cell = harness.load_cell("mistral-7b.serve-chat")
-    cfg = serve_cell.model_config(cell)
-    params = jax.jit(lambda k: W.make_params(
+    family = cell.family
+    cfg = family.model_config(cell.model, cell.harness)
+    params = jax.jit(lambda k: family.make_params(
         cell.model, k, jnp.dtype(cfg.dtype)))(W.seed_key(1))
     engine, server = serve_cell.build_replica(cell, params, cfg)
     one = SingleDeviceSharding(chip)

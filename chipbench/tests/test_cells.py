@@ -13,6 +13,12 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 REHEARSAL = "chipbench/tests/rehearsal/BENCHMARK.json"
+# read from the engine's own record: window deltas of its phases, its TTFT
+# stages, its iteration histogram and two counters of its registry
+ENGINE = {"engine.queue_wait_ms.chat", "engine.prefill_wait_ms.chat",
+          "engine.prefill_service_ms.chat", "engine.step_busy_ms.chat",
+          "engine.host_ms_per_step.chat", "engine.iter_max_ms.chat",
+          "engine.attn_live_page_share.chat"}
 
 
 def run_cell(workload, *more, cwd=ROOT, seed=3000000007):
@@ -47,6 +53,17 @@ def test_rehearsal_runs_end_to_end_and_names_the_cpu(workload, trace):
         assert line["metrics"]
         assert all(m["value"] == 0 for k, m in line["metrics"].items()
                    if k.startswith("compile.in_window"))
+    if trace and workload != "tiny.train":
+        got = {k: m["value"] for k, m in line["metrics"].items()}
+        assert ENGINE <= set(got)
+        assert all(got[k] >= 0 for k in ENGINE)
+        # host time is part of busy time; the longest iteration reads as
+        # the upper bound of its bucket; live pages are some of all pages
+        assert got["engine.host_ms_per_step.chat"] \
+            <= got["engine.step_busy_ms.chat"]
+        assert got["engine.iter_max_ms.chat"] in [
+            0.25 * 2 ** i for i in range(20)]
+        assert 0 < got["engine.attn_live_page_share.chat"] <= 100
     for name, c in line["check"].items():
         assert c["value"] <= c["limit"], name
     assert p.stderr.strip().splitlines()[-1].startswith("correct=True")

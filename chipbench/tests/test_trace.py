@@ -94,7 +94,9 @@ class _Dev:
 
 def _tracer_with(events, monkeypatch, tmp_path):
     from chipbench import harness
+    from chipbench import scopes
     monkeypatch.setattr(trace, "load_events", lambda d: events)
+    monkeypatch.setattr(scopes, "load_dir", lambda d: events)
     t = harness.Tracer(True)
     t.dir = str(tmp_path / "trace")
     return t
@@ -109,6 +111,19 @@ def test_a_tpu_run_whose_trace_lacks_a_chip_prints_no_result(
     t = _tracer_with(events, monkeypatch, tmp_path)
     with pytest.raises(SystemExit):
         t.reduce([_Dev("tpu")] * chips)
+
+
+def test_the_reduction_keeps_modules_and_scopes_for_the_readers(
+        monkeypatch, tmp_path):
+    import json
+    import os
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "scopes_small.json")) as f:
+        events = json.load(f)["events"]
+    red = _tracer_with(events, monkeypatch, tmp_path).reduce([_Dev("tpu")])
+    assert red["busy_s"] > 0 and red["device_ops"]
+    assert {"train_step", "_step_impl"} <= set(red["scopes"]["modules"])
+    assert red["scopes"]["scopes"]["attn"] > 0
 
 
 def test_the_cpu_rehearsal_reads_no_trace_numbers(monkeypatch, tmp_path):

@@ -11,26 +11,10 @@ from __future__ import annotations
 
 import time
 
-from chipbench import compare, flops, harness, traffic
+from chipbench import compare, harness, traffic
 from chipbench import weights as W
 
 REF_STEPS = 3
-
-
-def model_config(cell: harness.Cell):
-    from hadoop_tpu.models.config import ModelConfig
-    m = cell.model
-    experts = m.get("num_local_experts", 0)
-    return ModelConfig(
-        family="mixtral" if experts else "llama",
-        vocab_size=m["vocab_size"], d_model=m["hidden_size"],
-        n_layers=m["num_hidden_layers"], n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"], d_ff=m["intermediate_size"],
-        max_seq=cell.harness["context"], rope_theta=m["rope_theta"],
-        norm_eps=m["rms_norm_eps"], tie_embeddings=m["tie_word_embeddings"],
-        n_experts=experts, top_k=m.get("num_experts_per_tok", 2),
-        capacity_factor=cell.harness.get("moe_capacity_factor", 1.25),
-        dtype=m["torch_dtype"])
 
 
 def _flat(tree) -> dict:
@@ -52,8 +36,9 @@ def run(cell, devices, *, seed, seconds, traced, t_start, control=None,
                                            make_train_step)
 
     compiles = harness.CompileCounter()
-    cfg = model_config(cell)
+    family = cell.family
     model, tr = cell.model, cell.traffic
+    cfg = family.model_config(model, cell.harness)
     plan = MeshPlan(**cell.harness.get("mesh_plan", {}))
     mesh = make_mesh(plan, devices)
     step = make_train_step(cfg, plan, mesh, **cell.harness["train_step"])
@@ -65,7 +50,7 @@ def run(cell, devices, *, seed, seconds, traced, t_start, control=None,
     @jax.jit
     def init(key):
         params = jax.lax.with_sharding_constraint(
-            W.make_params(model, key, dtype), shard)
+            family.make_params(model, key, dtype), shard)
         zeros = lambda: jax.lax.with_sharding_constraint(   # noqa: E731
             jax.tree_util.tree_map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params), shard)
@@ -74,7 +59,7 @@ def run(cell, devices, *, seed, seconds, traced, t_start, control=None,
 
     params, opt = init(key)
     rows = tr["batch_per_chip"] * len(devices)
-    host_batches = traffic.packed_batches(tr, model["vocab_size"], seed,
+    host_batches = traffic.packed_batches(tr, cfg.vocab_size, seed,
                                           rows)
     ds = make_data_sharding(mesh)
     batches = [(jax.device_put(t, ds), jax.device_put(g, ds))
@@ -91,7 +76,7 @@ def run(cell, devices, *, seed, seconds, traced, t_start, control=None,
     prog["losses"] = [float(x) for x in prog["losses"]]
     prog["grad1"] = {k: v / (1.0 - reference.B1)
                      for k, v in _flat(mu1).items()}
-    prog["delta"] = _program_delta(params, model, key, dtype)
+    prog["delta"] = _program_delta(params, family, model, key, dtype)
     jax.block_until_ready((params, opt))
     setup_s = time.monotonic() - t_start
 
@@ -122,7 +107,7 @@ def run(cell, devices, *, seed, seconds, traced, t_start, control=None,
     # ---- the reference follows the first steps
     ref_batches = [(jnp.asarray(t), jnp.asarray(g))
                    for t, g in host_batches[:REF_STEPS]]
-    ref = _follow(model, seed, ref_batches, None)
+    ref = _follow(family, model, seed, ref_batches, None)
     numbers = compare.training(prog, ref)
     _leaf_table(prog, ref)
     limits = cell.harness["limits"]
@@ -134,19 +119,20 @@ def run(cell, devices, *, seed, seconds, traced, t_start, control=None,
         "tokens": n * rows * tr["seq_len"],
         "tokens_per_chip": n * rows * tr["seq_len"] / len(devices),
         "model_flops": n * rows * tr["seq_len"]
-        * flops.train_flops_per_token(model, tr["seq_len"]),
+        * family.train_flops_per_token(model, tr["seq_len"]),
         "compiles_in_window": in_window,
         "memory_peak_gb": peak / 1e9 if peak else None,
         "losses": prog["losses"], "last_loss": last_loss,
     }
     if control:
         obs["control"] = {
-            q: compare.training(_follow(model, seed, ref_batches, q), ref)
+            q: compare.training(_follow(family, model, seed, ref_batches, q),
+                                ref)
             for q in control.split(",")}
     if fault == "half":     # the reference in the program's place, with
         # half of each row left out of the loss
         obs["fault_half"] = compare.training(
-            _follow(model, seed, ref_batches, None, keep=0.5), ref)
+            _follow(family, model, seed, ref_batches, None, keep=0.5), ref)
     return harness.Outcome(obs, checks, attempted=n, failed=0,
                            devices=devices, trace=trace,
                            memory_peak_bytes=peak)
@@ -163,14 +149,12 @@ def _leaf_table(prog: dict, ref: dict) -> None:
               file=sys.stderr)
 
 
-def _follow(model, seed, batches, quant, keep=1.0) -> dict:
-    from chipbench import reference
-    losses, grad1, delta = reference.follow(model, seed, batches, quant,
-                                            keep)
+def _follow(family, model, seed, batches, quant, keep=1.0) -> dict:
+    losses, grad1, delta = family.follow(model, seed, batches, quant, keep)
     return {"losses": losses, "grad1": _flat(grad1), "delta": _flat(delta)}
 
 
-def _program_delta(params, model, key, dtype) -> dict:
+def _program_delta(params, family, model, key, dtype) -> dict:
     """Per-leaf norm of what the first steps changed: each leaf against
     its value at the seed, regenerated alone so nothing large is held.
     The regenerated leaf is the OUTPUT of one program and the input of
@@ -188,8 +172,8 @@ def _program_delta(params, model, key, dtype) -> dict:
 
     out = {}
     leaves = jax.tree_util.tree_flatten_with_path(params)[0]
-    for (path, leaf), names in zip(leaves, W.leaf_paths(model)):
-        regen = jax.jit(
-            lambda key, names=names: W.make_leaf(model, key, names, dtype))
+    for (path, leaf), names in zip(leaves, family.leaf_paths(model)):
+        regen = jax.jit(lambda key, names=names: family.make_leaf(
+            model, key, names, dtype))
         out[jax.tree_util.keystr(path)] = float(gap(leaf, regen(key)))
     return out

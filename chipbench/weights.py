@@ -1,15 +1,19 @@
-"""Weights from ``--seed``, made by the benchmark and handed to the program.
+"""Weights from ``--seed``: what every family (``chipbench/families/``)
+makes its leaves from. Nothing here knows an architecture, and nothing
+imports the program: the reference regenerates the same values from the
+same seed, one layer at a time, after the program's state is gone. Every
+matrix has a key of its own — ``fold_in(fold_in(seed_key, crc32(leaf)),
+index)`` with ``index`` the layer (times the expert count, plus the
+expert, for an expert stack) — so a stack is a sequential map over
+matrices and the largest float32 transient is one matrix.
 
-The tree has the layout ``hadoop_tpu.models.decoder`` takes (layer-stacked
-leaves), but nothing here imports the program: the reference regenerates
-the same values from the same seed, one layer at a time, after the
-program's state is gone. Every matrix has a key of its own —
-``fold_in(fold_in(seed_key, crc32(leaf)), index)`` with ``index`` the layer
-(times the expert count, plus the expert, for an expert stack) — so a
-stack is a sequential map over matrices and the largest float32 transient
-is one matrix.
-
-``model`` is the configuration file's ``model`` group (HF key names).
+A family describes its leaves in *tables*: ``name -> (matrix shape,
+fan_in, matrices per layer)``, ``fan_in`` ``None`` marking a norm vector.
+``stack`` / ``one_layer`` / ``stacked_leaf`` make a run of layers that
+share one table, ``flat`` the leaves that stand alone; a family whose
+layers differ calls them once per run of like layers, with the run's
+first layer as ``start``, and lays the results out as its program takes
+them.
 """
 
 from __future__ import annotations
@@ -51,44 +55,6 @@ def freeze(model: dict) -> tuple:
         if isinstance(v, (int, float, str, bool, type(None)))))
 
 
-def dims(model: dict) -> dict:
-    d = model["hidden_size"]
-    hq = model["num_attention_heads"]
-    return {"D": d, "Hq": hq, "Hkv": model["num_key_value_heads"],
-            "Dh": model.get("head_dim") or d // hq,
-            "F": model["intermediate_size"], "V": model["vocab_size"],
-            "L": model["num_hidden_layers"],
-            "E": model.get("num_local_experts", 0)}
-
-
-def layer_leaves(model: dict) -> dict:
-    """name -> (matrix shape, fan_in, matrices per layer). fan_in None
-    marks a norm vector."""
-    m = dims(model)
-    d, f, e = m["D"], m["F"], m["E"]
-    qo, kv = m["Hq"] * m["Dh"], m["Hkv"] * m["Dh"]
-    leaves = {
-        "attn_norm_w": ((d,), None, 1),
-        "wq": ((d, qo), d, 1), "wk": ((d, kv), d, 1),
-        "wv": ((d, kv), d, 1), "wo": ((qo, d), qo, 1),
-        "mlp_norm_w": ((d,), None, 1),
-    }
-    per = e or 1
-    if e:
-        leaves["router"] = ((d, e), d, 1)
-    leaves["w_gate"] = ((d, f), d, per)
-    leaves["w_up"] = ((d, f), d, per)
-    leaves["w_down"] = ((f, d), f, per)
-    return leaves
-
-
-def top_leaves(model: dict) -> dict:
-    m = dims(model)
-    return {"embed": ((m["V"], m["D"]), m["D"], 1),
-            "final_norm_w": ((m["D"],), None, 1),
-            "lm_head": ((m["D"], m["V"]), m["D"], 1)}
-
-
 def _matrix(key, index, shape, fan_in, dtype):
     bits = jax.random.bits(jax.random.fold_in(key, index), shape,
                            jnp.uint32)
@@ -107,12 +73,12 @@ def _stack(key, start, count, shape, fan_in, dtype):
         start + jnp.arange(count))
 
 
-def layer_params(model: dict, key, layer, dtype) -> dict:
+def one_layer(table: dict, key, layer, dtype) -> dict:
     """One layer's leaves (no leading layer axis; an expert stack keeps
     its expert axis). ``key`` is ``seed_key(seed)``; it and ``layer``
     may be traced, so one compiled program serves every seed."""
     out = {}
-    for name, (shape, fan_in, per) in layer_leaves(model).items():
+    for name, (shape, fan_in, per) in table.items():
         k = _leaf_key(key, name)
         if per == 1:
             out[name] = _matrix(k, layer, shape, fan_in, dtype)
@@ -121,44 +87,23 @@ def layer_params(model: dict, key, layer, dtype) -> dict:
     return out
 
 
-def top_params(model: dict, key, dtype) -> dict:
+def flat(table: dict, key, dtype) -> dict:
+    """Leaves that stand alone (an embedding, a head): matrix 0 of each."""
     return {name: _matrix(_leaf_key(key, name), 0, shape, fan_in, dtype)
-            for name, (shape, fan_in, _) in top_leaves(model).items()}
+            for name, (shape, fan_in, _) in table.items()}
 
 
-def make_params(model: dict, key, dtype) -> dict:
-    """The whole layer-stacked tree from ``seed_key(seed)``. Call under
-    ``jax.jit`` (with the program's shardings as ``out_shardings`` where
-    it has a mesh)."""
-    n_layers = dims(model)["L"]
-    layers = {}
-    for name, (shape, fan_in, per) in layer_leaves(model).items():
-        k = _leaf_key(key, name)
-        flat = _stack(k, 0, n_layers * per, shape, fan_in, dtype)
-        if per > 1:
-            flat = flat.reshape((n_layers, per) + shape)
-        layers[name] = flat
-    tree = top_params(model, key, dtype)
-    tree["layers"] = layers
-    return tree
+def stacked_leaf(table: dict, key, name: str, n_layers: int, dtype,
+                 start: int = 0):
+    """Layers ``start .. start+n_layers`` of one leaf, stacked."""
+    shape, fan_in, per = table[name]
+    out = _stack(_leaf_key(key, name), start * per, n_layers * per, shape,
+                 fan_in, dtype)
+    return out.reshape((n_layers, per) + shape) if per > 1 else out
 
 
-def make_leaf(model: dict, key, path: tuple, dtype):
-    """One leaf of ``make_params``'s tree, alone: ``("embed",)`` or
-    ``("layers", "wq")``."""
-    if path[0] != "layers":
-        shape, fan_in, _ = top_leaves(model)[path[0]]
-        return _matrix(_leaf_key(key, path[0]), 0, shape, fan_in, dtype)
-    shape, fan_in, per = layer_leaves(model)[path[1]]
-    n_layers = dims(model)["L"]
-    flat = _stack(_leaf_key(key, path[1]), 0, n_layers * per, shape,
-                  fan_in, dtype)
-    return flat.reshape((n_layers, per) + shape) if per > 1 else flat
-
-
-def leaf_paths(model: dict):
-    """Paths of ``make_params``'s leaves in tree-flatten order."""
-    paths = [("embed",), ("final_norm_w",)]
-    paths += [("layers", n) for n in sorted(layer_leaves(model))]
-    paths.append(("lm_head",))
-    return paths
+def stack(table: dict, key, n_layers: int, dtype, start: int = 0) -> dict:
+    """Every leaf of the table for layers ``start .. start+n_layers``,
+    layer-stacked. Call under ``jax.jit``."""
+    return {name: stacked_leaf(table, key, name, n_layers, dtype, start)
+            for name in table}
