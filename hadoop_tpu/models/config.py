@@ -16,7 +16,7 @@ class ModelConfig:
     explicitly so tiny test/dryrun configs and real configs share one code
     path (static shapes only — required for XLA).
     """
-    family: str = "llama"            # gpt2 | llama | mixtral
+    family: str = "llama"   # gpt2 | llama | mixtral | deepseek_v32
     vocab_size: int = 32000
     d_model: int = 4096
     n_layers: int = 32
@@ -36,6 +36,38 @@ class ModelConfig:
     top_k: int = 2
     capacity_factor: float = 1.25
     dtype: str = "bfloat16"          # activations/params compute dtype
+    # ---- family "deepseek_v32" (serving only): latent attention (MLA)
+    # with a learned sparse selection, leading dense layers before the
+    # expert layers, a sigmoid group-limited router wider than the
+    # experts this replica holds, and YaRN frequencies. ``n_experts`` is
+    # then the number of routed experts HELD here (``experts_from ..
+    # experts_from + n_experts`` of the router's ``n_routed_experts``),
+    # ``d_ff`` the dense layers' MLP width and ``d_ff_expert`` an expert's.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    n_dense_layers: int = 0          # leading layers with a dense MLP
+    d_ff_expert: int = 0
+    n_shared_experts: int = 0
+    n_routed_experts: int = 0        # the router's width
+    experts_from: int = 0            # first routed expert held here
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    rope_factor: float = 1.0         # YaRN; 1.0 = plain theta
+    rope_original_max_seq: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+
+    def __post_init__(self):
+        if self.family == "deepseek_v32":
+            _validate_deepseek_v32(self)
 
     @property
     def head_dim(self) -> int:
@@ -48,6 +80,43 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+
+def _validate_deepseek_v32(c: ModelConfig) -> None:
+    """Every field the family needs, checked where the config is made:
+    a missing width must not surface as a shape error inside a jit."""
+    def need(ok: bool, what: str) -> None:
+        if not ok:
+            raise ValueError(f"family='deepseek_v32': {what}")
+    for name in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                 "qk_rope_head_dim", "v_head_dim", "index_n_heads",
+                 "index_head_dim", "index_topk", "d_ff_expert",
+                 "n_routed_experts", "n_experts"):
+        need(getattr(c, name) > 0, f"{name} must be set (> 0)")
+    need(c.qk_rope_head_dim % 2 == 0 and
+         c.index_head_dim > c.qk_rope_head_dim,
+         "qk_rope_head_dim must be even and below index_head_dim (the "
+         "index key is a rotary part followed by a plain one)")
+    need(0 <= c.n_dense_layers <= c.n_layers,
+         f"n_dense_layers={c.n_dense_layers} outside 0..n_layers")
+    need(0 <= c.experts_from and
+         c.experts_from + c.n_experts <= c.n_routed_experts,
+         f"experts_from={c.experts_from} + n_experts={c.n_experts} "
+         f"exceeds n_routed_experts={c.n_routed_experts}")
+    need(c.n_routed_experts % c.n_group == 0 and
+         1 <= c.topk_group <= c.n_group,
+         f"n_group={c.n_group} must divide n_routed_experts and "
+         f"topk_group={c.topk_group} lie in 1..n_group")
+    need(c.top_k <= c.topk_group * (c.n_routed_experts // c.n_group),
+         f"top_k={c.top_k} exceeds the experts of topk_group groups")
+    need(c.n_routed_experts // c.n_group >= 2,
+         "a group needs two experts (its score is the sum of its two "
+         "largest)")
+    need(c.use_rope and c.use_rmsnorm and c.use_swiglu
+         and not c.tie_embeddings,
+         "RoPE, RMSNorm, SwiGLU and an untied head are the architecture")
+    need(c.rope_factor == 1.0 or c.rope_original_max_seq > 0,
+         "rope_original_max_seq must be set when rope_factor != 1")
 
 
 def _gpt2(**kw) -> ModelConfig:
@@ -95,6 +164,19 @@ PRESETS = {
                             n_layers=2, n_heads=4, n_kv_heads=2, d_ff=128,
                             max_seq=128, n_experts=4, top_k=2,
                             dtype="float32", rope_theta=10000.0),
+    # the latent-attention / sparse-selection / held-experts family at
+    # test size: 1 dense + 2 expert layers, 8 of 32 routed experts held
+    "tiny-dsv32": ModelConfig(
+        family="deepseek_v32", vocab_size=256, d_model=64, n_layers=3,
+        n_heads=4, n_kv_heads=1, d_ff=128, max_seq=256, norm_eps=1e-6,
+        rope_theta=10000.0, dtype="float32", n_experts=8, top_k=4,
+        q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, index_n_heads=4,
+        index_head_dim=16, index_topk=16, n_dense_layers=1,
+        d_ff_expert=32, n_shared_experts=1, n_routed_experts=32,
+        experts_from=0, n_group=4, topk_group=2,
+        routed_scaling_factor=2.5, rope_factor=40.0,
+        rope_original_max_seq=32, rope_mscale=1.0),
     "tiny-gpt2": _gpt2(vocab_size=256, d_model=64, n_layers=2, n_heads=4,
                        n_kv_heads=4, d_ff=256, max_seq=128, dtype="float32"),
 }

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from hadoop_tpu.models.config import ModelConfig
+from hadoop_tpu.models.deepseek import refuse_training
 from hadoop_tpu.ops import (apply_rope, causal_attention, gelu, layer_norm,
                             rms_norm, rope_frequencies, swiglu)
 
@@ -99,6 +100,7 @@ SINGLE = ParallelCtx()
 
 def init_params(rng: jax.Array, cfg: ModelConfig) -> Dict[str, Any]:
     """Initialize the full (unsharded) parameter pytree."""
+    refuse_training(cfg, "models.decoder.init_params")
     k_embed, k_layers, k_head, k_pos = jax.random.split(rng, 4)
     dt = cfg.jax_dtype
     D, L, F, V = cfg.d_model, cfg.n_layers, cfg.d_ff, cfg.vocab_size
@@ -585,6 +587,7 @@ def forward_hidden(params, tokens, cfg: ModelConfig,
     ``sync_state`` (relaxed stale sync schedules only) threads the
     previous step's corrections through ``run_layers``; when given the
     return is ``(h, new_sync_state)``."""
+    refuse_training(cfg, "models.decoder.forward_hidden")
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
     h = embed_tokens(params, tokens, cfg, ctx)
     if sync_state is not None:

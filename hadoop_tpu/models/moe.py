@@ -14,6 +14,16 @@ MLP output is 0, residual passes through) — standard capacity semantics.
 The single-device path uses the identical dispatch math with a local
 expert stack, so parallel-vs-reference tests match bit-for-bit.
 
+A second router serves one chip's share of a wider expert-parallel
+deployment (``family="deepseek_v32"``): :func:`route_grouped` scores
+ALL ``n_routed_experts`` with a sigmoid, chooses by score plus a
+correction bias inside the best ``topk_group`` of ``n_group`` groups,
+and weights the chosen by their renormalised scores times
+``routed_scaling_factor``; :func:`moe_share` computes every assignment
+to an expert held here (``experts_from .. experts_from + n_experts``) —
+no capacity, no token dropped — plus the shared expert, and returns that
+partial sum. Assignments to experts held elsewhere are theirs to add.
+
 The serving engine's fused step reuses :func:`route` and
 :func:`_expert_ffn` directly (serving/engine.py ``_moe_mlp``) — the
 capacity padding is what keeps the step's shapes static, so serving
@@ -110,3 +120,57 @@ def moe_mlp(h: jnp.ndarray, lp, cfg: ModelConfig, ctx) -> jnp.ndarray:
     y2d = jnp.einsum("tec,ecd->td", combine.astype(jnp.float32),
                      ye.astype(jnp.float32))
     return y2d.reshape(B, S, D).astype(dtype)
+
+
+def route_grouped(x2d: jnp.ndarray, router_w: jnp.ndarray,
+                  router_bias: jnp.ndarray, cfg: ModelConfig):
+    """Sigmoid, group-limited top-k over the whole router.
+
+    x2d ``[T, D]``, router_w ``[D, N]``, router_bias ``[N]`` (the
+    correction bias: it moves the CHOICE, never the weights). Returns
+    (``idx [T, K]`` chosen experts of ``N = n_routed_experts``,
+    ``w [T, K]`` float32 weights). Scores in float32."""
+    n, g = cfg.n_routed_experts, cfg.n_group
+    scores = jax.nn.sigmoid(jnp.dot(x2d, router_w,
+                                    preferred_element_type=jnp.float32))
+    biased = (scores + router_bias.astype(jnp.float32)).reshape(-1, g, n // g)
+    group_score = jnp.sum(jax.lax.top_k(biased, 2)[0], axis=-1)    # [T, g]
+    _, best = jax.lax.top_k(group_score, cfg.topk_group)
+    keep = jnp.sum(jax.nn.one_hot(best, g, dtype=jnp.int32), axis=1) > 0
+    inside = jnp.where(keep[:, :, None], biased, -jnp.inf).reshape(-1, n)
+    _, idx = jax.lax.top_k(inside, cfg.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / jnp.sum(w, axis=-1, keepdims=True) * cfg.routed_scaling_factor
+    return idx, w
+
+
+@jax.named_scope("moe")
+def moe_share(x2d: jnp.ndarray, lp, cfg: ModelConfig, valid=None):
+    """This replica's share of the expert layer for rows ``x2d [T, D]``:
+    ``sum_i w_i E_i(x)`` over the chosen experts HELD here plus the
+    shared expert. Static shapes by running each held expert over all
+    ``T`` rows with a zero weight where it was not chosen — every
+    assignment is computed, none dropped. ``lp``: ``router [D, N]``,
+    ``router_bias [N]``, ``w_gate/w_up [E, D, F]``, ``w_down [E, F, D]``,
+    and the shared expert's ``ws_gate/ws_up [D, Fs]``, ``ws_down [Fs, D]``.
+    Returns (``y [T, D]``, ``stats`` int32 ``[2]``: assignments to held
+    experts and held experts hit, over the rows ``valid`` marks)."""
+    e, lo = cfg.n_experts, cfg.experts_from
+    idx, w = route_grouped(x2d, lp["router"], lp["router_bias"], cfg)
+    local = idx - lo
+    held = (local >= 0) & (local < e)
+    # [T, K, E]: which held expert each assignment fell on (none: zeros)
+    hot = jax.nn.one_hot(jnp.where(held, local, e), e + 1,
+                         dtype=jnp.float32)[..., :e]
+    gate = jnp.sum(hot * w[..., None], axis=1)                      # [T, E]
+    hidden = swiglu(jnp.einsum("td,edf->etf", x2d, lp["w_gate"]),
+                    jnp.einsum("td,edf->etf", x2d, lp["w_up"]))
+    ye = jnp.einsum("etf,efd->etd", hidden, lp["w_down"])
+    y = jnp.einsum("te,etd->td", gate, ye.astype(jnp.float32))
+    shared = swiglu(x2d @ lp["ws_gate"], x2d @ lp["ws_up"]) @ lp["ws_down"]
+    y = (y + shared.astype(jnp.float32)).astype(x2d.dtype)
+    if valid is not None:
+        hot = hot * valid[:, None, None]
+    per_expert = jnp.sum(hot, axis=(0, 1))
+    stats = jnp.stack([jnp.sum(per_expert), jnp.sum(per_expert > 0)])
+    return y, stats.astype(jnp.int32)

@@ -14,7 +14,8 @@ control flow on traced values.
 
 from hadoop_tpu.ops.activations import swiglu, gelu
 from hadoop_tpu.ops.norms import rms_norm, layer_norm
-from hadoop_tpu.ops.rope import apply_rope, rope_frequencies
+from hadoop_tpu.ops.rope import (apply_rope, rope_frequencies,
+                                 yarn_frequencies, yarn_mscale)
 from hadoop_tpu.ops.attention import causal_attention
 from hadoop_tpu.ops.cross_entropy import (
     softmax_cross_entropy,
@@ -28,6 +29,8 @@ __all__ = [
     "layer_norm",
     "apply_rope",
     "rope_frequencies",
+    "yarn_frequencies",
+    "yarn_mscale",
     "causal_attention",
     "softmax_cross_entropy",
     "vocab_parallel_cross_entropy",

@@ -33,6 +33,7 @@ from hadoop_tpu.models.decoder import (embed_tokens, final_hidden,
                                        forward_hidden, head_matrix,
                                        run_layers)
 from hadoop_tpu.models.decoder import init_params as _init_params
+from hadoop_tpu.models.deepseek import refuse_training
 from hadoop_tpu.ops import rope_frequencies
 from hadoop_tpu.ops.cross_entropy import chunked_lm_cross_entropy
 from hadoop_tpu.parallel.mesh import AXES, MeshPlan, param_specs, \
@@ -168,6 +169,7 @@ def make_train_step(cfg: ModelConfig, plan: MeshPlan, mesh: Mesh, *,
     pass's bucketed collectives, so they require ``overlap.enabled``
     (the default).
     """
+    refuse_training(cfg, "parallel.train.make_train_step")
     if overlap is None:
         overlap = DEFAULT_OVERLAP
     if parity is None:

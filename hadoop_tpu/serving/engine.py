@@ -141,6 +141,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from hadoop_tpu.models import deepseek as _dsv32
 from hadoop_tpu.models.config import ModelConfig
 from hadoop_tpu.models.decoder import _norm, head_matrix
 # MoE serving shares models/moe.py's dispatch math verbatim — the
@@ -207,7 +208,7 @@ def _shard_expert_stacks(params, shards: int):
             layers[k] = jax.device_put(leaf, spec)
     out = dict(params)
     out["layers"] = layers
-    return out
+    return out, NamedSharding(mesh, P())
 
 
 # fixed-shape page movers for the cold tiers: one trace each for the
@@ -404,6 +405,25 @@ class DecodeEngine:
                  moe_a2a_codec: str = "int8",
                  plan=None, metrics=None, tracer=None):
         self.cfg = cfg
+        # ---- family "deepseek_v32": latent pages + index-key pages in
+        # the two pools, per-kind layer stacks (models/deepseek.py). The
+        # planes it is not built for refuse here, by conf key — never a
+        # silent wrong layout.
+        self._dsa = cfg.family == _dsv32.FAMILY
+        if self._dsa:
+            for bad, key in (
+                    (is_quantized_tree(params), "serving.parity=relaxed"),
+                    (plan is not None, "a tp plan (serving.tp)"),
+                    (kv_host_bytes, "serving.kv.host.bytes"),
+                    (kv_store_fs is not None, "serving.kv.dfs.enable"),
+                    (speculate_k, "serving.speculate.k"),
+                    (int(moe_shards) > 1, "serving.moe.shards")):
+                if bad:
+                    raise NotImplementedError(
+                        f"family={cfg.family!r} does not serve under "
+                        f"{key}: the latent/index-key page layout, the "
+                        "sparse selection and the held-expert layer have "
+                        "no such path yet")
         # ---- expert plane (MoE checkpoints): the fused step routes
         # every row through models/moe.py's capacity-padded one-hot
         # dispatch, so the static row count pins the capacity and the
@@ -453,12 +473,28 @@ class DecodeEngine:
         self.expert_shards = expert_shard_count(
             cfg.n_experts, int(moe_shards),
             jax.local_device_count()) if cfg.is_moe else 0
+        if self._dsa:
+            # a share of a wider router is held by one chip: nothing of
+            # it is split over this replica's devices
+            self.expert_shards = 1
+        # where the step's carried buffers live from the first call on:
+        # beside expert stacks split over local chips a step hands the
+        # pools and the lane state back replicated over that mesh, and a
+        # second call with them so placed would trace the shape again
+        self._carry_sharding = None
         if cfg.is_moe and self.expert_shards > 1:
-            params = _shard_expert_stacks(params, self.expert_shards)
+            params, self._carry_sharding = _shard_expert_stacks(
+                params, self.expert_shards)
         self.hbm_bytes = int(hbm_bytes or 0)
         kv_itemsize = jnp.dtype(cfg.jax_dtype).itemsize
-        self.block_nbytes = (2 * cfg.n_layers * block_size *
-                             cfg.n_kv_heads * cfg.head_dim * kv_itemsize)
+        if self._dsa:
+            # a token is one latent and one index key a layer
+            self.block_nbytes = (cfg.n_layers * block_size * kv_itemsize *
+                                 (_dsv32.latent_width(cfg)
+                                  + cfg.index_head_dim))
+        else:
+            self.block_nbytes = (2 * cfg.n_layers * block_size *
+                                 cfg.n_kv_heads * cfg.head_dim * kv_itemsize)
         if self.hbm_bytes:
             # capacity = budget minus what the weights measurably
             # occupy; lanes sized so each can hold a full context
@@ -490,9 +526,13 @@ class DecodeEngine:
         self.tracer = tracer or global_tracer()
         # the tier manager owns the radix index and the cold tiers;
         # the engine stays the device owner (extract/inject below)
+        # (the latent family has no cold tier — refused above — so the
+        # tier manager sees its page only as a layout to salt the chain)
         self.kvstore = TieredKVCache(
-            self.pool, layers=cfg.n_layers, kv_heads=cfg.n_kv_heads,
-            head_dim=cfg.head_dim, dtype=cfg.jax_dtype,
+            self.pool, layers=cfg.n_layers,
+            kv_heads=1 if self._dsa else cfg.n_kv_heads,
+            head_dim=_dsv32.latent_width(cfg) + cfg.index_head_dim
+            if self._dsa else cfg.head_dim, dtype=cfg.jax_dtype,
             enabled=prefix_cache, host_bytes=kv_host_bytes,
             fs=kv_store_fs, dfs_dir=kv_store_dir,
             dfs_min_refs=kv_dfs_min_refs, codec=kv_codec,
@@ -514,6 +554,10 @@ class DecodeEngine:
 
         L, hkv, dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
         self._pool_shape = (L, num_blocks, block_size, hkv, dh)
+        if self._dsa:
+            # two pools, one block table: latents in the K slot, index
+            # keys in the V slot
+            self._pool_shape = (L, num_blocks, block_size)
         self._kv_sharding = None
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -611,6 +655,11 @@ class DecodeEngine:
         prompts at least ``plane.min_tokens`` long route to it from
         ``submit`` instead of the fused-step path. Caller is the
         relaxed-tier gate (``longctx_plane_from_conf`` re-validates)."""
+        if self._dsa:
+            raise NotImplementedError(
+                f"family={self.cfg.family!r} does not serve under "
+                "serving.longctx.enable: the long-context plane pages "
+                "per-head K/V, not latents and index keys")
         self._relaxed_longctx = plane
 
         def wake() -> None:
@@ -656,6 +705,8 @@ class DecodeEngine:
     def _rope_tables(self):
         if not self.cfg.use_rope:
             return None, None
+        if self._dsa:
+            return _dsv32.rope_tables(self.cfg)
         return rope_frequencies(self.cfg.head_dim, self.cfg.max_seq,
                                 self.cfg.rope_theta)
 
@@ -852,9 +903,22 @@ class DecodeEngine:
         # the scan; the hardware runs it n_layers times per step — the
         # MoE a2a sites record honest per-step executions/bytes
         from hadoop_tpu.obs.comm import comm_scale
-        with comm_scale(cfg.n_layers):
-            h, (kp, vp) = jax.lax.scan(layer, h,
-                                       (params["layers"], kp, vp))
+        if self._dsa:
+            # a scan per run of like layers over per-kind stacks; the
+            # rows in table-sharing groups: each lane's row(s) by the
+            # lane's table, the chunk's rows by one table
+            groups = [(0, tables_s, lens[:B * G].reshape(B, G))]
+            if chunk is not None:
+                groups.append((B * G, tables_s[c_slot][None, :],
+                               lens[B * G:][None, :]))
+            h, kp, vp, moe_stats = _dsv32.run_layers(
+                params, h, kp, vp, cfg,
+                {"pos": pos, "blk": blk, "off": off, "active": active,
+                 "groups": groups, "cos": cos, "sin": sin})
+        else:
+            with comm_scale(cfg.n_layers):
+                h, (kp, vp) = jax.lax.scan(layer, h,
+                                           (params["layers"], kp, vp))
         with jax.named_scope("head_sample"):
             h = _norm(h, params["final_norm_w"], params.get("final_norm_b"),
                       cfg)
@@ -966,6 +1030,14 @@ class DecodeEngine:
                 [out, n_emit[:, None], finished.astype(jnp.int32)[:, None],
                  accept[:, None]],
                 axis=1)                                         # [B, G + 3]
+            if self._dsa:
+                # the expert layers' own count rides the same read-back:
+                # two more columns, whose first row holds the step's
+                # assignments to held experts and held experts hit
+                packed = jnp.concatenate(
+                    [packed,
+                     jnp.zeros((B, 2), jnp.int32).at[0].set(moe_stats)],
+                    axis=1)
         if chunk is None:
             return kp, vp, new_state, packed
         return kp, vp, new_state, packed, c_first
@@ -1094,7 +1166,12 @@ class DecodeEngine:
             "expert_shards": self.expert_shards,
             "expert_bytes": self.expert_bytes,
         }
-        if self.cfg.is_moe:
+        if self._dsa:
+            # a share of a wider router: no capacity (every assignment
+            # to a held expert is computed), no exchange on one chip
+            plane["experts_routed"] = self.cfg.n_routed_experts
+            plane["experts_from"] = self.cfg.experts_from
+        elif self.cfg.is_moe:
             plane["expert_capacity"] = moe_capacity(
                 self.max_batch * (self.spec_k + 1), self._moe_cfg)
             plane["a2a_codec"] = self._moe_a2a_codec
@@ -1408,11 +1485,20 @@ class DecodeEngine:
     def _fresh_kv_pools(self):
         """Zeroed paged K/V pools, sharded when the engine owns a mesh
         — construction and the failed-step recovery path share it."""
+        if self._dsa:
+            cfg = self.cfg
+            return (jnp.zeros(self._pool_shape
+                              + (_dsv32.latent_width(cfg),), cfg.jax_dtype),
+                    jnp.zeros(self._pool_shape + (cfg.index_head_dim,),
+                              cfg.jax_dtype))
         kp = jnp.zeros(self._pool_shape, self.cfg.jax_dtype)
         vp = jnp.zeros(self._pool_shape, self.cfg.jax_dtype)
         if self._kv_sharding is not None:
             kp = jax.device_put(kp, self._kv_sharding)
             vp = jax.device_put(vp, self._kv_sharding)
+        elif self._carry_sharding is not None:
+            kp = jax.device_put(kp, self._carry_sharding)
+            vp = jax.device_put(vp, self._carry_sharding)
         return kp, vp
 
     def _fresh_dstate(self) -> dict:
@@ -1421,7 +1507,7 @@ class DecodeEngine:
         failed (donated) step call consumed — the seed resumes at the
         step count so the sampled-lane key stream never replays."""
         mb = self.max_batch
-        return {
+        state = {
             "tables": jnp.zeros((mb, self.blocks_per_seq), jnp.int32),
             "positions": jnp.zeros((mb,), jnp.int32),
             "last": jnp.zeros((mb,), jnp.int32),
@@ -1433,6 +1519,9 @@ class DecodeEngine:
             "stopt": jnp.full((mb,), -1, jnp.int32),
             "seed": jnp.int32(getattr(self, "steps", 0)),
         }
+        if self._carry_sharding is not None:
+            state = jax.device_put(state, self._carry_sharding)
+        return state
 
     def _push_slot(self, slot: int, req: Optional[GenRequest]) -> None:
         """One event scatter carrying a slot's whole lane state to the
@@ -1562,6 +1651,30 @@ class DecodeEngine:
             rows += self.prefill_chunk
         self.metrics.attn_pages_read.incr(int(np.sum(-(-lens // bs))))
         self.metrics.attn_pages_dense.incr(rows * self.blocks_per_seq)
+        if self._dsa:
+            # the sparse selection: entries the live rows could attend to
+            # against the entries they keep (a layer; every layer alike)
+            cfg = self.cfg
+            self.metrics.attn_entries_live.incr(int(np.sum(lens)))
+            self.metrics.attn_entries_selected.incr(
+                int(np.sum(np.minimum(lens, cfg.index_topk))))
+            self.metrics.moe_assignments.incr(
+                int(lens.size) * cfg.top_k
+                * (cfg.n_layers - cfg.n_dense_layers))
+            # distinct pages under those rows, from below: requests whose
+            # tables start with the same page share a radix chain, and
+            # the longest of them alone holds that many pages
+            chains: Dict[int, int] = {}
+            for slot in lanes:
+                req = self._slots[slot]
+                chains[req._blocks[0]] = max(
+                    chains.get(req._blocks[0], 0),
+                    -(-(int(self._seq_lens[slot]) + 1) // bs))
+            if pre is not None and n_valid:
+                chains[pre._blocks[0]] = max(
+                    chains.get(pre._blocks[0], 0),
+                    -(-(pre._prefill_pos + n_valid) // bs))
+            self.metrics.attn_pages_distinct.incr(sum(chains.values()))
 
     def _deliver_step(self, packed, pre: Optional[GenRequest],
                       n_valid: int, c_first, proposed: int,
@@ -1572,6 +1685,9 @@ class DecodeEngine:
         G = self.spec_k + 1
         self.steps += 1
         self._chunk_fill = n_valid
+        if self._dsa and self.metrics:
+            self.metrics.moe_assignments_local.incr(int(packed[0, G + 3]))
+            self.metrics.moe_local_experts_hit.incr(int(packed[0, G + 4]))
         emitted = 0
         self.occupancy_log.append(self.num_active)
         if len(self.occupancy_log) > 100_000:

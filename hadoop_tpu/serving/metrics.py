@@ -47,6 +47,21 @@ class ServingMetrics:
                              live rows attended to, against the pages a
                              walk of every row's whole table would have
                              read; their ratio is the live-page share
+    - ``attn_entries_live`` / ``attn_entries_selected``  (sparse
+                             selection) context entries the steps' live
+                             rows could attend to, against the entries
+                             they kept (at most ``index_topk`` a row)
+    - ``attn_pages_distinct``  distinct KV pages under those rows,
+                             counted from below (requests sharing a
+                             radix chain counted by the longest)
+    - ``moe_assignments`` / ``moe_assignments_local`` /
+      ``moe_local_experts_hit``  (a replica holding a share of a wider
+                             router) row-to-expert assignments of the
+                             steps' live rows over the expert layers,
+                             those that fell on experts held here, and
+                             held experts with at least one (summed over
+                             layers and steps; the last two counted on
+                             the device, read with the step's bundle)
     - ``qos_admitted`` / ``qos_shed``  door QoS gate outcomes (sheds
                              are 429 + Retry-After responses)
     - ``qos_tenants``        tenants tracked by the decay scheduler
@@ -195,6 +210,26 @@ class ServingMetrics:
         self.attn_pages_dense = reg.counter(
             "attn_pages_dense",
             "KV pages a walk of every row's whole block table would read")
+        # sparse selection and the held share of a wider router (zero
+        # for families without them)
+        self.attn_entries_live = reg.counter(
+            "attn_entries_live",
+            "context entries the steps' live rows could attend to")
+        self.attn_entries_selected = reg.counter(
+            "attn_entries_selected",
+            "context entries the sparse selection kept for those rows")
+        self.attn_pages_distinct = reg.counter(
+            "attn_pages_distinct",
+            "distinct KV pages under the steps' live rows, from below")
+        self.moe_assignments = reg.counter(
+            "moe_assignments",
+            "row-to-expert assignments of live rows, all expert layers")
+        self.moe_assignments_local = reg.counter(
+            "moe_assignments_local",
+            "those assignments that fell on experts held by this replica")
+        self.moe_local_experts_hit = reg.counter(
+            "moe_local_experts_hit",
+            "held experts with an assignment, summed over layers and steps")
         # door QoS: admissions vs sheds (429) and tracked tenants — the
         # autoscaler scrapes qos_shed off /prom as a scale-out signal
         # (a shedding fleet is past its SLO by definition)

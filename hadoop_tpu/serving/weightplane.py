@@ -321,7 +321,13 @@ def _finish_report(report: Dict[str, Any], params) -> Dict[str, Any]:
 
 
 def _expert_stack_bytes(params) -> int:
-    layers = params.get("layers", {}) if isinstance(params, dict) else {}
+    """The routed experts' stacks, wherever the tree keeps its expert
+    layers: ``layers`` (one stack of like layers) or ``moe_layers`` (the
+    expert layers of a tree with a stack per kind; its shared expert is
+    dense remainder)."""
+    if not isinstance(params, dict):
+        return 0
+    layers = params.get("moe_layers") or params.get("layers", {})
     return sum(resident_weight_bytes(layers[k])
                for k in EXPERT_STACKS if k in layers)
 
