@@ -218,15 +218,16 @@ def paged_attention(q: jnp.ndarray, kc: jnp.ndarray, vc: jnp.ndarray,
                     interpret: bool = False) -> jnp.ndarray:
     """Attention of ``t`` single-token rows over their paged contexts.
 
-    q: ``[t, hq, dh]``; kc, vc: ``[blocks, bs, hkv, dh]`` (one layer's
-    pool, ``hq`` a multiple of ``hkv``); tables: ``[t, bps]`` int32 pool
-    indices, page ``j`` of a row holding its positions
-    ``j*bs .. j*bs + bs - 1``; lens: ``[t]`` int32, the row attends to
-    positions ``< lens`` (``pos + 1`` for a live row). A row with
-    ``lens == 0`` attends to nothing and gets zeros — never NaN. Table
-    entries past a row's live pages never reach a softmax but may be
-    fetched, so every entry names a valid page. Returns ``[t, hq, dh]``
-    in ``q``'s dtype.
+    q: ``[t, hq, dh]``; kc, vc: ``[blocks, bs, hkv, dh]`` (a pool
+    addressed by page index, ``hq`` a multiple of ``hkv``; a pool
+    stacked over layers is addressed ``layer * blocks + page``);
+    tables: ``[t, bps]`` int32 pool indices, page ``j`` of a row holding
+    its positions ``j*bs .. j*bs + bs - 1``; lens: ``[t]`` int32, the
+    row attends to positions ``< lens`` (``pos + 1`` for a live row). A
+    row with ``lens == 0`` attends to nothing and gets zeros — never
+    NaN. Table entries past a row's live pages never reach a softmax but
+    may be fetched, so every entry names a valid page. Returns
+    ``[t, hq, dh]`` in ``q``'s dtype.
 
     ``impl``: "auto" picks the Pallas kernel on a TPU backend when the
     shapes qualify and the portable path otherwise; "flash" / "ref"

@@ -478,9 +478,10 @@ class DecodeEngine:
             # it is split over this replica's devices
             self.expert_shards = 1
         # where the step's carried buffers live from the first call on:
-        # beside expert stacks split over local chips a step hands the
-        # pools and the lane state back replicated over that mesh, and a
-        # second call with them so placed would trace the shape again
+        # beside expert stacks split over local chips (or weights split
+        # over a tp mesh, below) a step hands the pools and the lane
+        # state back replicated over that mesh, and a second call with
+        # them so placed would trace the shape again
         self._carry_sharding = None
         if cfg.is_moe and self.expert_shards > 1:
             params, self._carry_sharding = _shard_expert_stacks(
@@ -563,6 +564,7 @@ class DecodeEngine:
             from jax.sharding import NamedSharding, PartitionSpec as P
             self._kv_sharding = NamedSharding(
                 self._mesh, P(None, None, None, "tp", None))
+            self._carry_sharding = NamedSharding(self._mesh, P())
         self._kp, self._vp = self._fresh_kv_pools()
 
         # live HBM ledger (obs/hbm.py): this engine's resident bytes —
@@ -777,6 +779,12 @@ class DecodeEngine:
         draft row attends to the drafts before it exactly as it would
         have sequentially.
 
+        ``kp`` / ``vp`` are the donated pools ``[L, blocks, bs, hkv,
+        dh]`` and come back as the same buffers: the layer scan carries
+        both whole, viewed ``[L * blocks, bs, hkv, dh]``, and layer
+        ``l`` writes and reads its pages at ``l * blocks + page`` — no
+        slab is sliced out, stacked back or copied.
+
         All lane state arrives in (and leaves through) the donated
         ``state`` dict: positions advance by the accepted length, the
         stop-condition scan (max_new budget, stop_token) retires lanes
@@ -873,8 +881,9 @@ class DecodeEngine:
 
         # the scopes below are the step's stable names on the device
         # trace (metadata only: the compiled program is the same)
-        def layer(h, xs):
-            lp, kc, vc = xs
+        def layer(carry, xs):
+            h, kc, vc = carry
+            lp, base = xs
             with jax.named_scope("attn_proj"):
                 x = _norm(h, lp["attn_norm_w"], lp.get("attn_norm_b"), cfg)
                 q = self._wdot(x, lp["wq"]).reshape(t, hq, dh)
@@ -884,20 +893,21 @@ class DecodeEngine:
                     q = _rope_at(q, cos, sin, pos)
                     k = _rope_at(k, cos, sin, pos)
             with jax.named_scope("kv_update"):
-                kc = kc.at[blk, off].set(k.astype(kc.dtype))
-                vc = vc.at[blk, off].set(v.astype(vc.dtype))
+                kc = kc.at[base + blk, off].set(k.astype(kc.dtype))
+                vc = vc.at[base + blk, off].set(v.astype(vc.dtype))
             with jax.named_scope("attn"):
                 # read AFTER the scatter: a draft or chunk row sees the
                 # rows before it in this very step
-                attn = paged_attention(q, kc, vc, tables, lens, scale,
-                                       impl=attn_impl)
+                attn = paged_attention(q, kc, vc, base + tables, lens,
+                                       scale, impl=attn_impl)
             with jax.named_scope("attn_proj"):
                 h2 = h + self._wdot(attn.reshape(t, hq * dh),
                                     lp["wo"]).astype(h.dtype)
             with jax.named_scope("mlp"):
                 x2 = _norm(h2, lp["mlp_norm_w"], lp.get("mlp_norm_b"),
                            cfg)
-                return h2 + self._mlp(x2, lp).astype(h.dtype), (kc, vc)
+                return (h2 + self._mlp(x2, lp).astype(h.dtype), kc,
+                        vc), None
 
         # comm_scale: the trace-time comm ledgers see one body trace of
         # the scan; the hardware runs it n_layers times per step — the
@@ -916,9 +926,20 @@ class DecodeEngine:
                 {"pos": pos, "blk": blk, "off": off, "active": active,
                  "groups": groups, "cos": cos, "sin": sin})
         else:
+            # the pools ride the scan as its carry, viewed
+            # [layers * blocks, bs, hkv, dh]: a layer writes and reads
+            # its pages where they lie, at layer * blocks + page
+            pool_shape = kp.shape
+            n_blocks = pool_shape[1]
+            kp = kp.reshape((-1,) + pool_shape[2:])
+            vp = vp.reshape((-1,) + pool_shape[2:])
             with comm_scale(cfg.n_layers):
-                h, (kp, vp) = jax.lax.scan(layer, h,
-                                           (params["layers"], kp, vp))
+                (h, kp, vp), _ = jax.lax.scan(
+                    layer, (h, kp, vp),
+                    (params["layers"],
+                     jnp.arange(cfg.n_layers, dtype=jnp.int32) * n_blocks))
+            kp = kp.reshape(pool_shape)
+            vp = vp.reshape(pool_shape)
         with jax.named_scope("head_sample"):
             h = _norm(h, params["final_norm_w"], params.get("final_norm_b"),
                       cfg)
