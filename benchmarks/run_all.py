@@ -113,7 +113,7 @@ _KEY_METRICS = {
     "serving": [(("value",), "ttft_p50_ms")],
     "serving_speculate": [(("steps_ratio",), "steps_ratio")],
     "serving_quantized": [(("value",), "capacity_ratio")],
-    # expert-parallel MoE serving (serving/engine._moe_mlp): the lever
+    # expert-parallel MoE serving (serving/families/gqa.moe_mlp): the lever
     # counts as moving when the trajectory shows sparse tokens/s priced
     # against dense-compute NEXT TO the ledger-measured a2a byte cut
     # and the guard verdict that bought it

@@ -1,15 +1,19 @@
 """Model families for the TPU compute engine.
 
-One functional decoder core (``hadoop_tpu.models.decoder``) with family
-presets (``hadoop_tpu.models.config``):
+``models.decoder`` is the functional core of three families — training,
+and the norm / head rules serving shares (presets: ``models.config``):
 
 - ``gpt2``    — LayerNorm + learned positions + GeLU MLP
 - ``llama``   — RMSNorm + RoPE + SwiGLU + grouped-query attention
 - ``mixtral`` — llama core with a top-k routed mixture-of-experts MLP
 
-Parameters are stored layer-stacked (leading ``n_layers`` dim) so pipeline
-parallelism shards them over the ``pp`` mesh axis and the single-device
-path runs them under ``lax.scan`` — one compiled layer body either way.
+``models.deepseek`` is a fourth, serving only (``deepseek_v32``: latent
+attention, a learned sparse selection, a share of a wider router, a
+stack per layer kind). The serving step reaches every family's layers
+through ``serving/families``, not by name.
+
+Parameters are stored layer-stacked (leading ``n_layers`` dim): ``pp``
+shards them over its mesh axis, one device runs them under ``lax.scan``.
 """
 
 from hadoop_tpu.models.config import ModelConfig, PRESETS, get_config

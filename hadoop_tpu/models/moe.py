@@ -25,7 +25,7 @@ no capacity, no token dropped — plus the shared expert, and returns that
 partial sum. Assignments to experts held elsewhere are theirs to add.
 
 The serving engine's fused step reuses :func:`route` and
-:func:`_expert_ffn` directly (serving/engine.py ``_moe_mlp``) — the
+:func:`_expert_ffn` directly (serving/families/gqa.py ``moe_mlp``) — the
 capacity padding is what keeps the step's shapes static, so serving
 MUST share this module's dispatch math or the two planes drift.
 :func:`capacity` is the public twin of the capacity rule for the

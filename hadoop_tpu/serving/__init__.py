@@ -7,6 +7,8 @@ The compute plane inherited from the reference is batch-only (PAPER.md
     engine.py   continuous-batching decode engine over the paged KV pool
                 (device-resident step state, in-graph stop scan, and a
                 speculation lane verified in the same fused step)
+    families/   the seam to a model family: a token's cache entry and
+                the layers that read it (engine.py names no family)
     speculate.py  n-gram / prompt-lookup draft proposer per request
     weightplane.py  resident-weight dtype/layout policy behind
                 serving.parity: int8 + per-group scales at load,

@@ -1,4 +1,5 @@
-"""Continuous-batching decode engine over ``models.decoder`` weights.
+"""Continuous-batching decode engine over a model family's weights and
+pages (``serving/families``: the engine itself names no family).
 
 Design (TPU-first, same rules as the trainer):
 
@@ -10,20 +11,17 @@ Design (TPU-first, same rules as the trainer):
   compiles at exactly TWO shapes: decode-only (``[max_batch]`` rows —
   steady-state decode pays nothing for an idle chunk lane) and fused
   (``[max_batch + prefill_chunk]`` rows when a prompt chunk rides
-  along). Prompts of any length, any admission order, and any sampling
-  mix ride those two executables — no per-request retracing, ever.
-  ``decode_compiles`` / ``prefill_compiles`` count the two shape
-  families' traces so tests and the bench can assert exactly-once
+  along). Prompts of any length, admission order and sampling mix ride
+  those two executables; ``decode_compiles`` / ``prefill_compiles``
+  count their traces so tests and the bench can assert exactly-once
   compilation of each.
 
-- **Paged KV cache.** K/V live in a block pool of shape
-  ``[L, num_blocks, block_size, Hkv, Dh]``; each running request owns a
-  block table (list of pool indices). Each step scatters the new
-  tokens' K/V into ``table[pos // bs], pos % bs`` and then attends
-  each row to its own live pages through its table
-  (``ops.paged_attention``: the walk ends at the step's longest live
-  context, not at ``max_context``; GQA groups share each K/V read; K/V
-  stay in the pool's dtype) — requests share one pool with no
+- **Paged KV cache.** What a token caches is the family's (K and V per
+  KV head; or a latent and an index key): two pools ``[L, num_blocks,
+  block_size, *entry]`` under one block table per running request (a
+  list of pool indices). Each step scatters the new tokens' entries
+  into ``table[pos // bs], pos % bs`` and then attends each row to its
+  own live pages through its table — requests share one pool with no
   per-request padding waste (the vLLM PagedAttention layout). Block 0
   is a write-off scratch page: inactive rows and chunk padding scatter
   there and attend to nothing, so masking never needs dynamic shapes.
@@ -32,30 +30,28 @@ Design (TPU-first, same rules as the trainer):
   (block-granular trie keyed by token chunks) remembers fully-filled
   prompt blocks after prefill. A new request whose token prefix walks a
   cached path maps those blocks into its table (incref — shared,
-  read-only: full blocks are never rewritten, so sharing needs no copy)
-  and prefills only the tail; at least the last prompt token is always
-  recomputed so the first output token has fresh logits. Blocks whose
+  read-only: full blocks are never rewritten) and prefills only the
+  tail; at least the last prompt token is always recomputed so the
+  first output token has fresh logits. Blocks whose
   refcount drops to zero stay resident as cache and are evicted LRU
   (leaves first) when the pool runs dry — eviction composes with the
   recompute-preemption path: evict cold cache first, preempt the
   youngest request only when the cache is already dry.
 
-- **Tiered fleet-wide cache.** The pool + radix moved into
-  ``serving/kvstore`` and grew two cold tiers behind them: zero-ref
-  blocks demote to a host-RAM ring (``serving.kv.host.bytes``) when the
-  HBM tier evicts them, and hot shared prefixes persist as blocks on
-  the DataNodes (``serving.kv.dfs.enable``) via the DFS write pipeline
-  so ANY replica — including one that just restarted — maps them back
-  with hedged reads instead of re-prefilling. A radix miss at admission
-  consults host, then DFS, before falling back to prefill; promotions
-  ride fixed-shape jitted page movers (no new compiles). See
-  ``kvstore/tiered.py`` for the policy.
+- **Tiered fleet-wide cache.** Pool and radix live in
+  ``serving/kvstore`` with two cold tiers behind them: zero-ref blocks
+  demote to a host-RAM ring (``serving.kv.host.bytes``) when the HBM
+  tier evicts them, and hot shared prefixes persist as blocks on the
+  DataNodes (``serving.kv.dfs.enable``) so ANY replica — including one
+  that just restarted — maps them back with hedged reads instead of
+  re-prefilling. A radix miss at admission consults host, then DFS,
+  before falling back to prefill; promotions ride fixed-shape jitted
+  page movers (no new compiles). Policy: ``kvstore/tiered.py``.
 
 - **Chunked prefill, fused into the step.** A prompt is prefilled
   ``prefill_chunk`` tokens per engine step in the SAME compiled step
-  that advances every running decode — a long prompt can no longer
-  head-of-line-block the batch for a whole monolithic prefill call, so
-  admitted requests keep streaming while a new prompt fills in.
+  that advances every running decode — a long prompt cannot
+  head-of-line-block the batch: admitted requests keep streaming.
 
 - **Continuous batching.** New requests are admitted at any step
   boundary into free slots (their prefill chunks interleave with
@@ -66,64 +62,54 @@ Design (TPU-first, same rules as the trainer):
 
 - **Device-resident step state.** Block tables, positions, last
   tokens, active mask, sampling params, token budgets and the PRNG
-  seed live ON DEVICE and are carried through the donated step — the
-  host no longer rebuilds eight numpy arrays into device arrays every
-  step. State changes ride small event scatters (``_SET_SLOT`` /
-  ``_SET_TABLE``) on admission, prefill completion, page growth,
-  preemption and release — events, not steps. The stop-condition scan
-  (max_new budget, stop_token) runs INSIDE the compiled step, and the
-  host reads back one packed ``[B, k+4]`` bundle per step
-  (sampled tokens, emit counts, finished mask, verifier accept
-  lengths) instead of scanning per-slot Python. In steady-state decode the hot loop transfers
-  nothing host→device (tests pin this with a ``jax.transfer_guard``).
+  seed live ON DEVICE and are carried through the donated step. State
+  changes ride small event scatters (``_SET_SLOT`` / ``_SET_TABLE``)
+  on admission, prefill completion, page growth, preemption and
+  release — events, not steps. The stop-condition scan (max_new
+  budget, stop_token) runs INSIDE the compiled step, and the host
+  reads back one packed ``[B, k+4]`` bundle per step (sampled tokens,
+  emit counts, finished mask, verifier accept lengths). In
+  steady-state decode the hot loop transfers nothing host→device
+  (tests pin this with a ``jax.transfer_guard``).
 
 - **Speculative decoding.** A third lane in the SAME compiled step:
-  a host-side n-gram / prompt-lookup index over each request's prompt
-  + generated tokens (``serving/speculate.py``) proposes up to
-  ``serving.speculate.k`` draft tokens per decode lane; each lane
-  becomes a group of ``k+1`` rows (last accepted token + k drafts at
-  consecutive positions) and the single batched forward verifies all
-  of them against the paged KV cache at once. The longest agreeing
-  prefix is accepted — greedy lanes by argmax equality (token-for-token
-  identical to speculation-off, the serve_bench A-B contract), sampled
-  lanes by rejection sampling against the verifier distribution (the
-  draft is a point mass: accept ``u < p(draft)``, re-sample from the
-  draft-removed renormalized target on rejection — output distribution
-  exactly the target's). Rejected drafts waste only the row: their KV
-  lands beyond the accepted tip and is rewritten by the next step's
-  contiguous window before anything can attend to it, and the radix
-  prefix cache only ever sees accepted, block-aligned tokens. The two
-  compiled shapes stay two: ``[B*(k+1)]`` and ``[B*(k+1) + chunk]``.
+  a host-side n-gram index over each request's prompt + generated
+  tokens (``serving/speculate.py``, which also states the acceptance
+  rule) proposes up to ``serving.speculate.k`` draft tokens per decode
+  lane; each lane becomes a group of ``k+1`` rows (last accepted token
+  + k drafts at consecutive positions) and the one batched forward
+  verifies them all against the paged cache. Greedy lanes accept by
+  argmax equality (token-for-token identical to speculation-off),
+  sampled lanes by rejection sampling (output distribution exactly the
+  target's). Rejected drafts waste only the row: their entries land
+  beyond the accepted tip and are rewritten by the next step before
+  anything can attend to them, and the radix prefix cache only ever
+  sees accepted, block-aligned tokens. The two compiled shapes stay
+  two: ``[B*(k+1)]`` and ``[B*(k+1) + chunk]``.
 
 - **Weight plane.** Resident weights follow the per-tensor policy of
   ``serving/weightplane.py``: under ``serving.parity=relaxed`` the
   matmul weights live in HBM as int8 + per-group f32 scales and every
-  serving matmul dequantizes them in-register (weight-only int8 —
-  decode is bandwidth-bound, so ~4x fewer weight-read bytes is decode
-  speed AND freed HBM). ``hbm_bytes`` turns the freed memory into
-  capacity: the KV pool and the decode-lane count are sized against
-  the MEASURED resident-weight bytes, so the int8 plane admits 2-4x
-  the lanes x context of the f32 plane at the same budget. Bitwise
-  (the default) compiles the exact pre-weight-plane graph — zero
-  quantized code reachable, enforced by tpulint's
-  ``parity/relaxed-gated`` checker on the qdot/qrows/qhead call sites.
+  serving matmul dequantizes them in-register (decode is
+  bandwidth-bound: ~4x fewer weight-read bytes is decode speed AND
+  freed HBM). ``hbm_bytes`` turns the freed memory into capacity: the
+  KV pool and the lane count are sized against the MEASURED
+  resident-weight bytes, so the int8 plane admits 2-4x the lanes x
+  context of the f32 plane at the same budget. Bitwise
+  (the default) compiles zero quantized code: tpulint's
+  ``parity/relaxed-gated`` checker holds every qdot/qrows/qhead call.
 
 - **Long-context lane.** With a ``serving/longctx`` plane attached
-  (``attach_longctx`` — ``serving.parity=relaxed`` only, the CP
-  softmax reassociation is not bitwise), prompts of at least
-  ``serving.longctx.min.tokens`` bypass the fused step entirely:
-  prefill runs as a context-parallel job across the replica's mesh,
-  the finished KV streams into the host/DFS tiers
-  (``kvstore.ingest_chain``, digest-chained), and decode pages a
-  working set back through a fixed device window — the prompt never
-  has to fit this engine's pool, and the two step shapes here stay
-  exactly two.
+  (``attach_longctx`` — ``serving.parity=relaxed`` only), prompts of
+  at least ``serving.longctx.min.tokens`` bypass the fused step: CP
+  prefill across the replica's mesh, KV streamed into the host/DFS
+  tiers, decode through a fixed device window — the prompt never has
+  to fit this engine's pool, and the step shapes here stay two.
 
 - **Sharding.** Pass a ``MeshPlan`` (tp only) and the engine places the
-  weights with ``parallel.mesh.param_specs`` and the KV pool with heads
-  sharded over ``tp``; jit's SPMD partitioner inserts the decode
-  collectives. Under ``JAX_PLATFORMS=cpu`` the same code runs on the
-  virtual device mesh (tests) or a single device.
+  weights with ``parallel.mesh.param_specs`` and the pools by the
+  family's spec (KV heads over ``tp``); jit's SPMD partitioner inserts
+  the decode collectives.
 """
 
 from __future__ import annotations
@@ -134,41 +120,33 @@ import queue
 import threading
 import time
 from collections import deque
-from dataclasses import dataclass, field, replace as _dc_replace
+from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from hadoop_tpu.models import deepseek as _dsv32
 from hadoop_tpu.models.config import ModelConfig
 from hadoop_tpu.models.decoder import _norm, head_matrix
-# MoE serving shares models/moe.py's dispatch math verbatim — the
-# capacity padding there is what keeps the fused step's shapes static
-from hadoop_tpu.models.moe import _expert_ffn, route
-from hadoop_tpu.models.moe import capacity as moe_capacity
-from hadoop_tpu.ops import gelu, rope_frequencies, swiglu
-from hadoop_tpu.ops.paged_attention import paged_attention
+from hadoop_tpu.obs.hbm import hbm_ledger
 # BlockPool/PrefixCache live in the kvstore package now (the tiered
 # fleet-wide cache); re-exported here so `from serving.engine import
 # BlockPool` keeps working for every existing consumer
 from hadoop_tpu.serving.kvstore import (BlockPool, PrefixCache,
                                         TieredKVCache)
 from hadoop_tpu.serving.speculate import NgramProposer
-# the weight plane (serving/weightplane.py): qdot/qrows/qhead/qedot and
-# the lowp a2a codecs below are RELAXED-TIER entry points — every call
-# sits under an `if self._relaxed_weights ...` guard, so
-# serving.parity=bitwise (the default) compiles zero quantized code
-# (tpulint-enforced)
-from hadoop_tpu.parallel.lowp.quant import (moe_combine_quantized,
-                                            moe_dispatch_quantized)
-from hadoop_tpu.serving.weightplane import (EXPERT_STACKS, describe_tree,
-                                            expert_shard_count,
+# what a token's cache entry is and the layers that read it
+from hadoop_tpu.serving import families
+# qrows/qhead are RELAXED-TIER entry points — every call sits under an
+# `if self._relaxed_weights ...` guard, so serving.parity=bitwise (the
+# default) compiles zero quantized code (tpulint-enforced)
+from hadoop_tpu.serving.weightplane import (describe_tree,
                                             expert_weight_bytes,
                                             is_qtensor, is_quantized_tree,
-                                            qdot, qedot, qhead, qrows)
-from hadoop_tpu.tracing.tracer import global_tracer, phase
+                                            qhead, qrows)
+from hadoop_tpu.tracing.tracer import (current_context, global_tracer,
+                                       phase)
 
 log = logging.getLogger(__name__)
 
@@ -181,34 +159,6 @@ _NEG_INF = -1e30
 PHASES = ("engine.wait", "engine.admit", "engine.propose", "engine.pages",
           "engine.dispatch", "engine.readback", "engine.deliver",
           "engine.publish")
-
-
-def _shard_expert_stacks(params, shards: int):
-    """Place the expert FFN stacks expert-split across the replica's
-    local chips: the leading layout is ``[L, E, ...]`` (f32 stacks) or
-    ``[L, E, N, G, gs]``/``[L, E, N, G]`` (qtensor payload/scales), so
-    a ``P(None, "ep")`` spec over a 1-axis local mesh splits the expert
-    dim and replicates everything else — payload and scales split
-    together, scales can never land off their expert's shard. Dense
-    leaves (attention, norms, router) are untouched: they stay
-    replicated, exactly the dense engine's placement."""
-    from jax.sharding import Mesh, NamedSharding
-    from jax.sharding import PartitionSpec as P
-    mesh = Mesh(np.asarray(jax.local_devices()[:shards]), ("ep",))
-    spec = NamedSharding(mesh, P(None, "ep"))
-    layers = dict(params["layers"])
-    for k in EXPERT_STACKS:
-        if k not in layers:
-            continue
-        leaf = layers[k]
-        if is_qtensor(leaf):
-            layers[k] = {"q": jax.device_put(leaf["q"], spec),
-                         "s": jax.device_put(leaf["s"], spec)}
-        else:
-            layers[k] = jax.device_put(leaf, spec)
-    out = dict(params)
-    out["layers"] = layers
-    return out, NamedSharding(mesh, P())
 
 
 # fixed-shape page movers for the cold tiers: one trace each for the
@@ -345,16 +295,6 @@ class GenRequest:
 # apply EXACTLY the trained model's norm/head rules or served logits
 # silently diverge from training)
 
-def _rope_at(x, cos, sin, pos):
-    """Rotate one token per row: x [T, H, Dh], pos [T]."""
-    c = cos[pos][:, None, :]
-    s = sin[pos][:, None, :]
-    xf = x.astype(jnp.float32)
-    x1, x2 = jnp.split(xf, 2, axis=-1)
-    out = jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
-    return out.astype(x.dtype)
-
-
 def _mask_and_scale(logits, temps, topks):
     """The exact top-k mask + temperature transform ``_sample`` draws
     from, rank-polymorphic over leading axes — the speculation
@@ -405,37 +345,22 @@ class DecodeEngine:
                  moe_a2a_codec: str = "int8",
                  plan=None, metrics=None, tracer=None):
         self.cfg = cfg
-        # ---- family "deepseek_v32": latent pages + index-key pages in
-        # the two pools, per-kind layer stacks (models/deepseek.py). The
-        # planes it is not built for refuse here, by conf key — never a
-        # silent wrong layout.
-        self._dsa = cfg.family == _dsv32.FAMILY
-        if self._dsa:
-            for bad, key in (
-                    (is_quantized_tree(params), "serving.parity=relaxed"),
-                    (plan is not None, "a tp plan (serving.tp)"),
-                    (kv_host_bytes, "serving.kv.host.bytes"),
-                    (kv_store_fs is not None, "serving.kv.dfs.enable"),
-                    (speculate_k, "serving.speculate.k"),
-                    (int(moe_shards) > 1, "serving.moe.shards")):
-                if bad:
-                    raise NotImplementedError(
-                        f"family={cfg.family!r} does not serve under "
-                        f"{key}: the latent/index-key page layout, the "
-                        "sparse selection and the held-expert layer have "
-                        "no such path yet")
-        # ---- expert plane (MoE checkpoints): the fused step routes
-        # every row through models/moe.py's capacity-padded one-hot
-        # dispatch, so the static row count pins the capacity and the
-        # compile count stays at the same two shapes as dense
-        if moe_a2a_codec not in ("int8", "none"):
-            raise ValueError(f"serving.moe.a2a.codec={moe_a2a_codec!r} "
-                             "(choices: int8, none)")
-        self._moe_a2a_codec = moe_a2a_codec
-        self._moe_cfg = cfg
-        if cfg.is_moe and moe_capacity_factor:
-            self._moe_cfg = _dc_replace(
-                cfg, capacity_factor=float(moe_capacity_factor))
+        # ---- the weight plane: MEASURED resident bytes decide the KV
+        # budget. serving.parity=relaxed loads int8 weights + per-group
+        # scales (serving/weightplane.py); the freed HBM converts into
+        # more decode lanes x context below, at the same hbm_bytes.
+        self._relaxed_weights = is_quantized_tree(params)
+        # ---- the model family. The planes it is not built for refuse
+        # here, by conf key — never a silent wrong layout.
+        self._family = families.family_for(cfg, {
+            families.RELAXED: self._relaxed_weights,
+            families.TP_PLAN: plan is not None,
+            "serving.kv.host.bytes": kv_host_bytes,
+            "serving.kv.dfs.enable": kv_store_fs is not None,
+            "serving.speculate.k": speculate_k,
+            "serving.moe.shards": int(moe_shards) > 1},
+            moe_capacity_factor=moe_capacity_factor,
+            moe_shards=moe_shards, moe_a2a_codec=moe_a2a_codec)
         self.block_size = block_size
         self.prefill_chunk = max(1, int(prefill_chunk))
         self.max_context = min(max_context or cfg.max_seq, cfg.max_seq)
@@ -449,53 +374,29 @@ class DecodeEngine:
                 raise ValueError(f"block_size {block_size} exceeds the "
                                  f"model's max_seq {cfg.max_seq}")
             self.s_max = self.blocks_per_seq * block_size
-        # ---- the weight plane: MEASURED resident bytes decide the KV
-        # budget. serving.parity=relaxed loads int8 weights + per-group
-        # scales (serving/weightplane.py); the freed HBM converts into
-        # more decode lanes x context below, at the same hbm_bytes.
-        self._relaxed_weights = is_quantized_tree(params)
         self._q_embed = is_qtensor(params.get("embed"))
         self._q_head = is_qtensor(params["embed"]) if cfg.tie_embeddings \
             else is_qtensor(params.get("lm_head"))
-        if self._relaxed_weights and plan is not None:
-            raise NotImplementedError(
-                "tp sharding of int8 resident weights is not wired yet "
-                "(serving.parity=relaxed serves single-chip replicas)")
         # cached once: the params tree never changes after construction,
         # and /v1/health scrapes weight_plane() every autoscaler poll
         self._weight_desc = describe_tree(params)
         self.weight_bytes = self._weight_desc["weight_bytes"]
         self.quantize_seconds = quantize_seconds
-        # expert stacks: measured resident bytes (ledgered as the
-        # moe_experts component beside, not inside, the dense remainder)
-        # and the expert-dim shard count across the replica's chips
+        # expert stacks: measured resident bytes (the moe_experts HBM
+        # component, beside the dense remainder) and their shard count
+        # over the replica's chips. Beside stacks split over local chips
+        # (or weights over a tp mesh, below) a step hands pools and lane
+        # state back replicated over that mesh; _carry_sharding places
+        # them so from the first call, or a second one traces again
         self.expert_bytes = expert_weight_bytes(params, cfg)
-        self.expert_shards = expert_shard_count(
-            cfg.n_experts, int(moe_shards),
-            jax.local_device_count()) if cfg.is_moe else 0
-        if self._dsa:
-            # a share of a wider router is held by one chip: nothing of
-            # it is split over this replica's devices
-            self.expert_shards = 1
-        # where the step's carried buffers live from the first call on:
-        # beside expert stacks split over local chips (or weights split
-        # over a tp mesh, below) a step hands the pools and the lane
-        # state back replicated over that mesh, and a second call with
-        # them so placed would trace the shape again
-        self._carry_sharding = None
-        if cfg.is_moe and self.expert_shards > 1:
-            params, self._carry_sharding = _shard_expert_stacks(
-                params, self.expert_shards)
+        self.expert_shards = self._family.expert_shards
+        params, self._carry_sharding = self._family.place_experts(params)
         self.hbm_bytes = int(hbm_bytes or 0)
-        kv_itemsize = jnp.dtype(cfg.jax_dtype).itemsize
-        if self._dsa:
-            # a token is one latent and one index key a layer
-            self.block_nbytes = (cfg.n_layers * block_size * kv_itemsize *
-                                 (_dsv32.latent_width(cfg)
-                                  + cfg.index_head_dim))
-        else:
-            self.block_nbytes = (2 * cfg.n_layers * block_size *
-                                 cfg.n_kv_heads * cfg.head_dim * kv_itemsize)
+        # a page holds block_size tokens' entries in both pools, a layer
+        entries = self._family.entry_shapes
+        self.block_nbytes = (cfg.n_layers * block_size
+                             * jnp.dtype(cfg.jax_dtype).itemsize
+                             * sum(int(np.prod(e)) for e in entries))
         if self.hbm_bytes:
             # capacity = budget minus what the weights measurably
             # occupy; lanes sized so each can hold a full context
@@ -527,13 +428,10 @@ class DecodeEngine:
         self.tracer = tracer or global_tracer()
         # the tier manager owns the radix index and the cold tiers;
         # the engine stays the device owner (extract/inject below)
-        # (the latent family has no cold tier — refused above — so the
-        # tier manager sees its page only as a layout to salt the chain)
         self.kvstore = TieredKVCache(
             self.pool, layers=cfg.n_layers,
-            kv_heads=1 if self._dsa else cfg.n_kv_heads,
-            head_dim=_dsv32.latent_width(cfg) + cfg.index_head_dim
-            if self._dsa else cfg.head_dim, dtype=cfg.jax_dtype,
+            kv_heads=self._family.salt_layout[0],
+            head_dim=self._family.salt_layout[1], dtype=cfg.jax_dtype,
             enabled=prefix_cache, host_bytes=kv_host_bytes,
             fs=kv_store_fs, dfs_dir=kv_store_dir,
             dfs_min_refs=kv_dfs_min_refs, codec=kv_codec,
@@ -553,17 +451,13 @@ class DecodeEngine:
             params = shard_params(params, self._mesh, param_specs(cfg, plan))
         self.params = params
 
-        L, hkv, dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-        self._pool_shape = (L, num_blocks, block_size, hkv, dh)
-        if self._dsa:
-            # two pools, one block table: latents in the K slot, index
-            # keys in the V slot
-            self._pool_shape = (L, num_blocks, block_size)
+        self._pool_shapes = [(cfg.n_layers, num_blocks, block_size) + e
+                             for e in entries]
         self._kv_sharding = None
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
-            self._kv_sharding = NamedSharding(
-                self._mesh, P(None, None, None, "tp", None))
+            self._kv_sharding = NamedSharding(self._mesh,
+                                              self._family.pool_spec)
             self._carry_sharding = NamedSharding(self._mesh, P())
         self._kp, self._vp = self._fresh_kv_pools()
 
@@ -571,7 +465,6 @@ class DecodeEngine:
         # measured weights + the K/V pool it sized against them —
         # published as htpu_hbm_bytes{component=...} beside the trainer
         # and longctx components; torn down in stop()
-        from hadoop_tpu.obs.hbm import hbm_ledger
         # trailing separator: unregister_prefix("engine@123") must not
         # also match a coexisting "engine@1234..." owner
         self._hbm_owner = f"engine@{id(self)}."
@@ -657,11 +550,7 @@ class DecodeEngine:
         prompts at least ``plane.min_tokens`` long route to it from
         ``submit`` instead of the fused-step path. Caller is the
         relaxed-tier gate (``longctx_plane_from_conf`` re-validates)."""
-        if self._dsa:
-            raise NotImplementedError(
-                f"family={self.cfg.family!r} does not serve under "
-                "serving.longctx.enable: the long-context plane pages "
-                "per-head K/V, not latents and index keys")
+        self._family.refuse({"serving.longctx.enable": True})
         self._relaxed_longctx = plane
 
         def wake() -> None:
@@ -704,66 +593,6 @@ class DecodeEngine:
 
     # ----------------------------------------------------- compiled body
 
-    def _rope_tables(self):
-        if not self.cfg.use_rope:
-            return None, None
-        if self._dsa:
-            return _dsv32.rope_tables(self.cfg)
-        return rope_frequencies(self.cfg.head_dim, self.cfg.max_seq,
-                                self.cfg.rope_theta)
-
-    def _wdot(self, x, w):
-        """One serving matmul, weight-plane aware: under
-        ``serving.parity=relaxed`` the weight arrives as int8 + scale
-        groups and dequantizes in-register inside the contraction
-        (weightplane.qdot); bitwise (the default) is the plain matmul,
-        byte-identical to the pre-weight-plane engine."""
-        if self._relaxed_weights:
-            return qdot(x, w)
-        return x @ w
-
-    def _mlp(self, x, lp):
-        if self.cfg.is_moe:
-            return self._moe_mlp(x, lp)
-        if self.cfg.use_swiglu:
-            return self._wdot(swiglu(self._wdot(x, lp["w_gate"]),
-                                     self._wdot(x, lp["w_up"])),
-                              lp["w_down"])
-        return self._wdot(gelu(self._wdot(x, lp["w_in"]) + lp["b_in"]),
-                          lp["w_out"]) + lp["b_out"]
-
-    @jax.named_scope("moe")
-    def _moe_mlp(self, x, lp):
-        """Routed expert MLP inside the ONE fused step. The full row
-        batch ``x [T, D]`` (decode lanes + any riding prefill chunk)
-        goes through models/moe.py's capacity-padded one-hot dispatch —
-        T is static per shape family, so the capacity C is static and
-        the compile count stays at the same two shapes as dense.
-        Tokens past an expert's capacity (and inactive draft rows) get
-        an all-zero combine row: the combine einsum yields exact 0.0
-        and the residual passes through, bit-for-bit ``moe_mlp``'s
-        dropped-token semantics. Under ``serving.parity=relaxed`` the
-        expert contractions run against the int8 stacks
-        (weightplane.qedot) and both all2all legs ride the lowp codec,
-        recorded at the bounded ``moe.dispatch``/``moe.combine`` comm
-        sites (Flash Communication, arXiv:2412.04964)."""
-        mcfg = self._moe_cfg
-        dispatch, combine = route(x, lp["router"], mcfg)
-        xe = jnp.einsum("tec,td->ecd", dispatch.astype(x.dtype), x)
-        if self._relaxed_weights and self._moe_a2a_codec != "none":
-            xe = moe_dispatch_quantized(xe)
-        if self._relaxed_weights:
-            ye = qedot(swiglu(qedot(xe, lp["w_gate"]),
-                              qedot(xe, lp["w_up"])),
-                       lp["w_down"])
-        else:
-            ye = _expert_ffn(xe, lp, mcfg)
-        if self._relaxed_weights and self._moe_a2a_codec != "none":
-            ye = moe_combine_quantized(ye)
-        y2d = jnp.einsum("tec,ecd->td", combine.astype(jnp.float32),
-                         ye.astype(jnp.float32))
-        return y2d.astype(x.dtype)
-
     def _step_impl(self, params, kp, vp, state, drafts, draft_lens,
                    chunk):
         """The ONE compiled function: every row is one token at one
@@ -773,31 +602,29 @@ class DecodeEngine:
         consecutive positions, sharing the lane's block table row);
         when ``chunk`` rides along, the last ``prefill_chunk`` rows are
         consecutive positions of one request's prompt chunk.
-        Scatter-all-then-attend makes earlier rows' K/V visible to
+        Scatter-all-then-attend makes earlier rows' entries visible to
         later positions within the same step; each row's length
-        ``position + 1`` (0 for an inactive row) is its causal mask — a
-        draft row attends to the drafts before it exactly as it would
-        have sequentially.
+        ``position + 1`` (0 for an inactive row) is its causal mask.
 
-        ``kp`` / ``vp`` are the donated pools ``[L, blocks, bs, hkv,
-        dh]`` and come back as the same buffers: the layer scan carries
-        both whole, viewed ``[L * blocks, bs, hkv, dh]``, and layer
-        ``l`` writes and reads its pages at ``l * blocks + page`` — no
-        slab is sliced out, stacked back or copied.
+        ``kp`` / ``vp`` are the donated pools ``[L, blocks, bs,
+        *entry]`` and come back as the same buffers. The layers are the
+        family's (``serving/families``: ``run_layers`` over the rows
+        built here; its scans carry both pools whole and address layer
+        ``l``'s pages at ``l * blocks + page``, so no slab is sliced
+        out, stacked back or copied); embedding, head, sampling,
+        speculation's verify and the stop scan are here.
 
         All lane state arrives in (and leaves through) the donated
         ``state`` dict: positions advance by the accepted length, the
-        stop-condition scan (max_new budget, stop_token) retires lanes
-        in-graph, and the PRNG key derives from the carried seed — the
-        host uploads nothing per steady-state decode step and reads
-        back one packed ``[B, spec_k + 4]`` bundle
-        (tokens | emit_count | finished | accept_len).
+        stop-condition scan retires lanes in-graph, and the PRNG key
+        derives from the carried seed — the host uploads nothing per
+        steady-state decode step and reads back one packed ``[B, spec_k
+        + 4]`` bundle (tokens | emit_count | finished | accept_len), a
+        column wider for each counter the family's layers feed.
 
-        Compiled at exactly TWO shapes for the replica's lifetime:
-        ``[B*(spec_k+1)]`` rows (decode-only) and
-        ``[B*(spec_k+1) + prefill_chunk]`` rows (a prompt chunk riding
-        along). Any further trace is a retracing bug the counters
-        expose."""
+        Compiled at exactly TWO shapes for the replica's lifetime
+        (decode-only, and with a prompt chunk riding along): any
+        further trace is a retracing bug the counters expose."""
         cfg = self.cfg
         B, S = self.max_batch, self.spec_k
         G = S + 1
@@ -849,14 +676,12 @@ class DecodeEngine:
                 [temps, jnp.broadcast_to(temps_s[c_slot], (C,))])
             topks = jnp.concatenate(
                 [topks, jnp.broadcast_to(topks_s[c_slot], (C,))])
-        t = tokens.shape[0]
         # inactive draft rows can sit past the end of the table/rope
         # range; clip (identity for every live row) and let the active
         # mask discard their output
         pos = jnp.minimum(positions, self.s_max - 1)
 
-        hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        cos, sin = self._rope_tables()
+        cos, sin = self._family.rope_tables()
         with jax.named_scope("embed"):
             if self._relaxed_weights and self._q_embed:
                 # quantized embedding gather (policy-selectable; norms
@@ -871,75 +696,14 @@ class DecodeEngine:
             tables, (pos // self.block_size)[:, None], axis=1)[:, 0]
         blk = jnp.where(active, blk, BlockPool.SCRATCH)
         off = pos % self.block_size
-        scale = 1.0 / (dh ** 0.5)
         # causal by length: a live row attends to positions <= its own;
         # an inactive row attends to nothing and gets zeros
         lens = jnp.where(active, pos + 1, 0)
-        # the Pallas kernel is a one-device program: a pool sharded over
-        # this engine's mesh takes the portable path under GSPMD
-        attn_impl = "auto" if self._mesh is None else "ref"
-
-        # the scopes below are the step's stable names on the device
-        # trace (metadata only: the compiled program is the same)
-        def layer(carry, xs):
-            h, kc, vc = carry
-            lp, base = xs
-            with jax.named_scope("attn_proj"):
-                x = _norm(h, lp["attn_norm_w"], lp.get("attn_norm_b"), cfg)
-                q = self._wdot(x, lp["wq"]).reshape(t, hq, dh)
-                k = self._wdot(x, lp["wk"]).reshape(t, hkv, dh)
-                v = self._wdot(x, lp["wv"]).reshape(t, hkv, dh)
-                if cfg.use_rope:
-                    q = _rope_at(q, cos, sin, pos)
-                    k = _rope_at(k, cos, sin, pos)
-            with jax.named_scope("kv_update"):
-                kc = kc.at[base + blk, off].set(k.astype(kc.dtype))
-                vc = vc.at[base + blk, off].set(v.astype(vc.dtype))
-            with jax.named_scope("attn"):
-                # read AFTER the scatter: a draft or chunk row sees the
-                # rows before it in this very step
-                attn = paged_attention(q, kc, vc, base + tables, lens,
-                                       scale, impl=attn_impl)
-            with jax.named_scope("attn_proj"):
-                h2 = h + self._wdot(attn.reshape(t, hq * dh),
-                                    lp["wo"]).astype(h.dtype)
-            with jax.named_scope("mlp"):
-                x2 = _norm(h2, lp["mlp_norm_w"], lp.get("mlp_norm_b"),
-                           cfg)
-                return (h2 + self._mlp(x2, lp).astype(h.dtype), kc,
-                        vc), None
-
-        # comm_scale: the trace-time comm ledgers see one body trace of
-        # the scan; the hardware runs it n_layers times per step — the
-        # MoE a2a sites record honest per-step executions/bytes
-        from hadoop_tpu.obs.comm import comm_scale
-        if self._dsa:
-            # a scan per run of like layers over per-kind stacks; the
-            # rows in table-sharing groups: each lane's row(s) by the
-            # lane's table, the chunk's rows by one table
-            groups = [(0, tables_s, lens[:B * G].reshape(B, G))]
-            if chunk is not None:
-                groups.append((B * G, tables_s[c_slot][None, :],
-                               lens[B * G:][None, :]))
-            h, kp, vp, moe_stats = _dsv32.run_layers(
-                params, h, kp, vp, cfg,
-                {"pos": pos, "blk": blk, "off": off, "active": active,
-                 "groups": groups, "cos": cos, "sin": sin})
-        else:
-            # the pools ride the scan as its carry, viewed
-            # [layers * blocks, bs, hkv, dh]: a layer writes and reads
-            # its pages where they lie, at layer * blocks + page
-            pool_shape = kp.shape
-            n_blocks = pool_shape[1]
-            kp = kp.reshape((-1,) + pool_shape[2:])
-            vp = vp.reshape((-1,) + pool_shape[2:])
-            with comm_scale(cfg.n_layers):
-                (h, kp, vp), _ = jax.lax.scan(
-                    layer, (h, kp, vp),
-                    (params["layers"],
-                     jnp.arange(cfg.n_layers, dtype=jnp.int32) * n_blocks))
-            kp = kp.reshape(pool_shape)
-            vp = vp.reshape(pool_shape)
+        h, kp, vp, stats = self._family.run_layers(params, h, kp, vp, {
+            "pos": pos, "blk": blk, "off": off, "active": active,
+            "lens": lens, "tables": tables, "tables_s": tables_s,
+            "B": B, "G": G, "cos": cos, "sin": sin,
+            "chunk_slot": None if chunk is None else c_slot})
         with jax.named_scope("head_sample"):
             h = _norm(h, params["final_norm_w"], params.get("final_norm_b"),
                       cfg)
@@ -1051,13 +815,12 @@ class DecodeEngine:
                 [out, n_emit[:, None], finished.astype(jnp.int32)[:, None],
                  accept[:, None]],
                 axis=1)                                         # [B, G + 3]
-            if self._dsa:
-                # the expert layers' own count rides the same read-back:
-                # two more columns, whose first row holds the step's
-                # assignments to held experts and held experts hit
+            n_stats = len(self._family.counters)
+            if n_stats:
+                # the layers' own counts: a column each, in row 0
                 packed = jnp.concatenate(
                     [packed,
-                     jnp.zeros((B, 2), jnp.int32).at[0].set(moe_stats)],
+                     jnp.zeros((B, n_stats), jnp.int32).at[0].set(stats)],
                     axis=1)
         if chunk is None:
             return kp, vp, new_state, packed
@@ -1079,7 +842,6 @@ class DecodeEngine:
             # the long-context lane: CP prefill across the mesh, KV
             # streamed into the cold tiers, working-set decode — the
             # prompt never has to fit this engine's pool or s_max
-            from hadoop_tpu.tracing.tracer import current_context
             return self._relaxed_longctx.longctx_submit(
                 prompt, sampling,
                 trace_ctx=trace_ctx or current_context(), tenant=tenant)
@@ -1097,7 +859,6 @@ class DecodeEngine:
             raise ValueError(
                 f"request needs {pages} KV pages but the pool holds only "
                 f"{self.pool.num_usable} — it could never run alone")
-        from hadoop_tpu.tracing.tracer import current_context
         req = GenRequest(prompt=list(prompt), sampling=sampling,
                          trace_ctx=trace_ctx or current_context(),
                          tenant=tenant)
@@ -1170,7 +931,7 @@ class DecodeEngine:
         dtype, MEASURED weight bytes, quantize-at-load seconds, and the
         lanes x context the KV budget admits at those bytes."""
         desc = self._weight_desc
-        plane = {
+        return {
             "parity": "relaxed" if self._relaxed_weights else "bitwise",
             "dtype": desc["dtype"],
             "weight_bytes": self.weight_bytes,
@@ -1186,17 +947,8 @@ class DecodeEngine:
             "experts": self.cfg.n_experts,
             "expert_shards": self.expert_shards,
             "expert_bytes": self.expert_bytes,
-        }
-        if self._dsa:
-            # a share of a wider router: no capacity (every assignment
-            # to a held expert is computed), no exchange on one chip
-            plane["experts_routed"] = self.cfg.n_routed_experts
-            plane["experts_from"] = self.cfg.experts_from
-        elif self.cfg.is_moe:
-            plane["expert_capacity"] = moe_capacity(
-                self.max_batch * (self.spec_k + 1), self._moe_cfg)
-            plane["a2a_codec"] = self._moe_a2a_codec
-        return plane
+            **self._family.describe_experts(
+                self.max_batch * (self.spec_k + 1))}
 
     def cache_stats(self) -> Dict[str, Any]:
         """Prefix-cache + chunked-prefill observability (health, bench)."""
@@ -1504,23 +1256,13 @@ class DecodeEngine:
         psp.finish()
 
     def _fresh_kv_pools(self):
-        """Zeroed paged K/V pools, sharded when the engine owns a mesh
-        — construction and the failed-step recovery path share it."""
-        if self._dsa:
-            cfg = self.cfg
-            return (jnp.zeros(self._pool_shape
-                              + (_dsv32.latent_width(cfg),), cfg.jax_dtype),
-                    jnp.zeros(self._pool_shape + (cfg.index_head_dim,),
-                              cfg.jax_dtype))
-        kp = jnp.zeros(self._pool_shape, self.cfg.jax_dtype)
-        vp = jnp.zeros(self._pool_shape, self.cfg.jax_dtype)
-        if self._kv_sharding is not None:
-            kp = jax.device_put(kp, self._kv_sharding)
-            vp = jax.device_put(vp, self._kv_sharding)
-        elif self._carry_sharding is not None:
-            kp = jax.device_put(kp, self._carry_sharding)
-            vp = jax.device_put(vp, self._carry_sharding)
-        return kp, vp
+        """Zeroed paged pools, sharded when the engine owns a mesh —
+        construction and the failed-step recovery path share it."""
+        pools = tuple(jnp.zeros(shape, self.cfg.jax_dtype)
+                      for shape in self._pool_shapes)
+        sharding = self._kv_sharding or self._carry_sharding
+        return pools if sharding is None \
+            else jax.device_put(pools, sharding)
 
     def _fresh_dstate(self) -> dict:
         """Zeroed device-resident step state, every lane cleared. Used
@@ -1656,10 +1398,9 @@ class DecodeEngine:
     def _count_attn_pages(self, pre: Optional[GenRequest],
                           n_valid: int) -> None:
         """The live-page share of this step's attention, from the host's
-        mirrors (no device read-back): pages its live rows attend to —
-        a lane's row ``j`` at position ``p + j`` reads the pages of
-        ``p + j + 1`` tokens, a chunk row likewise — against the pages
-        of every row's whole table."""
+        mirrors: pages its live rows attend to (a row at position ``p``
+        reads the pages of ``p + 1`` tokens) against every row's whole
+        table; then what only the family counts."""
         bs = self.block_size
         j = np.arange(self.spec_k + 1)
         lanes = np.flatnonzero(self._active)
@@ -1672,30 +1413,17 @@ class DecodeEngine:
             rows += self.prefill_chunk
         self.metrics.attn_pages_read.incr(int(np.sum(-(-lens // bs))))
         self.metrics.attn_pages_dense.incr(rows * self.blocks_per_seq)
-        if self._dsa:
-            # the sparse selection: entries the live rows could attend to
-            # against the entries they keep (a layer; every layer alike)
-            cfg = self.cfg
-            self.metrics.attn_entries_live.incr(int(np.sum(lens)))
-            self.metrics.attn_entries_selected.incr(
-                int(np.sum(np.minimum(lens, cfg.index_topk))))
-            self.metrics.moe_assignments.incr(
-                int(lens.size) * cfg.top_k
-                * (cfg.n_layers - cfg.n_dense_layers))
-            # distinct pages under those rows, from below: requests whose
-            # tables start with the same page share a radix chain, and
-            # the longest of them alone holds that many pages
-            chains: Dict[int, int] = {}
-            for slot in lanes:
-                req = self._slots[slot]
-                chains[req._blocks[0]] = max(
-                    chains.get(req._blocks[0], 0),
-                    -(-(int(self._seq_lens[slot]) + 1) // bs))
-            if pre is not None and n_valid:
-                chains[pre._blocks[0]] = max(
-                    chains.get(pre._blocks[0], 0),
-                    -(-(pre._prefill_pos + n_valid) // bs))
-            self.metrics.attn_pages_distinct.incr(sum(chains.values()))
+        self._family.count_step(self.metrics, lens,
+                                self._chains(lanes, pre, n_valid))
+
+    def _chains(self, lanes, pre: Optional[GenRequest], n_valid: int):
+        """(first page, pages held) per live request; lazy."""
+        bs = self.block_size
+        for slot in lanes:
+            yield (self._slots[slot]._blocks[0],
+                   -(-(int(self._seq_lens[slot]) + 1) // bs))
+        if pre is not None and n_valid:
+            yield pre._blocks[0], -(-(pre._prefill_pos + n_valid) // bs)
 
     def _deliver_step(self, packed, pre: Optional[GenRequest],
                       n_valid: int, c_first, proposed: int,
@@ -1706,9 +1434,9 @@ class DecodeEngine:
         G = self.spec_k + 1
         self.steps += 1
         self._chunk_fill = n_valid
-        if self._dsa and self.metrics:
-            self.metrics.moe_assignments_local.incr(int(packed[0, G + 3]))
-            self.metrics.moe_local_experts_hit.incr(int(packed[0, G + 4]))
+        for j, name in enumerate(self._family.counters if self.metrics
+                                 else ()):
+            getattr(self.metrics, name).incr(int(packed[0, G + 3 + j]))
         emitted = 0
         self.occupancy_log.append(self.num_active)
         if len(self.occupancy_log) > 100_000:
@@ -1848,18 +1576,13 @@ class DecodeEngine:
             fsp.add_kv("prefill_wait_s", f"{stages['prefill_wait']:.6f}")
             fsp.add_kv("prefill_service_s", f"{stages['prefill']:.6f}")
             fsp.finish()
-        self._maybe_finish(req, tok)
+        if self._exhausted(req):
+            self._release_slot(req)
+            self._finish_request(req, FINISHED)
         if req._slot is not None:
             # still running: arm the device lane (active, position at
             # the context tip, budget counters) in one scatter
             self._push_slot(slot, req)
-
-    def _maybe_finish(self, req: GenRequest, tok: int) -> None:
-        sp = req.sampling
-        if len(req.out_tokens) >= sp.max_new_tokens or \
-                (sp.stop_token is not None and tok == sp.stop_token):
-            self._release_slot(req)
-            self._finish_request(req, FINISHED)
 
     def _publish_metrics(self) -> None:
         if not self.metrics:
@@ -1918,7 +1641,6 @@ class DecodeEngine:
         with self._cond:
             self._cond.notify_all()
         # a stopped engine's pool must not haunt the HBM ledger
-        from hadoop_tpu.obs.hbm import hbm_ledger
         hbm_ledger().unregister_prefix(self._hbm_owner)
         if self._thread is not None:
             self._thread.join(timeout=timeout)

@@ -1,0 +1,102 @@
+"""The seam between ``DecodeEngine`` and a model family.
+
+"What a token's cache entry is and which layers read it" is one
+decision, and a family owns it. The engine keeps scheduling, pages,
+tiers, row building, embedding, head, sampling and the read-back, and
+asks a family for the members of ``Family`` below — nothing else; it
+names no family. A new family is a module here, its math under
+``models/`` and a line in ``FAMILIES`` (README, "Adding a family to the
+serving path"). This lives under ``serving/`` because the dense
+family's matmuls need ``weightplane`` and the exchange codec:
+``models/`` must not import upward.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Iterable, Mapping, Tuple
+
+from jax.sharding import PartitionSpec
+
+from hadoop_tpu.models.config import ModelConfig
+
+
+# two planes a family may need to know of, by the key they refuse under
+RELAXED = "serving.parity=relaxed"
+TP_PLAN = "a tp plan (serving.tp)"
+
+
+class Family:
+    """One per engine, built by ``family_for(cfg, asked, **options)``:
+    ``asked`` maps a plane's conf key to whether the engine was asked
+    for it, ``options`` are the engine's ``moe_*`` arguments, a family's
+    to read or ignore. Members with a body here are optional."""
+
+    # a token's entry in the K-slot and the V-slot pool (trailing
+    # shape): both pools are ``[layers, blocks, block_size, *entry]``,
+    # in ``cfg.jax_dtype``, sharing one block table
+    entry_shapes: Tuple[Tuple[int, ...], Tuple[int, ...]]
+    # the (kv_heads, head_dim) pair the chain salt is made of: prefixes
+    # persisted to the DFS tier are keyed by it, so it never changes
+    salt_layout: Tuple[int, int]
+    # both pools' spec on a tp mesh (replicated unless the family says)
+    pool_spec = PartitionSpec()
+    # how many local chips the expert stacks split over (0: no experts)
+    expert_shards = 0
+    # ServingMetrics counters fed by ``run_layers``' stats columns, in
+    # order: the packed read-back is that many columns wider
+    counters: Tuple[str, ...] = ()
+
+    def __init__(self, cfg: ModelConfig, asked: Mapping[str, Any],
+                 **options):
+        self.cfg = cfg
+        self.refuse(asked)
+
+    def refuse(self, asked: Mapping[str, Any]) -> None:
+        """Raise NotImplementedError naming the conf key of a plane
+        asked for that this family is not built for (at construction,
+        and again when a long-context plane is attached)."""
+
+    def place_experts(self, params):
+        """(params with the expert stacks split over ``expert_shards``
+        chips, the sharding the step's carried buffers then take)."""
+        return params, None
+
+    def rope_tables(self):
+        """(cos, sin), traced inside the step; none by default."""
+        return None, None
+
+    def run_layers(self, params, h, kp, vp, rows: Dict[str, Any]):
+        """All layers over the step's rows ``h [T, D]``: every row is
+        one token at one position; scatter each live row's entry into
+        the pools, then attend. ``rows``: ``pos``, ``blk`` (the page a
+        row writes; scratch for a dead row), ``off``, ``active``,
+        ``lens`` (``pos + 1``, 0 for a dead row), ``tables`` — all per
+        row; the lanes' ``tables_s [B, bps]``; ``B`` lanes of ``G`` rows
+        come first, then the chunk's rows, which share lane
+        ``chunk_slot``'s table (None: no chunk); ``cos``, ``sin``.
+        Returns ``(h, kp, vp, stats)``, the pools in their given shape;
+        ``stats`` is int32 ``[len(counters)]``, or ``()`` for none."""
+        raise NotImplementedError
+
+    def count_step(self, metrics, lens, chains: Iterable) -> None:
+        """Once a step, on the host, before dispatch: count what only
+        this family has. ``lens``: the live rows' lengths; ``chains``
+        yields ``(first page, pages held)`` per live request, lazily."""
+
+    def describe_experts(self, rows: int) -> Dict[str, Any]:
+        """What ``weight_plane()`` says of the experts beyond their
+        count, shards and bytes; ``rows`` = lanes x rows a lane."""
+        return {}
+
+
+from hadoop_tpu.serving.families.gqa import PagedKVFamily  # noqa: E402
+from hadoop_tpu.serving.families.latent import LatentFamily  # noqa: E402
+
+FAMILIES: Dict[str, type] = {
+    "gpt2": PagedKVFamily, "llama": PagedKVFamily,
+    "mixtral": PagedKVFamily, "deepseek_v32": LatentFamily}
+
+
+def family_for(cfg: ModelConfig, asked: Mapping[str, Any],
+               **options) -> Family:
+    return FAMILIES[cfg.family](cfg, asked, **options)

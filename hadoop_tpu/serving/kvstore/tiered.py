@@ -156,9 +156,6 @@ class TieredKVCache:
     def dfs_enabled(self) -> bool:
         return self.dfs is not None
 
-    def set_extract(self, fn: Callable) -> None:
-        self._extract = fn
-
     # ---------------------------------------------------------- demotion
 
     def demote(self, node: _RadixNode) -> None:

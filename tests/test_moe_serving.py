@@ -1,4 +1,4 @@
-"""Expert-parallel MoE serving (serving/engine.py ``_moe_mlp`` + the
+"""Expert-parallel MoE serving (serving/families/gqa.py ``moe_mlp`` + the
 weight plane's expert stacks).
 
 Pins the contracts the workload class ships under:
@@ -180,7 +180,7 @@ def test_dropped_token_residual_passthrough_exact(moe_model):
     router[0, 0] = 1.0
     lp["router"] = jnp.asarray(router)
     x = jnp.tile(jnp.eye(1, D, 0, dtype=jnp.float32) * 5.0, (8, 1))
-    y = eng._moe_mlp(x, lp)
+    y = eng._family.moe_mlp(x, lp)
     assert y.shape == (8, D)
     y = np.asarray(y)
     # kept rows produce a real MLP contribution...

@@ -1,0 +1,189 @@
+"""The seam between ``DecodeEngine`` and a model family
+(``hadoop_tpu/serving/families``).
+
+- the engine's source names no family: what a token's cache entry is and
+  which layers read it is the family's, asked for through ``Family``'s
+  members alone;
+- a family is additive: a toy one defined HERE (an entry of another
+  width in each pool, layers that change nothing, one stats column),
+  registered by patching the dict, serves requests through the unedited
+  engine and its column reaches the counter it names;
+- what the engine derives from a family is pinned to the values the
+  engine before the seam held (pool shapes, a page's bytes, the chain
+  salt that DFS-persisted prefixes are keyed by, the read-back's width,
+  ``weight_plane()``'s keys).
+"""
+
+import dataclasses
+import pathlib
+import re
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from hadoop_tpu.models import decoder, deepseek
+from hadoop_tpu.models.config import PRESETS, get_config
+from hadoop_tpu.serving import engine as engine_mod
+from hadoop_tpu.serving import families
+from hadoop_tpu.serving.engine import DecodeEngine, SamplingParams
+from hadoop_tpu.serving.metrics import ServingMetrics
+
+
+def test_engine_source_names_no_family():
+    src = pathlib.Path(engine_mod.__file__).read_text()
+    assert re.findall(r"_dsa|_dsv32|deepseek|cfg\.family", src) == []
+    # the expert plane is a family's too: the one question left is
+    # whether the HBM ledger gets a moe_experts component
+    assert src.count("is_moe") == 1
+    for name in families.FAMILIES:
+        assert name not in src, name
+
+
+def test_every_preset_has_a_family_and_its_counters_exist():
+    assert {c.family for c in PRESETS.values()} <= set(families.FAMILIES)
+    metrics = ServingMetrics("serving.test.families.names")
+    for cls in set(families.FAMILIES.values()):
+        for name in cls.counters:
+            assert hasattr(getattr(metrics, name), "incr"), name
+
+
+# ------------------------------------------------------------ a toy family
+
+class ToyFamily(families.Family):
+    """Entries 3 and 5 wide; a "layer" that writes each live row's
+    position into its entry and leaves ``h`` alone; one stats column:
+    the live rows."""
+    counters = ("attn_pages_distinct",)
+    entry_shapes = ((3,), (5,))
+    salt_layout = (1, 8)
+    refused = []
+
+    def refuse(self, asked):
+        self.refused.append(dict(asked))
+        if asked.get("serving.speculate.k"):
+            raise NotImplementedError("toy: serving.speculate.k")
+
+    def run_layers(self, params, h, kp, vp, rows):
+        mark = rows["lens"].astype(kp.dtype)[:, None]
+        kp = kp.at[0, rows["blk"], rows["off"]].set(
+            jnp.broadcast_to(mark, (h.shape[0], 3)))
+        vp = vp.at[0, rows["blk"], rows["off"]].set(
+            jnp.broadcast_to(-mark, (h.shape[0], 5)))
+        return h, kp, vp, jnp.sum(rows["active"], dtype=jnp.int32)[None]
+
+    def describe_experts(self, rows):
+        return {"toy_rows": rows}
+
+
+@pytest.fixture()
+def toy():
+    cfg = dataclasses.replace(get_config("tiny"), family="toy",
+                              dtype="float32")
+    key = jax.random.PRNGKey(5)
+    params = {"embed": jax.random.normal(key, (cfg.vocab_size, cfg.d_model)),
+              "final_norm_w": jnp.ones((cfg.d_model,)),
+              "lm_head": jax.random.normal(jax.random.fold_in(key, 1),
+                                           (cfg.d_model, cfg.vocab_size))}
+    ToyFamily.refused = []
+    with mock.patch.dict(families.FAMILIES, {"toy": ToyFamily}):
+        yield params, cfg
+
+
+def test_a_toy_family_serves_through_the_unedited_engine(toy):
+    params, cfg = toy
+    metrics = ServingMetrics("serving.test.families.toy")
+    before = metrics.attn_pages_distinct.value()
+    eng = DecodeEngine(params, cfg, max_batch=2, block_size=4, num_blocks=9,
+                       max_context=32, prefill_chunk=8, metrics=metrics)
+    assert eng._kp.shape == (cfg.n_layers, 9, 4, 3)
+    assert eng._vp.shape == (cfg.n_layers, 9, 4, 5)
+    assert eng.block_nbytes == cfg.n_layers * 4 * 4 * (3 + 5)
+    assert eng.expert_shards == 0
+    assert eng.weight_plane()["toy_rows"] == 2
+    prompts = [[7, 3, 11, 5, 2], [9, 1]]
+    outs = eng.generate(prompts, SamplingParams(max_new_tokens=4))
+
+    # layers that change nothing: a token follows from the one before
+    def after(tok):
+        h = decoder._norm(params["embed"][tok][None], params["final_norm_w"],
+                          None, cfg)
+        return int(jnp.argmax(h @ params["lm_head"]))
+    for prompt, out in zip(prompts, outs):
+        want, tok = [], prompt[-1]
+        for _ in range(4):
+            tok = after(tok)
+            want.append(tok)
+        assert out == want
+    # the stats column reached the counter it names: every prompt token
+    # and every decode step's token was one live row
+    rows = sum(len(p) + 3 for p in prompts)
+    assert metrics.attn_pages_distinct.value() - before == rows
+    # and the rows landed in both pools' pages, layer 0, at their offsets
+    kp, vp = np.asarray(eng._kp), np.asarray(eng._vp)
+    written = kp[0, 1:, :, 0].ravel()
+    assert sorted(written[written != 0]) == sorted(
+        list(range(1, 5 + 4)) + list(range(1, 2 + 4)))
+    assert np.array_equal(vp[0, 1:, :, 4], -kp[0, 1:, :, 2])
+    assert not kp[1:].any()
+    eng.stop()
+
+
+def test_a_family_is_asked_by_conf_key_what_it_refuses(toy):
+    params, cfg = toy
+    kw = dict(max_batch=2, block_size=4, num_blocks=9, max_context=32)
+    with pytest.raises(NotImplementedError, match="serving.speculate.k"):
+        DecodeEngine(params, cfg, speculate_k=2, **kw)
+    eng = DecodeEngine(params, cfg, **kw)
+    asked = ToyFamily.refused[-1]
+    assert set(asked) == {
+        "serving.parity=relaxed", "a tp plan (serving.tp)",
+        "serving.kv.host.bytes", "serving.kv.dfs.enable",
+        "serving.speculate.k", "serving.moe.shards"}
+    assert not any(asked.values())
+    eng.attach_longctx(mock.Mock())
+    assert ToyFamily.refused[-1] == {"serving.longctx.enable": True}
+    eng._relaxed_longctx = None
+    eng.stop()
+
+
+# ------------------------------------------- pins taken from the parent
+
+PINS = {
+    "tiny": ((4, 9, 4, 2, 16), (4, 9, 4, 2, 16), 4096,
+             "bf7f3adbf5a543b373d3e78f082448bcd7bbcdfb587dad4a484122dda7c8f185",
+             (2, 4), set()),
+    "tiny-moe": ((2, 9, 4, 2, 16), (2, 9, 4, 2, 16), 2048,
+                 "19670316828032032e0608387260f35eb30dce94605cc2967180fe981732c4b8",
+                 (2, 4), {"a2a_codec", "expert_capacity"}),
+    "tiny-dsv32": ((3, 9, 4, 128), (3, 9, 4, 16), 6912,
+                   "a2045a68068ed982674bd8b7b33b6b2cd267f1a2ca9e359ee30acb0e52705e91",
+                   (2, 6), {"experts_from", "experts_routed"}),
+}
+PLANE_KEYS = {"dtype", "expert_bytes", "expert_shards", "experts",
+              "hbm_bytes", "kv_capacity_tokens", "lanes", "lanes_x_context",
+              "max_context", "parity", "quantize_seconds",
+              "quantized_leaves", "weight_bytes"}
+
+
+@pytest.mark.parametrize("name", sorted(PINS))
+def test_what_the_engine_derives_is_what_it_held_before(name):
+    kp_shape, vp_shape, nbytes, salt, packed, extra = PINS[name]
+    cfg = get_config(name)
+    init = deepseek.init_params if name == "tiny-dsv32" \
+        else decoder.init_params
+    eng = DecodeEngine(init(jax.random.PRNGKey(0), cfg), cfg, max_batch=2,
+                       block_size=4, num_blocks=9, max_context=32,
+                       prefill_chunk=8)
+    assert (eng._kp.shape, eng._vp.shape) == (kp_shape, vp_shape)
+    assert eng.block_nbytes == nbytes
+    assert eng.block_nbytes * 9 == eng._kp.nbytes + eng._vp.nbytes
+    assert eng.kvstore.chain_salt.hex() == salt
+    out = jax.eval_shape(eng._step_impl, eng.params, eng._kp, eng._vp,
+                         eng._dstate, eng._dz_drafts, eng._dz_lens, None)
+    assert out[3].shape == packed
+    assert out[3].shape[1] == 4 + len(eng._family.counters)
+    assert set(eng.weight_plane()) == PLANE_KEYS | extra
+    eng.stop()
