@@ -63,14 +63,35 @@ Design (TPU-first, same rules as the trainer):
 - **Device-resident step state.** Block tables, positions, last
   tokens, active mask, sampling params, token budgets and the PRNG
   seed live ON DEVICE and are carried through the donated step. State
-  changes ride small event scatters (``_SET_SLOT`` / ``_SET_TABLE``)
-  on admission, prefill completion, page growth, preemption and
-  release — events, not steps. The stop-condition scan (max_new
-  budget, stop_token) runs INSIDE the compiled step, and the host
-  reads back one packed ``[B, k+4]`` bundle per step (sampled tokens,
-  emit counts, finished mask, verifier accept lengths). In
+  changes ride small event scatters (``_SET_SLOT`` / ``_SET_TABLE``
+  / ``_ARM_SLOT``) on admission, prefill completion, page growth,
+  preemption and release — events, not steps. The stop-condition scan
+  (max_new budget, stop_token) runs INSIDE the compiled step, and the
+  host reads back one packed ``[B, k+4]`` bundle per step (sampled
+  tokens, emit counts, finished mask, verifier accept lengths). In
   steady-state decode the hot loop transfers nothing host→device
   (tests pin this with a ``jax.transfer_guard``).
+
+- **One step ahead of the read-back.** Step n+1's inputs are step n's
+  DEVICE outputs, so the serving thread's loop is: admit and ensure
+  pages for step n+1 → dispatch n+1 → THEN read back and deliver step
+  n (the one device→host read of a step, which by then returns at
+  once) → publish. The device's queue holds its next step when the
+  current one ends; the host's part of an iteration runs under the
+  device's instead of beside it. What the host must know at dispatch
+  it knows from its own numbers: a lane's next write lands at its
+  mirror position plus the step in flight; a prompt's chunks advance
+  at dispatch; the lane of a prompt whose last chunk is on the device
+  is armed there, from the step's own first sample; a lane at the end
+  of its budget is not run again; a row of a bundle belongs to the
+  request that held the slot when the step was dispatched. A token is
+  delivered no later than one step after the one that made it.
+  Everything that is not the steady loop drains first (``_drain``):
+  preemption on a dry pool, the tier page movers, ``persist_cache``,
+  ``prefill_to_store``, ``stop``, and ``step()`` itself, which runs
+  the same iteration whole. An engine that speculates does not run
+  ahead: its drafts for step n+1 are made on the host from the tokens
+  of step n.
 
 - **Speculative decoding.** A third lane in the SAME compiled step:
   a host-side n-gram index over each request's prompt + generated
@@ -179,7 +200,7 @@ _EXTRACT = jax.jit(_extract_impl)
 
 
 # device-resident step-state event movers: the ONLY host→device traffic
-# of the steady-state decode loop is these two scatters, and they fire
+# of the steady-state decode loop is these three scatters, and they fire
 # on slot lifecycle events (admission, prefill completion, page growth,
 # preemption, release) — never per step. Module-level jits like
 # _INJECT/_EXTRACT: one trace per state layout for the process
@@ -211,8 +232,27 @@ def _set_table_impl(state, ints):
     return out
 
 
+def _arm_slot_impl(state, ints, first):
+    """Flip a slot whose prompt is fully cached to a decode lane, from
+    the fused step's own ``c_first`` — ``first`` is that device scalar,
+    never read by the host on the way, so the next step can be
+    dispatched before this one's read-back. ``ints`` = [slot, position,
+    out_count]. A first token that is the lane's stop token leaves the
+    lane off, as the step's own stop scan would."""
+    slot = ints[0]
+    stop = state["stopt"][slot]
+    out = dict(state)
+    out["positions"] = state["positions"].at[slot].set(ints[1])
+    out["last"] = state["last"].at[slot].set(first)
+    out["active"] = state["active"].at[slot].set(
+        ~((stop >= 0) & (first == stop)))
+    out["outc"] = state["outc"].at[slot].set(ints[2])
+    return out
+
+
 _SET_SLOT = jax.jit(_set_slot_impl, donate_argnums=(0,))
 _SET_TABLE = jax.jit(_set_table_impl, donate_argnums=(0,))
+_ARM_SLOT = jax.jit(_arm_slot_impl, donate_argnums=(0,))
 
 
 # --------------------------------------------------------------- requests
@@ -288,6 +328,22 @@ class GenRequest:
         if self.state == FAILED:
             raise RuntimeError(self.error or "generation failed")
         return list(self.out_tokens)
+
+
+@dataclass
+class _Flight:
+    """A step that is on the device and not read back yet, with what
+    the host needs to deliver it later: a row of the bundle belongs to
+    the request that held the slot WHEN THE STEP WAS DISPATCHED (the
+    slot may have been released, and placed again, since)."""
+    packed: Any                     # the [B, G+3] bundle, on the device
+    c_first: Any                    # the chunk's sample (fused shape)
+    rows: List[tuple]               # (slot, request) of the lanes it ran
+    pre: Optional[GenRequest]       # whose prompt chunk rode along
+    n_valid: int                    # tokens of that chunk
+    last_chunk: bool                # ... and it was the prompt's last
+    proposed: int                   # draft tokens it verifies
+    t0: float                       # when its device time began
 
 
 # ----------------------------------------------------------------- engine
@@ -493,13 +549,13 @@ class DecodeEngine:
         # one the compiled step consumes and advances.
         self._tables = np.zeros((max_batch, self.blocks_per_seq), np.int32)
         self._seq_lens = np.zeros((max_batch,), np.int32)
-        self._last_tokens = np.zeros((max_batch,), np.int32)
         self._active = np.zeros((max_batch,), bool)
         self._slots: List[Optional[GenRequest]] = [None] * max_batch
         # device-resident step state: carried (donated) through every
         # step, mutated from the host ONLY by slot lifecycle events via
-        # _SET_SLOT/_SET_TABLE. "seed" replaces the per-step host
+        # _SET_SLOT/_SET_TABLE/_ARM_SLOT. "seed" replaces the per-step host
         # PRNGKey upload — the key is derived in-graph.
+        self._dispatched = 0        # steps ever dispatched: the seed
         self._dstate = self._fresh_dstate()
         # per-step draft proposals (host-filled when speculating); the
         # device-resident zero twins are dispatched on steps with no
@@ -508,6 +564,16 @@ class DecodeEngine:
         self._draft_lens = np.zeros((max_batch,), np.int32)
         self._dz_drafts = jnp.zeros((max_batch, self.spec_k), jnp.int32)
         self._dz_lens = jnp.zeros((max_batch,), jnp.int32)
+        # the step in flight (dispatched, not read back) and, per lane,
+        # the steps it has there: the mirrors above are as of the last
+        # step DELIVERED, and a lane's next write lands at its mirror
+        # position plus these. The serving thread keeps one step ahead
+        # of its own read-back unless the host computes an input of
+        # step n+1 from an output of step n, as the draft proposer does.
+        self._flight: Optional[_Flight] = None
+        self._in_flight = np.zeros((max_batch,), np.int32)
+        self._runs_ahead = self.spec_k == 0
+        self.steps_run_ahead = 0    # dispatched with the last unread
 
         # the admission seam: a deque by default, or any deque-shaped
         # queue (append/appendleft/popleft/len/[0]) — the door's QoS
@@ -579,13 +645,16 @@ class DecodeEngine:
 
     def _extract_block(self, blk: int):
         """One page's (K, V) payload to host numpy — the demotion /
-        persistence copy. Fixed-shape jit, compiled once per layout."""
+        persistence copy. Fixed-shape jit, compiled once per layout.
+        Drains first (scheduler lock held, as for every tier move)."""
+        self._drain()
         k, v = _EXTRACT(self._kp, self._vp, jnp.int32(blk))
         return np.asarray(k), np.asarray(v)
 
     def _inject_block(self, blk: int, k, v) -> None:
         """Scatter a cold-tier payload into pool page ``blk`` (donated
-        buffers — no pool-sized copy, no new compile)."""
+        buffers — no pool-sized copy, no new compile). Drains first."""
+        self._drain()
         self._kp, self._vp = _INJECT(
             self._kp, self._vp, jnp.int32(blk),
             jnp.asarray(k, self._kp.dtype),
@@ -982,17 +1051,28 @@ class DecodeEngine:
     # ------------------------------------------------------ the scheduler
 
     def step(self) -> int:
-        """One scheduler iteration: admit waiting requests into free
-        slots (mapping any cached prefix), propose draft tokens for
+        """One scheduler iteration, whole: admit waiting requests into
+        free slots (mapping any cached prefix), propose draft tokens for
         the speculation lane, ensure every decoding request has pages
         for this step's tokens, run the fused decode+prefill-chunk
-        step, retire finished requests. Returns the number of tokens
-        emitted."""
+        step, read it back, retire finished requests. Nothing is in
+        flight on return, and the tokens emitted are returned: tests,
+        ``generate()`` and the offline bench drive this; the serving
+        thread (``_run_loop``) runs the same iteration one step ahead
+        of its read-back."""
         with self._sched_lock:
-            began = (time.monotonic(),
-                     self.phase_s.get("engine.wait", 0.0),
-                     self._decode_only_compiles + self._fused_compiles)
-            steps = self.steps
+            return self._iterate(ahead=False)
+
+    def _iterate(self, ahead: bool) -> int:
+        """admit → (propose) → pages → dispatch step n+1 → read back
+        and deliver step n → publish; scheduler lock held. With
+        ``ahead`` the new step stays in flight, so the device holds its
+        next step while the host delivers, admits and dispatches;
+        without, it is read back and delivered here too."""
+        began = (time.monotonic(),
+                 self.phase_s.get("engine.wait", 0.0),
+                 self._decode_only_compiles + self._fused_compiles)
+        try:
             with self._phase("engine.admit"):
                 self._admit()
             if self.spec_k:
@@ -1000,12 +1080,33 @@ class DecodeEngine:
                     self._propose_drafts()
             with self._phase("engine.pages"):
                 self._ensure_blocks()
-            emitted = self._run_step()
-            if self.steps != steps:
+            with self._phase("engine.dispatch"):
+                flight = self._dispatch()
+            before, self._flight = self._flight, flight
+            emitted = self._deliver(before) if before is not None else 0
+            if flight is not None:
                 self._log_iteration(began)
-            with self._phase("engine.publish"):
-                self._publish_metrics()
-            return emitted
+                if not ahead or all(r is None for r in self._slots):
+                    # (nobody is left to run: what flies is stale rows)
+                    emitted += self._drain()
+        except BaseException:
+            # a failed step takes what was in flight with it
+            self._flight = None
+            self._in_flight[:] = 0
+            raise
+        with self._phase("engine.publish"):
+            self._publish_metrics()
+        return emitted
+
+    def _drain(self) -> int:
+        """Read back and deliver the step in flight, if there is one;
+        scheduler lock held. Everything that is not the steady loop
+        calls this first — a preemption on a dry pool, the tier page
+        movers, ``persist_cache``, ``prefill_to_store``, ``stop`` — so
+        that the mirrors, the pool's counts and the radix index are
+        those of a device with nothing pending."""
+        flight, self._flight = self._flight, None
+        return self._deliver(flight) if flight is not None else 0
 
     def _phase(self, name: str) -> phase:
         return phase(name, self.phase_s)
@@ -1140,6 +1241,10 @@ class DecodeEngine:
         got = self.pool.alloc(n)
         if got is not None or self.prefix_cache is None:
             return got
+        if self.kvstore.host is not None:
+            # a victim's demotion reads its page: not under the eviction
+            # walk, where a delivery would change the index it walks
+            self._drain()
         evicted = self.prefix_cache.evict(n - self.pool.num_free,
                                           self.pool.refcount,
                                           on_evict=self.kvstore.demote)
@@ -1168,7 +1273,6 @@ class DecodeEngine:
         self._tables[slot] = row
         self._seq_lens[slot] = 0
         self._active[slot] = False
-        self._last_tokens[slot] = 0
         # the admission-event scatter: the slot's whole lane state
         # (table row, sampling params, budget, stop token) lands on
         # device ONCE here; the compiled step carries it from now on
@@ -1194,14 +1298,26 @@ class DecodeEngine:
         for slot, req in enumerate(self._slots):
             if req is None or req._prefill_pos is not None:
                 continue     # prefilling slots pre-allocated at admit
-            # this step scatters K/V at position seq_lens[slot]; that
-            # page must be owned or the write would land in scratch and
-            # silently corrupt the request's context
-            need = int(self._seq_lens[slot]) // self.block_size + 1
-            while req._slot is not None and len(req._blocks) < need:
+            # this step scatters K/V at the lane's position: its mirror
+            # plus the step in flight; that page must be owned or the
+            # write would land in scratch and silently corrupt the
+            # request's context. (A lane the device retires in flight
+            # for its stop token emits nothing; the page asked for it
+            # here is released with the slot.)
+            while req._slot is not None and self._runs_next(slot, req) \
+                    and len(req._blocks) * self.block_size <= \
+                    int(self._seq_lens[slot] + self._in_flight[slot]):
                 got = self._try_alloc(1)
                 if got is not None:
-                    self._append_block(slot, req, got[0])
+                    if req._slot is None:
+                        # retired by a delivery inside the allocation
+                        self.pool.free(got)
+                    else:
+                        self._append_block(slot, req, got[0])
+                    continue
+                if self._flight is not None:
+                    # what finished in flight gives its pages back
+                    self._drain()
                     continue
                 # pool and cache dry: evict the youngest running
                 # request — which may be this one (then its slot
@@ -1227,6 +1343,15 @@ class DecodeEngine:
             self._draft_lens[slot] = min(
                 lens, len(req._blocks) * self.block_size
                 - int(self._seq_lens[slot]) - 1)
+
+    def _runs_next(self, slot: int, req: GenRequest) -> bool:
+        """Whether the lane decodes in the next step to be dispatched:
+        armed, and not at the end of its budget with the step in flight
+        (the device retires it there; the host can count that far
+        without reading)."""
+        return bool(self._active[slot]) and \
+            len(req.out_tokens) + int(self._in_flight[slot]) \
+            < req.sampling.max_new_tokens
 
     def _append_block(self, slot: int, req: GenRequest,
                       block: int) -> None:
@@ -1268,7 +1393,8 @@ class DecodeEngine:
         """Zeroed device-resident step state, every lane cleared. Used
         at construction and to REPLACE a state dict whose buffers a
         failed (donated) step call consumed — the seed resumes at the
-        step count so the sampled-lane key stream never replays."""
+        count of steps dispatched so the sampled-lane key stream never
+        replays."""
         mb = self.max_batch
         state = {
             "tables": jnp.zeros((mb, self.blocks_per_seq), jnp.int32),
@@ -1280,7 +1406,7 @@ class DecodeEngine:
             "outc": jnp.zeros((mb,), jnp.int32),
             "maxn": jnp.zeros((mb,), jnp.int32),
             "stopt": jnp.full((mb,), -1, jnp.int32),
-            "seed": jnp.int32(getattr(self, "steps", 0)),
+            "seed": jnp.int32(self._dispatched),
         }
         if self._carry_sharding is not None:
             state = jax.device_put(state, self._carry_sharding)
@@ -1299,8 +1425,9 @@ class DecodeEngine:
             sp = req.sampling
             stop = -1 if sp.stop_token is None else int(sp.stop_token)
             ints = np.asarray(
-                [slot, int(self._seq_lens[slot]),
-                 int(self._last_tokens[slot]),
+                # (last token 0: a lane's last token is the device's
+                # own from the step that arms it — ``_ARM_SLOT``)
+                [slot, int(self._seq_lens[slot]), 0,
                  int(self._active[slot]), sp.top_k,
                  len(req.out_tokens), sp.max_new_tokens, stop],
                 np.int32)
@@ -1338,64 +1465,120 @@ class DecodeEngine:
         self._active[slot] = False
         self._seq_lens[slot] = 0
         self._tables[slot] = 0
-        self._last_tokens[slot] = 0
+        self._in_flight[slot] = 0
         self._draft_lens[slot] = 0     # stale drafts must not dispatch
         self._push_slot(slot, None)    # release event: clear the lane
 
-    def _run_step(self) -> int:
-        with self._phase("engine.dispatch"):
-            # oldest still-prefilling request gets this step's chunk budget
-            pre: Optional[GenRequest] = None
-            for r in self._slots:
-                if r is not None and r._prefill_pos is not None:
-                    if pre is None or r._admit_seq < pre._admit_seq:
-                        pre = r
-            if pre is None and not self._active.any():
-                return 0
-            proposed = int(self._draft_lens.sum()) if self.spec_k else 0
-            if proposed:
-                drafts_in, lens_in = self._draft_tokens, self._draft_lens
-            else:
-                # nothing proposed this step: dispatch the device-resident
-                # zero twins so an idle speculation lane uploads nothing
-                drafts_in, lens_in = self._dz_drafts, self._dz_lens
-            n_valid = 0
-            if pre is not None:
-                n_valid = min(self.prefill_chunk,
-                              len(pre._ctx) - pre._prefill_pos)
+    def _dispatch(self) -> Optional[_Flight]:
+        """Put the next step on the device and return its record; reads
+        nothing back. What the host decides here it decides from its own
+        numbers: which lanes run (``_runs_next``), the chunk's span
+        (``_prefill_pos`` advances HERE, so the step after carries the
+        next chunk), and whether the chunk is the prompt's last — then
+        the lane is armed from the step's ``c_first`` on the device."""
+        # oldest still-prefilling request gets this step's chunk budget
+        pre: Optional[GenRequest] = None
+        for r in self._slots:
+            if r is not None and r._prefill_pos is not None:
+                if pre is None or r._admit_seq < pre._admit_seq:
+                    pre = r
+        rows = [(slot, r) for slot, r in enumerate(self._slots)
+                if r is not None and self._runs_next(slot, r)]
+        if pre is None and not rows:
+            return None
+        proposed = int(self._draft_lens.sum()) if self.spec_k else 0
+        if proposed:
+            drafts_in, lens_in = self._draft_tokens, self._draft_lens
+        else:
+            # nothing proposed this step: dispatch the device-resident
+            # zero twins so an idle speculation lane uploads nothing
+            drafts_in, lens_in = self._dz_drafts, self._dz_lens
+        n_valid = 0
+        if pre is not None:
+            start = pre._prefill_pos
+            n_valid = min(self.prefill_chunk, len(pre._ctx) - start)
+        if self.metrics:
+            self._count_attn_pages(rows, pre, n_valid)
+        t0 = time.monotonic()
+        last_chunk = False
+        if pre is None:
+            # decode-only shape: no idle chunk rows to pay for — and
+            # with the state device-resident, NOTHING crosses
+            # host→device on this path (the steady-state contract the
+            # transfer-guard test pins)
+            self._kp, self._vp, self._dstate, packed = self._step_fn(
+                self.params, self._kp, self._vp, self._dstate,
+                drafts_in, lens_in, None)
+            c_first = None
+        else:
+            c = self.prefill_chunk
+            c_tokens = np.zeros((c,), np.int32)
+            c_tokens[:n_valid] = pre._ctx[start:start + n_valid]
+            c_ints = np.asarray([pre._slot, start, n_valid], np.int32)
+            if pre.first_chunk_at is None:
+                pre.first_chunk_at = time.monotonic()
+            self._kp, self._vp, self._dstate, packed, c_first = \
+                self._step_fn(self.params, self._kp, self._vp,
+                              self._dstate, drafts_in, lens_in,
+                              (c_tokens, c_ints))
+            pre._prefill_pos = start + n_valid
+            last_chunk = pre._prefill_pos >= len(pre._ctx)
+            if last_chunk:
+                self._arm(pre, c_first)
+                c_first.copy_to_host_async()
+        # the bundle starts for the host the moment the step ends
+        packed.copy_to_host_async()
+        self._dispatched += 1
+        for slot, _ in rows:
+            self._in_flight[slot] += 1
+        if self._flight is not None:
+            self.steps_run_ahead += 1
             if self.metrics:
-                self._count_attn_pages(pre, n_valid)
-            t0 = time.monotonic()
-            if pre is None:
-                # decode-only shape: no idle chunk rows to pay for — and
-                # with the state device-resident, NOTHING crosses
-                # host→device on this path (the steady-state contract the
-                # transfer-guard test pins)
-                self._kp, self._vp, self._dstate, packed = self._step_fn(
-                    self.params, self._kp, self._vp, self._dstate,
-                    drafts_in, lens_in, None)
-                c_first = None
-            else:
-                c = self.prefill_chunk
-                start = pre._prefill_pos
-                c_tokens = np.zeros((c,), np.int32)
-                c_tokens[:n_valid] = pre._ctx[start:start + n_valid]
-                c_ints = np.asarray([pre._slot, start, n_valid], np.int32)
-                if pre.first_chunk_at is None:
-                    pre.first_chunk_at = time.monotonic()
-                self._kp, self._vp, self._dstate, packed, c_first = \
-                    self._step_fn(self.params, self._kp, self._vp,
-                                  self._dstate, drafts_in, lens_in,
-                                  (c_tokens, c_ints))
+                self.metrics.steps_run_ahead.incr()
+        return _Flight(packed, c_first, rows, pre, n_valid, last_chunk,
+                       proposed, t0)
+
+    def _arm(self, req: GenRequest, c_first) -> None:
+        """The prompt's last chunk is on the device: the slot is a decode
+        lane from the next step on, at the context's tip, its last token
+        the chunk's sample — which stays on the device (``_ARM_SLOT``);
+        the host delivers it when it reads the step (``_finish_prefill``).
+        A request at the end of its budget with that token is not armed."""
+        slot = req._slot
+        req._prefill_pos = None
+        self._seq_lens[slot] = len(req._ctx)
+        if self.prefix_cache is not None:
+            # the fully-filled prompt blocks enter the prefix index HERE,
+            # not at the read: the device runs its steps in order, so
+            # whoever maps these pages from the next admission on reads
+            # them after this step has written them
+            full = len(req._ctx) // self.block_size
+            if full:
+                self.prefix_inserted_blocks += self.prefix_cache.insert(
+                    req._ctx[:full * self.block_size], req._blocks[:full])
+        count = len(req.out_tokens) + 1
+        if count >= req.sampling.max_new_tokens:
+            return
+        self._active[slot] = True
+        self._dstate = _ARM_SLOT(
+            self._dstate,
+            np.asarray([slot, len(req._ctx), count], np.int32), c_first)
+
+    def _deliver(self, flight: _Flight) -> int:
+        """The host's half of a step: read its bundle, hand the tokens
+        out. With the next step already dispatched the read returns as
+        soon as this step ends, and the device is not waiting on it."""
         with self._phase("engine.readback"):
             # the ONE device→host read of the step: [B, G+3] =
             # tokens | emit_count | finished | accept_len
-            packed = np.asarray(packed)
+            packed = np.asarray(flight.packed)
+        if self._flight is not None:
+            # the step behind this one starts on the device about now
+            self._flight.t0 = time.monotonic()
         with self._phase("engine.deliver"):
-            return self._deliver_step(packed, pre, n_valid, c_first,
-                                      proposed, t0)
+            return self._deliver_step(packed, flight)
 
-    def _count_attn_pages(self, pre: Optional[GenRequest],
+    def _count_attn_pages(self, rows, pre: Optional[GenRequest],
                           n_valid: int) -> None:
         """The live-page share of this step's attention, from the host's
         mirrors: pages its live rows attend to (a row at position ``p``
@@ -1403,50 +1586,50 @@ class DecodeEngine:
         table; then what only the family counts."""
         bs = self.block_size
         j = np.arange(self.spec_k + 1)
-        lanes = np.flatnonzero(self._active)
-        lens = self._seq_lens[lanes, None] + 1 + j
+        lanes = np.asarray([slot for slot, _ in rows], np.intp)
+        at = self._seq_lens[lanes] + self._in_flight[lanes]
+        lens = at[:, None] + 1 + j
         lens = lens[j <= self._draft_lens[lanes, None]]
-        rows = self.max_batch * j.size
+        n_rows = self.max_batch * j.size
         if pre is not None:
             lens = np.concatenate(
                 [lens, pre._prefill_pos + 1 + np.arange(n_valid)])
-            rows += self.prefill_chunk
+            n_rows += self.prefill_chunk
         self.metrics.attn_pages_read.incr(int(np.sum(-(-lens // bs))))
-        self.metrics.attn_pages_dense.incr(rows * self.blocks_per_seq)
+        self.metrics.attn_pages_dense.incr(n_rows * self.blocks_per_seq)
         self._family.count_step(self.metrics, lens,
-                                self._chains(lanes, pre, n_valid))
+                                self._chains(rows, at, pre, n_valid))
 
-    def _chains(self, lanes, pre: Optional[GenRequest], n_valid: int):
+    def _chains(self, rows, at, pre: Optional[GenRequest], n_valid: int):
         """(first page, pages held) per live request; lazy."""
         bs = self.block_size
-        for slot in lanes:
-            yield (self._slots[slot]._blocks[0],
-                   -(-(int(self._seq_lens[slot]) + 1) // bs))
+        for (_, req), pos in zip(rows, at):
+            yield req._blocks[0], -(-(int(pos) + 1) // bs)
         if pre is not None and n_valid:
             yield pre._blocks[0], -(-(pre._prefill_pos + n_valid) // bs)
 
-    def _deliver_step(self, packed, pre: Optional[GenRequest],
-                      n_valid: int, c_first, proposed: int,
-                      t0: float) -> int:
+    def _deliver_step(self, packed, flight: _Flight) -> int:
         """What the host does with a step's read-back bundle: advance
         the mirrors, deliver each lane's tokens, retire what finished,
-        complete the prefill whose last chunk rode along."""
+        complete the prefill whose last chunk rode along. A row is its
+        request's only while that request still holds the slot."""
         G = self.spec_k + 1
         self.steps += 1
-        self._chunk_fill = n_valid
+        self._chunk_fill = flight.n_valid
         for j, name in enumerate(self._family.counters if self.metrics
                                  else ()):
             getattr(self.metrics, name).incr(int(packed[0, G + 3 + j]))
         emitted = 0
-        self.occupancy_log.append(self.num_active)
+        self.occupancy_log.append(len(flight.rows))
         if len(self.occupancy_log) > 100_000:
             del self.occupancy_log[:50_000]
         accepted = 0
         spec_parent = None
         step_exemplar = None   # any sampled request names this step
-        for slot, req in enumerate(self._slots):
-            if req is None or not self._active[slot]:
-                continue
+        for slot, req in flight.rows:
+            if self._slots[slot] is not req:
+                continue       # released since the step was dispatched
+            self._in_flight[slot] -= 1
             if step_exemplar is None and req.trace_ctx is not None \
                     and req.trace_ctx.sampled:
                 step_exemplar = req.trace_ctx.trace_id
@@ -1468,11 +1651,11 @@ class DecodeEngine:
             # mirrors advance with the device state (the device already
             # committed these positions)
             self._seq_lens[slot] += n
-            self._last_tokens[slot] = int(toks[-1])
             emitted += self._deliver_burst(req, toks)
             if packed[slot, G + 1] or self._exhausted(req):
                 self._release_slot(req)
                 self._finish_request(req, FINISHED)
+        proposed = flight.proposed
         if self.spec_k and proposed:
             self.spec_proposed += proposed
             self.spec_accepted += accepted
@@ -1488,17 +1671,16 @@ class DecodeEngine:
             ssp.add_kv("proposed", str(proposed))
             ssp.add_kv("accepted", str(accepted))
             ssp.finish()
-        if pre is not None:
-            pre._prefill_pos += n_valid
-            if pre._prefill_pos >= len(pre._ctx):
-                # the chunk's last valid row sat at the final context
-                # position — its sample is the first output token
-                self._finish_prefill(pre, int(c_first))
-                emitted += 1
+        pre = flight.pre
+        if flight.last_chunk and pre._slot is not None:
+            # the chunk's last valid row sat at the final context
+            # position — its sample is the first output token
+            self._finish_prefill(pre, int(flight.c_first))
+            emitted += 1
         self.tokens_generated += emitted
         if self.metrics:
             self.metrics.tokens_out.incr(emitted)
-            step_s = time.monotonic() - t0
+            step_s = time.monotonic() - flight.t0
             self.metrics.decode_step.add(step_s)
             # exemplar: a slow decode_step bucket on /prom names a
             # trace riding this step, resolvable at the fleet doctor
@@ -1533,21 +1715,11 @@ class DecodeEngine:
              req.out_tokens[-1] == sp.stop_token)
 
     def _finish_prefill(self, req: GenRequest, tok: int) -> None:
-        """Prompt fully cached: flip the slot to a decode lane, publish
-        the fully-filled prompt blocks into the prefix index, deliver
-        the first token, and scatter the armed lane state to the
-        device (a prefill-completion event)."""
-        slot = req._slot
-        ctx_len = len(req._ctx)
-        req._prefill_pos = None
-        self._seq_lens[slot] = ctx_len
-        self._last_tokens[slot] = tok
-        self._active[slot] = True
-        if self.prefix_cache is not None:
-            full = ctx_len // self.block_size
-            if full:
-                self.prefix_inserted_blocks += self.prefix_cache.insert(
-                    req._ctx[:full * self.block_size], req._blocks[:full])
+        """The step that carried the prompt's last chunk has been read:
+        deliver the first token. The lane was armed, and its prompt's
+        blocks indexed, at dispatch (``_arm``); a first token that ends
+        the request (its budget, or the stop token — which the device
+        saw too and left the lane off) releases the slot here."""
         first = req.first_token_at is None
         req._deliver(tok)
         if req._proposer is not None:
@@ -1579,10 +1751,6 @@ class DecodeEngine:
         if self._exhausted(req):
             self._release_slot(req)
             self._finish_request(req, FINISHED)
-        if req._slot is not None:
-            # still running: arm the device lane (active, position at
-            # the context tip, budget counters) in one scatter
-            self._push_slot(slot, req)
 
     def _publish_metrics(self) -> None:
         if not self.metrics:
@@ -1651,6 +1819,12 @@ class DecodeEngine:
         # pages stay allocated (the process is going down anyway)
         locked = self._sched_lock.acquire(timeout=5.0)
         try:
+            if locked:
+                try:
+                    # what the device already made is delivered
+                    self._drain()
+                except Exception as e:  # noqa: BLE001 — going down
+                    log.warning("step in flight lost at stop: %s", e)
             for req in [r for r in self._slots if r]:
                 if not req.done.is_set():
                     if locked:
@@ -1683,6 +1857,7 @@ class DecodeEngine:
         if not self.kvstore.dfs_enabled:
             return 0
         with self._sched_lock:
+            self._drain()
             n = self.kvstore.persist_resident()
             watermark = self.kvstore.persists_enqueued
         if n and not self.kvstore.flush(timeout, up_to=watermark):
@@ -1725,6 +1900,7 @@ class DecodeEngine:
                 self.step()
         req.wait(timeout)
         with self._sched_lock:
+            self._drain()
             blocks = self.kvstore.persist_prefix(prompt,
                                                  parent_ctx=req.trace_ctx)
             # flush to THIS handoff's watermark, not the global queue
@@ -1742,6 +1918,12 @@ class DecodeEngine:
         return durable * self.block_size
 
     def _run_loop(self) -> None:
+        """The serving thread: ``_iterate`` for as long as there is
+        work, one step ahead of its own read-back (``_runs_ahead``) —
+        step n+1 is on the device before step n's tokens are read, so
+        the host's part of an iteration runs under the device's. An
+        engine that speculates keeps in step with its read-back: its
+        drafts for step n+1 come from the tokens of step n."""
         while not self._stop.is_set():
             with self._cond:
                 # _local_idle, not idle: a busy longctx plane must not
@@ -1753,40 +1935,47 @@ class DecodeEngine:
             if self._stop.is_set():
                 return
             try:
-                self.step()
+                with self._sched_lock:
+                    self._iterate(ahead=self._runs_ahead)
             except Exception as e:  # noqa: BLE001 — fail requests, not
                 # the thread: a poisoned request must not wedge the
-                # replica with clients blocked on .done forever. Slot
-                # state only moves under the scheduler lock (a racing
-                # stop() must not double-release the same pages), and
-                # the queue drains via popleft — a submit() racing this
-                # handler is left pending for the next loop iteration,
-                # never silently dropped
-                with self._sched_lock:
-                    # the failed step call consumed ALL the donated
-                    # device buffers (KV pools + step state) — rebuild
-                    # them BEFORE the release path scatters lane-clear
-                    # events into the state, or the recovery itself
-                    # raises on deleted buffers and wedges the replica
-                    self._dstate = self._fresh_dstate()
-                    self._kp, self._vp = self._fresh_kv_pools()
-                    for req in [r for r in self._slots if r]:
-                        self._release_slot(req)
-                        self._finish_request(req, FAILED, f"decode failed: {e}")
-                    # the HBM radix indexed pages that died with the
-                    # pools: purge it (no demotion — the bytes are
-                    # gone; host/DFS tier copies are digest-keyed and
-                    # survive) so no future admission maps a zeroed
-                    # page as a cached prefix
-                    if self.prefix_cache is not None:
-                        self.pool.free(self.prefix_cache.evict(
-                            len(self.prefix_cache), self.pool.refcount))
-                    while True:
-                        with self._cond:
-                            if not self._pending:
-                                break
-                            req = self._pending.popleft()
-                        self._finish_request(req, FAILED, f"decode failed: {e}")
+                # replica with clients blocked on .done forever
+                self._recover(e)
+
+    def _recover(self, e: Exception) -> None:
+        """A step failed — at its dispatch, or at the read of the step
+        in flight, where a device failure surfaces (``_iterate`` has
+        dropped what was in flight). Slot state only moves under the
+        scheduler lock (a racing stop() must not double-release the
+        same pages), and the queue drains via popleft — a submit()
+        racing this handler is left pending for the next loop
+        iteration, never silently dropped."""
+        with self._sched_lock:
+            # the failed step call consumed ALL the donated device
+            # buffers (KV pools + step state), of the step in flight
+            # and of the one behind it alike — rebuild them once,
+            # BEFORE the release path scatters lane-clear events into
+            # the state, or the recovery itself raises on deleted
+            # buffers and wedges the replica
+            self._dstate = self._fresh_dstate()
+            self._kp, self._vp = self._fresh_kv_pools()
+            for req in [r for r in self._slots if r]:
+                self._release_slot(req)
+                self._finish_request(req, FAILED, f"decode failed: {e}")
+            # the HBM radix indexed pages that died with the
+            # pools: purge it (no demotion — the bytes are
+            # gone; host/DFS tier copies are digest-keyed and
+            # survive) so no future admission maps a zeroed
+            # page as a cached prefix
+            if self.prefix_cache is not None:
+                self.pool.free(self.prefix_cache.evict(
+                    len(self.prefix_cache), self.pool.refcount))
+            while True:
+                with self._cond:
+                    if not self._pending:
+                        break
+                    req = self._pending.popleft()
+                self._finish_request(req, FAILED, f"decode failed: {e}")
 
     # ------------------------------------------------------------- offline
 

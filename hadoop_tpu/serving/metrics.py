@@ -21,6 +21,11 @@ class ServingMetrics:
     - ``kv_blocks_in_use``   allocated KV pages (and a 0..1 utilization)
     - ``time_to_first_token`` quantiles (s), submit → first token
     - ``decode_step``        per-step latency rate (num_ops = steps)
+    - ``steps_run_ahead``    steps dispatched while the step before them
+                             had not been read back yet (the serving
+                             thread keeps one step in flight; against
+                             the step count, the share of steps whose
+                             host work ran under the device's)
     - ``tokens_out``         generated tokens (monotonic; tokens/s is the
                              derivative any sink can take)
     - ``requests`` / ``preemptions`` lifetime counters
@@ -115,6 +120,9 @@ class ServingMetrics:
             "decode_step", "one continuous-batching decode step")
         self.decode_step_hist = reg.histogram(
             "decode_step_seconds", "one continuous-batching decode step")
+        self.steps_run_ahead = reg.counter(
+            "steps_run_ahead",
+            "steps dispatched before the step ahead of them was read")
         # the scheduler loop's own time, one family with the bounded
         # label set of engine.PHASES (inline literals: the label lint
         # proves the bound), and the TTFT timeline's three stages
