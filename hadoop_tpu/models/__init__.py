@@ -9,8 +9,11 @@ and the norm / head rules serving shares (presets: ``models.config``):
 
 ``models.deepseek`` is a fourth, serving only (``deepseek_v32``: latent
 attention, a learned sparse selection, a share of a wider router, a
-stack per layer kind). The serving step reaches every family's layers
-through ``serving/families``, not by name.
+stack per layer kind), ``models.lfm2`` a fifth, serving only too
+(``lfm2_moe``: a gated short convolution whose state lives beside the
+pages, QK-normed GQA, every expert of the router resident). The serving
+step reaches every family's layers through ``serving/families``, not by
+name.
 
 Parameters are stored layer-stacked (leading ``n_layers`` dim): ``pp``
 shards them over its mesh axis, one device runs them under ``lax.scan``.

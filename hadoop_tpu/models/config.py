@@ -3,7 +3,7 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -16,7 +16,8 @@ class ModelConfig:
     explicitly so tiny test/dryrun configs and real configs share one code
     path (static shapes only — required for XLA).
     """
-    family: str = "llama"   # gpt2 | llama | mixtral | deepseek_v32
+    # gpt2 | llama | mixtral | deepseek_v32 | lfm2_moe
+    family: str = "llama"
     vocab_size: int = 32000
     d_model: int = 4096
     n_layers: int = 32
@@ -64,10 +65,24 @@ class ModelConfig:
     rope_beta_fast: float = 32.0
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
+    # ---- family "lfm2_moe" (serving only): every layer is an operator
+    # (``layer_types[l]``: "conv", a gated short convolution whose
+    # recurrent state is its last ``conv_kernel - 1`` inputs, or
+    # "full_attention", GQA with a per-head RMSNorm of q and k) followed
+    # by a SwiGLU FFN (``d_ff`` wide in the first ``n_dense_layers``
+    # layers, after them a sigmoid top-k router with a choice-only bias
+    # over ``n_routed_experts`` experts ``d_ff_expert`` wide, every one
+    # resident, no shared expert). The chosen weights are normalised by
+    # their sum plus ``router_norm_eps``. Embedding and head are tied.
+    layer_types: Tuple[str, ...] = ()
+    conv_kernel: int = 0
+    router_norm_eps: float = 0.0
 
     def __post_init__(self):
         if self.family == "deepseek_v32":
             _validate_deepseek_v32(self)
+        elif self.family == "lfm2_moe":
+            _validate_lfm2_moe(self)
 
     @property
     def head_dim(self) -> int:
@@ -117,6 +132,62 @@ def _validate_deepseek_v32(c: ModelConfig) -> None:
          "RoPE, RMSNorm, SwiGLU and an untied head are the architecture")
     need(c.rope_factor == 1.0 or c.rope_original_max_seq > 0,
          "rope_original_max_seq must be set when rope_factor != 1")
+
+
+LFM2_OPERATORS = ("conv", "full_attention")
+
+
+def _validate_lfm2_moe(c: ModelConfig) -> None:
+    def need(ok: bool, what: str) -> None:
+        if not ok:
+            raise ValueError(f"family='lfm2_moe': {what}")
+    need(len(c.layer_types) == c.n_layers,
+         f"layer_types names {len(c.layer_types)} layers, n_layers is "
+         f"{c.n_layers}")
+    need(all(t in LFM2_OPERATORS for t in c.layer_types),
+         f"layer_types holds {sorted(set(c.layer_types))}; an operator is "
+         f"one of {LFM2_OPERATORS}")
+    need(0 <= c.n_dense_layers <= c.n_layers,
+         f"n_dense_layers={c.n_dense_layers} outside 0..n_layers")
+    need(c.conv_kernel >= 2, "conv_kernel (conv_L_cache) must be >= 2")
+    need(c.d_model % c.n_heads == 0 and c.n_heads % c.n_kv_heads == 0
+         and c.head_dim % 2 == 0,
+         f"d_model={c.d_model} must divide into n_heads={c.n_heads} even "
+         f"heads and n_kv_heads={c.n_kv_heads} must divide n_heads")
+    if c.n_layers > c.n_dense_layers:
+        need(c.d_ff_expert > 0 and c.n_experts > 0,
+             "d_ff_expert and n_experts must be set (> 0)")
+        need(c.n_routed_experts >= c.n_experts and 0 <= c.experts_from
+             and c.experts_from + c.n_experts <= c.n_routed_experts,
+             f"experts_from={c.experts_from} + n_experts={c.n_experts} "
+             f"exceeds n_routed_experts={c.n_routed_experts}")
+        need(c.n_group == 1 and c.topk_group == 1
+             and c.n_shared_experts == 0,
+             "one router group and no shared expert are the architecture")
+        need(2 <= c.top_k <= c.n_routed_experts,
+             f"top_k={c.top_k} outside 2..n_routed_experts")
+    need(c.use_rope and c.use_rmsnorm and c.use_swiglu
+         and c.tie_embeddings,
+         "RoPE, RMSNorm, SwiGLU and a tied head are the architecture")
+
+
+# families with a serving path only, and what a training entry point
+# would have to have for them
+SERVING_ONLY = {
+    "deepseek_v32": "latent attention, sparse selection or held-expert "
+                    "layer",
+    "lfm2_moe": "gated short convolution, per-head q/k norm or "
+                "resident-expert layer"}
+
+
+def refuse_training(cfg: ModelConfig, where: str) -> None:
+    """Training entry points call this first: these families have a
+    serving path only, and must never fall through to the llama layer."""
+    if cfg.family in SERVING_ONLY:
+        raise NotImplementedError(
+            f"family={cfg.family!r} is built for serving only "
+            f"(serving.engine.DecodeEngine); {where} has no "
+            f"{SERVING_ONLY[cfg.family]}")
 
 
 def _gpt2(**kw) -> ModelConfig:
@@ -177,6 +248,16 @@ PRESETS = {
         experts_from=0, n_group=4, topk_group=2,
         routed_scaling_factor=2.5, rope_factor=40.0,
         rope_original_max_seq=32, rope_mscale=1.0),
+    # a gated short convolution in 5 of 7 layers, QK-normed GQA in 2,
+    # 1 dense FFN then a sigmoid top-4 router over 16 resident experts
+    "tiny-lfm2": ModelConfig(
+        family="lfm2_moe", vocab_size=256, d_model=64, n_layers=7,
+        n_heads=4, n_kv_heads=2, d_ff=128, max_seq=256, norm_eps=1e-5,
+        rope_theta=10000.0, dtype="float32", tie_embeddings=True,
+        n_experts=16, n_routed_experts=16, top_k=4, d_ff_expert=32,
+        n_dense_layers=1, conv_kernel=3, router_norm_eps=1e-6,
+        layer_types=("conv", "full_attention", "conv", "conv",
+                     "full_attention", "conv", "conv")),
     "tiny-gpt2": _gpt2(vocab_size=256, d_model=64, n_layers=2, n_heads=4,
                        n_kv_heads=4, d_ff=256, max_seq=128, dtype="float32"),
 }

@@ -30,8 +30,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from hadoop_tpu.models.config import ModelConfig
-from hadoop_tpu.models.deepseek import refuse_training
+from hadoop_tpu.models.config import ModelConfig, refuse_training
 from hadoop_tpu.ops import (apply_rope, causal_attention, gelu, layer_norm,
                             rms_norm, rope_frequencies, swiglu)
 

@@ -38,18 +38,6 @@ from hadoop_tpu.ops import layer_norm, rms_norm, swiglu
 from hadoop_tpu.ops.rope import yarn_frequencies, yarn_mscale
 from hadoop_tpu.ops.sparse_mla import sparse_mla_attention
 
-FAMILY = "deepseek_v32"
-
-
-def refuse_training(cfg: ModelConfig, where: str) -> None:
-    """Training entry points call this first: the family has a serving
-    path only, and must never fall through to the llama layer."""
-    if cfg.family == FAMILY:
-        raise NotImplementedError(
-            f"family={FAMILY!r} is built for serving only "
-            f"(serving.engine.DecodeEngine); {where} has no latent "
-            "attention, sparse selection or held-expert layer")
-
 
 def latent_width(cfg: ModelConfig) -> int:
     """A token's row in the latent pool: ``kv_lora_rank`` latent entries,

@@ -15,7 +15,8 @@ The single-device path uses the identical dispatch math with a local
 expert stack, so parallel-vs-reference tests match bit-for-bit.
 
 A second router serves one chip's share of a wider expert-parallel
-deployment (``family="deepseek_v32"``): :func:`route_grouped` scores
+deployment (``family="deepseek_v32"``; ``family="lfm2_moe"`` is its case
+of one group, every expert held and no shared expert): :func:`route_grouped` scores
 ALL ``n_routed_experts`` with a sigmoid, chooses by score plus a
 correction bias inside the best ``topk_group`` of ``n_group`` groups,
 and weights the chosen by their renormalised scores times
@@ -140,21 +141,27 @@ def route_grouped(x2d: jnp.ndarray, router_w: jnp.ndarray,
     inside = jnp.where(keep[:, :, None], biased, -jnp.inf).reshape(-1, n)
     _, idx = jax.lax.top_k(inside, cfg.top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
-    w = w / jnp.sum(w, axis=-1, keepdims=True) * cfg.routed_scaling_factor
+    total = jnp.sum(w, axis=-1, keepdims=True)
+    if cfg.router_norm_eps:
+        total = total + cfg.router_norm_eps
+    w = w / total * cfg.routed_scaling_factor
     return idx, w
 
 
 @jax.named_scope("moe")
-def moe_share(x2d: jnp.ndarray, lp, cfg: ModelConfig, valid=None):
+def moe_share(x2d: jnp.ndarray, lp, cfg: ModelConfig, valid=None,
+              busiest: bool = False):
     """This replica's share of the expert layer for rows ``x2d [T, D]``:
     ``sum_i w_i E_i(x)`` over the chosen experts HELD here plus the
-    shared expert. Static shapes by running each held expert over all
-    ``T`` rows with a zero weight where it was not chosen — every
-    assignment is computed, none dropped. ``lp``: ``router [D, N]``,
-    ``router_bias [N]``, ``w_gate/w_up [E, D, F]``, ``w_down [E, F, D]``,
-    and the shared expert's ``ws_gate/ws_up [D, Fs]``, ``ws_down [Fs, D]``.
-    Returns (``y [T, D]``, ``stats`` int32 ``[2]``: assignments to held
-    experts and held experts hit, over the rows ``valid`` marks)."""
+    shared expert, where the layer has one. Static shapes by running
+    each held expert over all ``T`` rows with a zero weight where it was
+    not chosen — every assignment is computed, none dropped. ``lp``:
+    ``router [D, N]``, ``router_bias [N]``, ``w_gate/w_up [E, D, F]``,
+    ``w_down [E, F, D]``, and a shared expert's ``ws_gate/ws_up [D,
+    Fs]``, ``ws_down [Fs, D]``. Returns (``y [T, D]``, ``stats`` int32
+    ``[2]``: assignments to held experts and held experts hit, over the
+    rows ``valid`` marks; with ``busiest`` a third: the rows of the held
+    expert that most of them chose)."""
     e, lo = cfg.n_experts, cfg.experts_from
     idx, w = route_grouped(x2d, lp["router"], lp["router_bias"], cfg)
     local = idx - lo
@@ -167,10 +174,15 @@ def moe_share(x2d: jnp.ndarray, lp, cfg: ModelConfig, valid=None):
                     jnp.einsum("td,edf->etf", x2d, lp["w_up"]))
     ye = jnp.einsum("etf,efd->etd", hidden, lp["w_down"])
     y = jnp.einsum("te,etd->td", gate, ye.astype(jnp.float32))
-    shared = swiglu(x2d @ lp["ws_gate"], x2d @ lp["ws_up"]) @ lp["ws_down"]
-    y = (y + shared.astype(jnp.float32)).astype(x2d.dtype)
+    if "ws_gate" in lp:
+        shared = swiglu(x2d @ lp["ws_gate"],
+                        x2d @ lp["ws_up"]) @ lp["ws_down"]
+        y = y + shared.astype(jnp.float32)
+    y = y.astype(x2d.dtype)
     if valid is not None:
         hot = hot * valid[:, None, None]
     per_expert = jnp.sum(hot, axis=(0, 1))
-    stats = jnp.stack([jnp.sum(per_expert), jnp.sum(per_expert > 0)])
-    return y, stats.astype(jnp.int32)
+    stats = [jnp.sum(per_expert), jnp.sum(per_expert > 0)]
+    if busiest:
+        stats.append(jnp.max(per_expert))
+    return y, jnp.stack(stats).astype(jnp.int32)

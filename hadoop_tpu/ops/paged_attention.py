@@ -103,6 +103,69 @@ def _paged_ref(q, kc, vc, tables, lens, scale):
     return out.reshape(t, hq, dh)
 
 
+def paged_attention_packed(q: jnp.ndarray, kc: jnp.ndarray,
+                           vc: jnp.ndarray, tables: jnp.ndarray,
+                           lens: jnp.ndarray, scale: float) -> jnp.ndarray:
+    """``paged_attention`` over pools whose pages keep a token's KV heads
+    side by side in ONE row: kc, vc ``[blocks, bs, hkv * dh]``. For heads
+    narrower than the 128 lanes of a tile (``dh`` 64): a pool shaped
+    ``[..., hkv, dh]`` is then laid out with the blocks minor on the
+    device and converted whole on the way in and out of every step, and
+    a gathered chunk reshaped to heads is relaid every trip. Here a
+    query head is widened to the packed row with zeros outside its own
+    KV head's lanes, so both contractions run over whole rows as they
+    lie (``hkv`` times the FLOPs of the MXU, which decode leaves idle;
+    the bytes are the same). Portable ``jax.numpy`` / ``lax``, the walk
+    of ``_paged_ref``: to the call's longest live context, a chunk of
+    pages at a time. q ``[t, hq, dh]``; returns ``[t, hq, dh]``."""
+    out_dtype = q.dtype
+    t, hq, dh = q.shape
+    _, bs, width = kc.shape
+    hkv = width // dh
+    record_attention_impl("paged", "packed", q.shape, kc.shape)
+    # own[h, g]: query head h reads KV head g
+    own = (jnp.arange(hq)[:, None] // (hq // hkv)
+           == jnp.arange(hkv)[None, :]).astype(kc.dtype)
+    qp = (q.astype(kc.dtype)[:, :, None, :]
+          * own[None, :, :, None]).reshape(t, hq, width)
+    tables, ppc = _chunked_tables(tables, bs)
+    ctok = ppc * bs
+    n_chunks = (jnp.max(lens) + ctok - 1) // ctok
+    ktok = jnp.arange(ctok)
+
+    def chunk(carry):
+        i, m, l, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(tables, i * ppc, ppc, axis=1)
+        with jax.named_scope("kv_gather"):
+            k = kc[pages].reshape(t, ctok, width)
+            v = vc[pages].reshape(t, ctok, width)
+        s = jnp.einsum("tqe,tke->tqk", qp, k,
+                       preferred_element_type=jnp.float32) * scale
+        live = ((i * ctok + ktok)[None, :] < lens[:, None])[:, None, :]
+        s = jnp.where(live, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        # (the mask, not the exponent, keeps dead positions out: see
+        # _paged_ref)
+        p = jnp.where(live, jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + jnp.sum(p, axis=-1)
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "tqk,tke->tqe", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        return i + 1, m_new, l, acc
+
+    stat = jnp.zeros((t, hq), jnp.float32)
+    _, _, l, acc = jax.lax.while_loop(
+        lambda c: c[0] < n_chunks, chunk,
+        (jnp.int32(0), stat + _NEG_INF, stat,
+         jnp.zeros((t, hq, width), jnp.float32)))
+    out = acc / jnp.where(l > 0, l, 1.0)[..., None]
+    # a head's own lanes of the packed row
+    out = jnp.einsum("tqgd,qg->tqd", out.reshape(t, hq, hkv, dh),
+                     own.astype(jnp.float32))
+    return out.astype(out_dtype)
+
+
 # ============================================================ Pallas (TPU)
 
 def kernel_supported(q_shape, kc_shape, dtype) -> bool:

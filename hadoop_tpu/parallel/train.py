@@ -28,12 +28,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from hadoop_tpu.models.config import ModelConfig
+from hadoop_tpu.models.config import ModelConfig, refuse_training
 from hadoop_tpu.models.decoder import (embed_tokens, final_hidden,
                                        forward_hidden, head_matrix,
                                        run_layers)
 from hadoop_tpu.models.decoder import init_params as _init_params
-from hadoop_tpu.models.deepseek import refuse_training
 from hadoop_tpu.ops import rope_frequencies
 from hadoop_tpu.ops.cross_entropy import chunked_lm_cross_entropy
 from hadoop_tpu.parallel.mesh import AXES, MeshPlan, param_specs, \

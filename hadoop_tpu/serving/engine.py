@@ -17,9 +17,12 @@ Design (TPU-first, same rules as the trainer):
   compilation of each.
 
 - **Paged KV cache.** What a token caches is the family's (K and V per
-  KV head; or a latent and an index key): two pools ``[L, num_blocks,
-  block_size, *entry]`` under one block table per running request (a
-  list of pool indices). Each step scatters the new tokens' entries
+  KV head; a latent and an index key; K and V in some layers and a
+  page's recurrent-state tail in the others): its pools, each
+  ``[layers of its kind, num_blocks, *page]``, under ONE block table per
+  running request (a list of pool indices). A family may also carry
+  per-lane state beside the tables (what a recurrent layer keeps of a
+  sequence), set when a lane starts. Each step scatters the new tokens' entries
   into ``table[pos // bs], pos % bs`` and then attends each row to its
   own live pages through its table — requests share one pool with no
   per-request padding waste (the vLLM PagedAttention layout). Block 0
@@ -211,6 +214,7 @@ def _set_slot_impl(state, ints, table_row, temp):
     stop_token] so one small upload carries the whole event."""
     slot = ints[0]
     return {
+        **state,    # the seed, and a family's own lane state
         "tables": state["tables"].at[slot].set(table_row),
         "positions": state["positions"].at[slot].set(ints[1]),
         "last": state["last"].at[slot].set(ints[2]),
@@ -220,7 +224,6 @@ def _set_slot_impl(state, ints, table_row, temp):
         "outc": state["outc"].at[slot].set(ints[5]),
         "maxn": state["maxn"].at[slot].set(ints[6]),
         "stopt": state["stopt"].at[slot].set(ints[7]),
-        "seed": state["seed"],
     }
 
 
@@ -448,11 +451,15 @@ class DecodeEngine:
         self.expert_shards = self._family.expert_shards
         params, self._carry_sharding = self._family.place_experts(params)
         self.hbm_bytes = int(hbm_bytes or 0)
-        # a page holds block_size tokens' entries in both pools, a layer
-        entries = self._family.entry_shapes
-        self.block_nbytes = (cfg.n_layers * block_size
-                             * jnp.dtype(cfg.jax_dtype).itemsize
-                             * sum(int(np.prod(e)) for e in entries))
+        # a page is what every pool holds of it, in the layers the pool
+        # spans
+        pools = self._family.pools(block_size)
+        itemsize = jnp.dtype(cfg.jax_dtype).itemsize
+        self._pool_desc = [
+            {"layers": layers, "page": list(page),
+             "page_bytes": layers * int(np.prod(page)) * itemsize}
+            for layers, page in pools]
+        self.block_nbytes = sum(p["page_bytes"] for p in self._pool_desc)
         if self.hbm_bytes:
             # capacity = budget minus what the weights measurably
             # occupy; lanes sized so each can hold a full context
@@ -507,15 +514,15 @@ class DecodeEngine:
             params = shard_params(params, self._mesh, param_specs(cfg, plan))
         self.params = params
 
-        self._pool_shapes = [(cfg.n_layers, num_blocks, block_size) + e
-                             for e in entries]
+        self._pool_shapes = [(layers, num_blocks) + tuple(page)
+                             for layers, page in pools]
         self._kv_sharding = None
         if self._mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
             self._kv_sharding = NamedSharding(self._mesh,
                                               self._family.pool_spec)
             self._carry_sharding = NamedSharding(self._mesh, P())
-        self._kp, self._vp = self._fresh_kv_pools()
+        self._pools = self._fresh_kv_pools()
 
         # live HBM ledger (obs/hbm.py): this engine's resident bytes —
         # measured weights + the K/V pool it sized against them —
@@ -556,6 +563,9 @@ class DecodeEngine:
         # _SET_SLOT/_SET_TABLE/_ARM_SLOT. "seed" replaces the per-step host
         # PRNGKey upload — the key is derived in-graph.
         self._dispatched = 0        # steps ever dispatched: the seed
+        # what the family's layers keep of a lane beside its pages (None:
+        # nothing), carried in the step state under "lane"
+        self._lane_shapes = self._family.lane_state(max_batch)
         self._dstate = self._fresh_dstate()
         # per-step draft proposals (host-filled when speculating); the
         # device-resident zero twins are dispatched on steps with no
@@ -609,7 +619,29 @@ class DecodeEngine:
         # serving.parity=relaxed — the CP softmax reassociation is not
         # bitwise, so the bitwise default must keep it unreachable
         self._relaxed_longctx = None
-        self._step_fn = jax.jit(self._step_impl, donate_argnums=(1, 2, 3))
+        n_pools = len(self._pool_shapes)
+        self._step_fn = jax.jit(
+            self._step_impl, donate_argnums=tuple(range(1, n_pools + 2)))
+        self._start_lane_fn = jax.jit(self._start_lane_impl,
+                                      donate_argnums=(0,))
+
+    # the first two pools by the names they had while every family had
+    # two (tests and the benchmark's compiled-program check read them)
+    @property
+    def _kp(self):
+        return self._pools[0]
+
+    @_kp.setter
+    def _kp(self, pool) -> None:
+        self._pools[0] = pool
+
+    @property
+    def _vp(self):
+        return self._pools[1]
+
+    @_vp.setter
+    def _vp(self, pool) -> None:
+        self._pools[1] = pool
 
     def attach_longctx(self, plane) -> None:
         """Wire the long-context serving plane (``serving/longctx``):
@@ -645,8 +677,10 @@ class DecodeEngine:
 
     def _extract_block(self, blk: int):
         """One page's (K, V) payload to host numpy — the demotion /
-        persistence copy. Fixed-shape jit, compiled once per layout.
-        Drains first (scheduler lock held, as for every tier move)."""
+        persistence copy (the cold tiers move two pools: a family with
+        another set of them refuses the tiers). Fixed-shape jit, compiled
+        once per layout. Drains first (scheduler lock held, as for every
+        tier move)."""
         self._drain()
         k, v = _EXTRACT(self._kp, self._vp, jnp.int32(blk))
         return np.asarray(k), np.asarray(v)
@@ -655,15 +689,20 @@ class DecodeEngine:
         """Scatter a cold-tier payload into pool page ``blk`` (donated
         buffers — no pool-sized copy, no new compile). Drains first."""
         self._drain()
-        self._kp, self._vp = _INJECT(
+        self._pools = list(_INJECT(
             self._kp, self._vp, jnp.int32(blk),
             jnp.asarray(k, self._kp.dtype),
-            jnp.asarray(v, self._vp.dtype))
+            jnp.asarray(v, self._vp.dtype)))
 
     # ----------------------------------------------------- compiled body
 
-    def _step_impl(self, params, kp, vp, state, drafts, draft_lens,
-                   chunk):
+    def _start_lane_impl(self, state, pools, ints):
+        """A lane starts: its family state set from the pools. ``ints`` =
+        [slot, the last page it maps from the prefix cache (0: none)]."""
+        return {**state, "lane": self._family.start_lane(
+            state["lane"], pools, ints[0], ints[1])}
+
+    def _step_impl(self, params, *rest):
         """The ONE compiled function: every row is one token at one
         position. The first ``max_batch * (spec_k + 1)`` rows are the
         decode lanes — each lane a GROUP of ``spec_k + 1`` rows (its
@@ -675,16 +714,19 @@ class DecodeEngine:
         later positions within the same step; each row's length
         ``position + 1`` (0 for an inactive row) is its causal mask.
 
-        ``kp`` / ``vp`` are the donated pools ``[L, blocks, bs,
-        *entry]`` and come back as the same buffers. The layers are the
-        family's (``serving/families``: ``run_layers`` over the rows
-        built here; its scans carry both pools whole and address layer
-        ``l``'s pages at ``l * blocks + page``, so no slab is sliced
-        out, stacked back or copied); embedding, head, sampling,
+        ``rest`` is ``*pools, state, drafts, draft_lens, chunk``: the
+        family's donated pools ``[layers, blocks, *page]`` (two of them
+        for most families: ``kp, vp``) come back as the same buffers.
+        The layers are the family's (``serving/families``: ``run_layers``
+        over the rows built here; its scans carry the pools whole and
+        address a layer's pages at ``l * blocks + page``, so no slab is
+        sliced out, stacked back or copied); embedding, head, sampling,
         speculation's verify and the stop scan are here.
 
         All lane state arrives in (and leaves through) the donated
-        ``state`` dict: positions advance by the accepted length, the
+        ``state`` dict (the family's own under ``"lane"``, handed to
+        ``run_layers`` and taken back from it): positions advance by the
+        accepted length, the
         stop-condition scan retires lanes in-graph, and the PRNG key
         derives from the carried seed — the host uploads nothing per
         steady-state decode step and reads back one packed ``[B, spec_k
@@ -694,6 +736,7 @@ class DecodeEngine:
         Compiled at exactly TWO shapes for the replica's lifetime
         (decode-only, and with a prompt chunk riding along): any
         further trace is a retracing bug the counters expose."""
+        *pools, state, drafts, draft_lens, chunk = rest
         cfg = self.cfg
         B, S = self.max_batch, self.spec_k
         G = S + 1
@@ -768,11 +811,14 @@ class DecodeEngine:
         # causal by length: a live row attends to positions <= its own;
         # an inactive row attends to nothing and gets zeros
         lens = jnp.where(active, pos + 1, 0)
-        h, kp, vp, stats = self._family.run_layers(params, h, kp, vp, {
-            "pos": pos, "blk": blk, "off": off, "active": active,
-            "lens": lens, "tables": tables, "tables_s": tables_s,
-            "B": B, "G": G, "cos": cos, "sin": sin,
-            "chunk_slot": None if chunk is None else c_slot})
+        h, pools, lane, stats = self._family.run_layers(
+            params, h, tuple(pools), state["lane"], {
+                "pos": pos, "blk": blk, "off": off, "active": active,
+                "lens": lens, "tables": tables, "tables_s": tables_s,
+                "B": B, "G": G, "block": self.block_size,
+                "cos": cos, "sin": sin,
+                "chunk_slot": None if chunk is None else c_slot,
+                "chunk_n": None if chunk is None else c_n})
         with jax.named_scope("head_sample"):
             h = _norm(h, params["final_norm_w"], params.get("final_norm_b"),
                       cfg)
@@ -879,6 +925,7 @@ class DecodeEngine:
                 "maxn": maxn,
                 "stopt": stopt,
                 "seed": state["seed"] + 1,
+                "lane": lane,
             }
             packed = jnp.concatenate(
                 [out, n_emit[:, None], finished.astype(jnp.int32)[:, None],
@@ -892,8 +939,8 @@ class DecodeEngine:
                      jnp.zeros((B, n_stats), jnp.int32).at[0].set(stats)],
                     axis=1)
         if chunk is None:
-            return kp, vp, new_state, packed
-        return kp, vp, new_state, packed, c_first
+            return (*pools, new_state, packed)
+        return (*pools, new_state, packed, c_first)
 
     # -------------------------------------------------------- public face
 
@@ -1033,6 +1080,10 @@ class DecodeEngine:
             "evictions": self.prefix_evictions,
             "inserted_blocks": self.prefix_inserted_blocks,
             "prefill_chunk": self.prefill_chunk,
+            # what a page is made of: every pool's layers, page shape
+            # and bytes a page (they add up to ``block_nbytes``)
+            "pools": self._pool_desc,
+            "block_nbytes": self.block_nbytes,
             # per-tier traffic: HBM radix hits vs host-ring and DFS
             # recoveries, demotions/promotions/persists
             "tiers": self.kvstore.stats(),
@@ -1277,6 +1328,17 @@ class DecodeEngine:
         # (table row, sampling params, budget, stop token) lands on
         # device ONCE here; the compiled step carries it from now on
         self._push_slot(slot, req)
+        if self._lane_shapes is not None:
+            # the family's lane state starts where the request does:
+            # after the last page it maps (a prefix hit, or a resume
+            # after preemption), or from nothing
+            self._dstate = self._start_lane_fn(
+                self._dstate, tuple(self._pools), np.asarray(
+                    [slot, blocks[shared_blocks - 1] if shared_blocks
+                     else BlockPool.SCRATCH], np.int32))
+            if self.metrics:
+                (self.metrics.recurrent_state_restores if shared_blocks
+                 else self.metrics.recurrent_state_cold_starts).incr()
         if req.admitted_at is None:
             req.admitted_at = time.monotonic()
         sp = self.tracer.span("serving.admit", parent=req.trace_ctx)
@@ -1285,6 +1347,8 @@ class DecodeEngine:
                   f"{req.admitted_at - req.submitted_at:.6f}")
         sp.add_kv("prompt_tokens", str(len(ctx)))
         sp.add_kv("prefix_tokens_reused", str(req.prefix_tokens_reused))
+        if self._lane_shapes is not None:
+            sp.add_kv("state_restored_pages", str(shared_blocks))
         sp.finish()
 
     def _ensure_blocks(self) -> None:
@@ -1383,8 +1447,8 @@ class DecodeEngine:
     def _fresh_kv_pools(self):
         """Zeroed paged pools, sharded when the engine owns a mesh —
         construction and the failed-step recovery path share it."""
-        pools = tuple(jnp.zeros(shape, self.cfg.jax_dtype)
-                      for shape in self._pool_shapes)
+        pools = [jnp.zeros(shape, self.cfg.jax_dtype)
+                 for shape in self._pool_shapes]
         sharding = self._kv_sharding or self._carry_sharding
         return pools if sharding is None \
             else jax.device_put(pools, sharding)
@@ -1407,6 +1471,9 @@ class DecodeEngine:
             "maxn": jnp.zeros((mb,), jnp.int32),
             "stopt": jnp.full((mb,), -1, jnp.int32),
             "seed": jnp.int32(self._dispatched),
+            "lane": jax.tree_util.tree_map(
+                lambda shape: jnp.zeros(shape, self.cfg.jax_dtype),
+                self._lane_shapes, is_leaf=lambda x: isinstance(x, tuple)),
         }
         if self._carry_sharding is not None:
             state = jax.device_put(state, self._carry_sharding)
@@ -1506,8 +1573,8 @@ class DecodeEngine:
             # with the state device-resident, NOTHING crosses
             # host→device on this path (the steady-state contract the
             # transfer-guard test pins)
-            self._kp, self._vp, self._dstate, packed = self._step_fn(
-                self.params, self._kp, self._vp, self._dstate,
+            *self._pools, self._dstate, packed = self._step_fn(
+                self.params, *self._pools, self._dstate,
                 drafts_in, lens_in, None)
             c_first = None
         else:
@@ -1517,8 +1584,8 @@ class DecodeEngine:
             c_ints = np.asarray([pre._slot, start, n_valid], np.int32)
             if pre.first_chunk_at is None:
                 pre.first_chunk_at = time.monotonic()
-            self._kp, self._vp, self._dstate, packed, c_first = \
-                self._step_fn(self.params, self._kp, self._vp,
+            *self._pools, self._dstate, packed, c_first = \
+                self._step_fn(self.params, *self._pools,
                               self._dstate, drafts_in, lens_in,
                               (c_tokens, c_ints))
             pre._prefill_pos = start + n_valid
@@ -1958,7 +2025,7 @@ class DecodeEngine:
             # the state, or the recovery itself raises on deleted
             # buffers and wedges the replica
             self._dstate = self._fresh_dstate()
-            self._kp, self._vp = self._fresh_kv_pools()
+            self._pools = self._fresh_kv_pools()
             for req in [r for r in self._slots if r]:
                 self._release_slot(req)
                 self._finish_request(req, FAILED, f"decode failed: {e}")

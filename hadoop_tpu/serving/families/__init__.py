@@ -13,7 +13,7 @@ family's matmuls need ``weightplane`` and the exchange codec:
 
 from __future__ import annotations
 
-from typing import Any, Dict, Iterable, Mapping, Tuple
+from typing import Any, Dict, Iterable, Mapping, Sequence, Tuple
 
 from jax.sharding import PartitionSpec
 
@@ -31,14 +31,36 @@ class Family:
     for it, ``options`` are the engine's ``moe_*`` arguments, a family's
     to read or ignore. Members with a body here are optional."""
 
-    # a token's entry in the K-slot and the V-slot pool (trailing
-    # shape): both pools are ``[layers, blocks, block_size, *entry]``,
-    # in ``cfg.jax_dtype``, sharing one block table
-    entry_shapes: Tuple[Tuple[int, ...], Tuple[int, ...]]
+    # the pools the cache manager holds under its ONE block table, in
+    # the order ``run_layers`` takes them: ``(layers, page shape)`` each,
+    # given the page's tokens — a pool is ``[layers, blocks, *page
+    # shape]`` in ``cfg.jax_dtype``. A pool spans the layers that read
+    # it (all of them, or those of one kind), and a page holds there
+    # whatever the family keeps of its tokens: an entry a token, or
+    # something of a fixed size a page.
+    def pools(self, block_size: int) -> Sequence[Tuple[int, Tuple[int, ...]]]:
+        raise NotImplementedError
+
+    # per-lane state that the step carries beside tables and positions
+    # (donated; an array, or a pytree of arrays, given the lanes), for a
+    # family whose layers keep more of a sequence than its pages: None
+    # for none. A lane that starts — cold, from a prefix hit, or resumed
+    # after a preemption — has it set by ``start_lane``.
+    def lane_state(self, lanes: int):
+        return None
+
+    def start_lane(self, lane, pools, slot, page):
+        """``lane`` with lane ``slot``'s state that of a sequence whose
+        first row here follows the last token of pool page ``page`` (the
+        last page the lane maps from the prefix cache; 0, the scratch
+        page, when it starts from nothing). Traced; ``slot`` and
+        ``page`` are int32 scalars."""
+        raise NotImplementedError
+
     # the (kv_heads, head_dim) pair the chain salt is made of: prefixes
     # persisted to the DFS tier are keyed by it, so it never changes
     salt_layout: Tuple[int, int]
-    # both pools' spec on a tp mesh (replicated unless the family says)
+    # every pool's spec on a tp mesh (replicated unless the family says)
     pool_spec = PartitionSpec()
     # how many local chips the expert stacks split over (0: no experts)
     expert_shards = 0
@@ -65,17 +87,20 @@ class Family:
         """(cos, sin), traced inside the step; none by default."""
         return None, None
 
-    def run_layers(self, params, h, kp, vp, rows: Dict[str, Any]):
+    def run_layers(self, params, h, pools, lane, rows: Dict[str, Any]):
         """All layers over the step's rows ``h [T, D]``: every row is
         one token at one position; scatter each live row's entry into
-        the pools, then attend. ``rows``: ``pos``, ``blk`` (the page a
-        row writes; scratch for a dead row), ``off``, ``active``,
-        ``lens`` (``pos + 1``, 0 for a dead row), ``tables`` — all per
-        row; the lanes' ``tables_s [B, bps]``; ``B`` lanes of ``G`` rows
-        come first, then the chunk's rows, which share lane
-        ``chunk_slot``'s table (None: no chunk); ``cos``, ``sin``.
-        Returns ``(h, kp, vp, stats)``, the pools in their given shape;
-        ``stats`` is int32 ``[len(counters)]``, or ``()`` for none."""
+        the pools, then attend. ``pools``: the arrays of ``pools()``;
+        ``lane``: the array (or pytree) of ``lane_state()``. ``rows``:
+        ``pos``, ``blk`` (the page a row writes; scratch for a dead
+        row), ``off``, ``active``, ``lens`` (``pos + 1``, 0 for a dead
+        row), ``tables`` — all per row; the lanes' ``tables_s [B,
+        bps]``; ``B`` lanes of ``G`` rows come first, then the chunk's
+        rows, consecutive positions of lane ``chunk_slot`` of which the
+        first ``chunk_n`` are live (both None: no chunk); ``block`` the
+        page's tokens; ``cos``, ``sin``. Returns ``(h, pools, lane,
+        stats)``, pools and lane state in their given shapes; ``stats``
+        is int32 ``[len(counters)]``, or ``()`` for none."""
         raise NotImplementedError
 
     def count_step(self, metrics, lens, chains: Iterable) -> None:
@@ -91,10 +116,12 @@ class Family:
 
 from hadoop_tpu.serving.families.gqa import PagedKVFamily  # noqa: E402
 from hadoop_tpu.serving.families.latent import LatentFamily  # noqa: E402
+from hadoop_tpu.serving.families.lfm2 import ConvStateFamily  # noqa: E402
 
 FAMILIES: Dict[str, type] = {
     "gpt2": PagedKVFamily, "llama": PagedKVFamily,
-    "mixtral": PagedKVFamily, "deepseek_v32": LatentFamily}
+    "mixtral": PagedKVFamily, "deepseek_v32": LatentFamily,
+    "lfm2_moe": ConvStateFamily}
 
 
 def family_for(cfg: ModelConfig, asked: Mapping[str, Any],

@@ -63,7 +63,6 @@ class PagedKVFamily(Family):
     def __init__(self, cfg, asked, *, moe_capacity_factor: float = 0.0,
                  moe_shards: int = 0, moe_a2a_codec: str = "int8"):
         self.cfg = cfg
-        self.entry_shapes = ((cfg.n_kv_heads, cfg.head_dim),) * 2
         self.salt_layout = (cfg.n_kv_heads, cfg.head_dim)
         if moe_a2a_codec not in ("int8", "none"):
             raise ValueError(f"serving.moe.a2a.codec={moe_a2a_codec!r} "
@@ -83,6 +82,11 @@ class PagedKVFamily(Family):
             raise NotImplementedError(
                 "tp sharding of int8 resident weights is not wired yet "
                 "(serving.parity=relaxed serves single-chip replicas)")
+
+    def pools(self, block_size):
+        # a token's K and V per KV head, in every layer
+        page = (block_size, self.cfg.n_kv_heads, self.cfg.head_dim)
+        return [(self.cfg.n_layers, page)] * 2
 
     def place_experts(self, params):
         if self.expert_shards > 1:
@@ -145,8 +149,9 @@ class PagedKVFamily(Family):
                          ye.astype(jnp.float32))
         return y2d.astype(x.dtype)
 
-    def run_layers(self, params, h, kp, vp, rows):
+    def run_layers(self, params, h, pools, lane, rows):
         cfg = self.cfg
+        kp, vp = pools
         t = h.shape[0]
         hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         pos, cos, sin = rows["pos"], rows["cos"], rows["sin"]
@@ -195,4 +200,4 @@ class PagedKVFamily(Family):
                 layer, (h, kp, vp),
                 (params["layers"],
                  jnp.arange(cfg.n_layers, dtype=jnp.int32) * n_blocks))
-        return h, kp.reshape(pool_shape), vp.reshape(pool_shape), ()
+        return h, (kp.reshape(pool_shape), vp.reshape(pool_shape)), lane, ()

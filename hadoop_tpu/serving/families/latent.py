@@ -24,11 +24,16 @@ class LatentFamily(Family):
     expert_shards = 1       # one chip's share of a wider router
 
     def __init__(self, cfg, asked, **options):
-        width = deepseek.latent_width(cfg)
-        self.entry_shapes = ((width,), (cfg.index_head_dim,))
         # no cold tier: the page is only a layout to salt the chain with
-        self.salt_layout = (1, width + cfg.index_head_dim)
+        self.salt_layout = (1, deepseek.latent_width(cfg)
+                            + cfg.index_head_dim)
         super().__init__(cfg, asked)
+
+    def pools(self, block_size):
+        # a token's latent, and its index key, in every layer
+        cfg = self.cfg
+        return [(cfg.n_layers, (block_size, deepseek.latent_width(cfg))),
+                (cfg.n_layers, (block_size, cfg.index_head_dim))]
 
     def refuse(self, asked) -> None:
         for key, on in asked.items():
@@ -40,7 +45,7 @@ class LatentFamily(Family):
     def rope_tables(self):
         return deepseek.rope_tables(self.cfg)
 
-    def run_layers(self, params, h, kp, vp, rows):
+    def run_layers(self, params, h, pools, lane, rows):
         # table-sharing groups: a lane's rows, then the chunk's rows
         b, g = rows["B"], rows["G"]
         tables_s, lens = rows["tables_s"], rows["lens"]
@@ -48,8 +53,9 @@ class LatentFamily(Family):
         if rows["chunk_slot"] is not None:
             groups.append((b * g, tables_s[rows["chunk_slot"]][None, :],
                            lens[b * g:][None, :]))
-        return deepseek.run_layers(params, h, kp, vp, self.cfg,
-                                   {**rows, "groups": groups})
+        h, kp, vp, stats = deepseek.run_layers(
+            params, h, *pools, self.cfg, {**rows, "groups": groups})
+        return h, (kp, vp), lane, stats
 
     def count_step(self, metrics, lens, chains) -> None:
         # the sparse selection: entries the live rows could attend to

@@ -67,6 +67,16 @@ class ServingMetrics:
                              held experts with at least one (summed over
                              layers and steps; the last two counted on
                              the device, read with the step's bundle)
+    - ``moe_expert_rows_max``  rows of the busiest held expert, summed
+                             over layers and steps (device): times the
+                             experts held over ``moe_assignments_local``
+                             it is the load imbalance, 1.0 for an even
+                             router
+    - ``recurrent_state_restores`` / ``recurrent_state_cold_starts``
+                             (a family with per-lane state) lanes started
+                             from the state tail of a cached page — a
+                             prefix hit, a resume after preemption —
+                             against lanes started from nothing
     - ``qos_admitted`` / ``qos_shed``  door QoS gate outcomes (sheds
                              are 429 + Retry-After responses)
     - ``qos_tenants``        tenants tracked by the decay scheduler
@@ -238,6 +248,15 @@ class ServingMetrics:
         self.moe_local_experts_hit = reg.counter(
             "moe_local_experts_hit",
             "held experts with an assignment, summed over layers and steps")
+        self.moe_expert_rows_max = reg.counter(
+            "moe_expert_rows_max",
+            "rows of the busiest held expert, summed over layers and steps")
+        self.recurrent_state_restores = reg.counter(
+            "recurrent_state_restores",
+            "lanes started from the state tail of a cached page")
+        self.recurrent_state_cold_starts = reg.counter(
+            "recurrent_state_cold_starts",
+            "lanes with per-lane state started from nothing")
         # door QoS: admissions vs sheds (429) and tracked tenants — the
         # autoscaler scrapes qos_shed off /prom as a scale-out signal
         # (a shedding fleet is past its SLO by definition)

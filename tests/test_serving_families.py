@@ -4,8 +4,9 @@
 - the engine's source names no family: what a token's cache entry is and
   which layers read it is the family's, asked for through ``Family``'s
   members alone;
-- a family is additive: a toy one defined HERE (an entry of another
-  width in each pool, layers that change nothing, one stats column),
+- a family is additive: a toy one defined HERE (three pools, each
+  spanning its own number of layers with a page of its own shape, a
+  per-lane state, layers that change nothing, one stats column),
   registered by patching the dict, serves requests through the unedited
   engine and its column reaches the counter it names;
 - what the engine derives from a family is pinned to the values the
@@ -53,11 +54,13 @@ def test_every_preset_has_a_family_and_its_counters_exist():
 # ------------------------------------------------------------ a toy family
 
 class ToyFamily(families.Family):
-    """Entries 3 and 5 wide; a "layer" that writes each live row's
-    position into its entry and leaves ``h`` alone; one stats column:
-    the live rows."""
+    """Entries 3 and 5 wide in every layer, and a third pool of ONE layer
+    whose page holds a single number (the last length written there); a
+    per-lane state (the rows a lane has run since it started, from the
+    page it started after); a "layer" that writes each live row's
+    position into its entry and leaves ``h`` alone; one stats column: the
+    live rows."""
     counters = ("attn_pages_distinct",)
-    entry_shapes = ((3,), (5,))
     salt_layout = (1, 8)
     refused = []
 
@@ -66,13 +69,33 @@ class ToyFamily(families.Family):
         if asked.get("serving.speculate.k"):
             raise NotImplementedError("toy: serving.speculate.k")
 
-    def run_layers(self, params, h, kp, vp, rows):
+    def pools(self, block_size):
+        n = self.cfg.n_layers
+        return [(n, (block_size, 3)), (n, (block_size, 5)), (1, (1,))]
+
+    def lane_state(self, lanes):
+        return (lanes, 2)
+
+    def start_lane(self, lane, pools, slot, page):
+        return lane.at[slot].set(jnp.stack(
+            [page.astype(lane.dtype), jnp.zeros((), lane.dtype)]))
+
+    def run_layers(self, params, h, pools, lane, rows):
+        kp, vp, last = pools
         mark = rows["lens"].astype(kp.dtype)[:, None]
         kp = kp.at[0, rows["blk"], rows["off"]].set(
             jnp.broadcast_to(mark, (h.shape[0], 3)))
         vp = vp.at[0, rows["blk"], rows["off"]].set(
             jnp.broadcast_to(-mark, (h.shape[0], 5)))
-        return h, kp, vp, jnp.sum(rows["active"], dtype=jnp.int32)[None]
+        last = last.at[0, rows["blk"], 0].max(mark[:, 0])
+        b = rows["B"]
+        ran = rows["active"][:b].astype(lane.dtype)
+        if rows["chunk_slot"] is not None:
+            ran = ran.at[rows["chunk_slot"]].add(
+                rows["chunk_n"].astype(lane.dtype))
+        lane = lane.at[:, 1].add(ran)
+        return h, (kp, vp, last), lane, \
+            jnp.sum(rows["active"], dtype=jnp.int32)[None]
 
     def describe_experts(self, rows):
         return {"toy_rows": rows}
@@ -100,7 +123,10 @@ def test_a_toy_family_serves_through_the_unedited_engine(toy):
                        max_context=32, prefill_chunk=8, metrics=metrics)
     assert eng._kp.shape == (cfg.n_layers, 9, 4, 3)
     assert eng._vp.shape == (cfg.n_layers, 9, 4, 5)
-    assert eng.block_nbytes == cfg.n_layers * 4 * 4 * (3 + 5)
+    assert [p.shape for p in eng._pools][2:] == [(1, 9, 1)]
+    assert eng.block_nbytes == cfg.n_layers * 4 * 4 * (3 + 5) + 4
+    assert eng.block_nbytes * 9 == sum(p.nbytes for p in eng._pools)
+    assert eng._dstate["lane"].shape == (2, 2)
     assert eng.expert_shards == 0
     assert eng.weight_plane()["toy_rows"] == 2
     prompts = [[7, 3, 11, 5, 2], [9, 1]]
@@ -128,6 +154,23 @@ def test_a_toy_family_serves_through_the_unedited_engine(toy):
         list(range(1, 5 + 4)) + list(range(1, 2 + 4)))
     assert np.array_equal(vp[0, 1:, :, 4], -kp[0, 1:, :, 2])
     assert not kp[1:].any()
+    # the third pool rode the step too: a page's number is the largest
+    # length written there
+    last = np.asarray(eng._pools[2])[0, 1:, 0]
+    assert last.max() == 5 + 3 and np.array_equal(
+        last, kp[0, 1:, :, 0].max(axis=1))
+    # and the lane state: both lanes started from nothing (page 0) and
+    # ran their prompt's rows and their decode rows
+    assert np.asarray(eng._dstate["lane"]).tolist() == [[0, 5 + 3],
+                                                       [0, 2 + 3]]
+    # a second request over the first one's cached page starts its lane
+    # AFTER that page, and says so
+    again = eng.submit(prompts[0][:4] + [1, 2],
+                       SamplingParams(max_new_tokens=1))
+    eng.step()
+    assert again.prefix_tokens_reused == 4
+    page = eng.prefix_cache.match_nodes(prompts[0][:4])[0].block
+    assert np.asarray(eng._dstate["lane"])[0].tolist() == [page, 2]
     eng.stop()
 
 
@@ -162,6 +205,7 @@ PINS = {
                    "a2045a68068ed982674bd8b7b33b6b2cd267f1a2ca9e359ee30acb0e52705e91",
                    (2, 6), {"experts_from", "experts_routed"}),
 }
+# (``tiny-lfm2`` has three pools and a lane state: tests/test_lfm2.py)
 PLANE_KEYS = {"dtype", "expert_bytes", "expert_shards", "experts",
               "hbm_bytes", "kv_capacity_tokens", "lanes", "lanes_x_context",
               "max_context", "parity", "quantize_seconds",
@@ -180,6 +224,7 @@ def test_what_the_engine_derives_is_what_it_held_before(name):
     assert (eng._kp.shape, eng._vp.shape) == (kp_shape, vp_shape)
     assert eng.block_nbytes == nbytes
     assert eng.block_nbytes * 9 == eng._kp.nbytes + eng._vp.nbytes
+    assert len(eng._pools) == 2 and eng._dstate["lane"] is None
     assert eng.kvstore.chain_salt.hex() == salt
     out = jax.eval_shape(eng._step_impl, eng.params, eng._kp, eng._vp,
                          eng._dstate, eng._dz_drafts, eng._dz_lens, None)
