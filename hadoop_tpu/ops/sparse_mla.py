@@ -15,7 +15,8 @@ attend to its whole context:
    positions, ties broken by the lower position; a row with at most ``k``
    live positions keeps them all. No sort: the k-th largest value is found
    by a 32-step search over the scores' bit patterns (each step one
-   compare-and-count pass), then the kept positions are compacted into
+   compare-and-count pass; ``ops/topk.py``, which the sampler shares),
+   then the kept positions are compacted into
    ``[k]`` indices block by block with compares and one-hot products
    alone — no scatter, no gather, no data-dependent shape. ``approx_max_k`` or a sampled selection would be a
    different result, not a faster one.
@@ -38,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from hadoop_tpu.ops.attention import _NEG_INF, record_attention_impl
+from hadoop_tpu.ops.topk import kth_largest, sortable
 
 # positions per block of the compaction (and of the blocked prefix sums)
 BLOCK = 256
@@ -52,15 +54,6 @@ CHUNK_TOKENS_SHARED = 1024
 
 
 # ======================================================== exact selection
-
-def _sortable(x):
-    """float32 -> uint32 with the same order (``-0.0`` counted as
-    ``0.0``, as a float compare counts it). Every finite value and both
-    infinities map above 0, which is kept for dead positions."""
-    x = jnp.where(x == 0, 0.0, x).astype(jnp.float32)
-    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
-    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(0x80000000))
-
 
 def _blocked_counts(mask):
     """mask ``[n, S]`` (S a multiple of BLOCK) -> (``incl [n, nb, BLOCK]``:
@@ -101,17 +94,9 @@ def _select(scores, lens, k: int):
         s += pad
     nb = s // BLOCK
     live = jnp.arange(s)[None, :] < lens[:, None]
-    key = jnp.where(live, _sortable(scores), jnp.uint32(0))
+    key = jnp.where(live, sortable(scores), jnp.uint32(0))
     want = jnp.minimum(lens, k).astype(jnp.int32)
-
-    # the want-th largest key: the largest t with count(key >= t) >= want,
-    # built bit by bit from the top
-    def bit(i, t):
-        cand = t | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
-        c = jnp.sum(key >= cand[:, None], axis=-1, dtype=jnp.int32)
-        return jnp.where(c >= want, cand, t)
-
-    t = jax.lax.fori_loop(0, 32, bit, jnp.zeros((n,), jnp.uint32))
+    t = kth_largest(key, want)
     above = key > t[:, None]
     tied = (key == t[:, None]) & live
     need = want - jnp.sum(above, axis=-1, dtype=jnp.int32)

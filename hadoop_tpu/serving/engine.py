@@ -71,7 +71,8 @@ Design (TPU-first, same rules as the trainer):
   preemption and release — events, not steps. The stop-condition scan
   (max_new budget, stop_token) runs INSIDE the compiled step, and the
   host reads back one packed ``[B, k+4]`` bundle per step (sampled
-  tokens, emit counts, finished mask, verifier accept lengths). In
+  tokens, emit counts, finished mask, verifier accept lengths; then a
+  column for each count the step makes on the device). In
   steady-state decode the hot loop transfers nothing host→device
   (tests pin this with a ``jax.transfer_guard``).
 
@@ -154,6 +155,7 @@ import numpy as np
 from hadoop_tpu.models.config import ModelConfig
 from hadoop_tpu.models.decoder import _norm, head_matrix
 from hadoop_tpu.obs.hbm import hbm_ledger
+from hadoop_tpu.ops.topk import kth_largest, sortable
 # BlockPool/PrefixCache live in the kvstore package now (the tiered
 # fleet-wide cache); re-exported here so `from serving.engine import
 # BlockPool` keeps working for every existing consumer
@@ -339,7 +341,7 @@ class _Flight:
     the host needs to deliver it later: a row of the bundle belongs to
     the request that held the slot WHEN THE STEP WAS DISPATCHED (the
     slot may have been released, and placed again, since)."""
-    packed: Any                     # the [B, G+3] bundle, on the device
+    packed: Any                     # the [B, G+3+] bundle, on the device
     c_first: Any                    # the chunk's sample (fused shape)
     rows: List[tuple]               # (slot, request) of the lanes it ran
     pre: Optional[GenRequest]       # whose prompt chunk rode along
@@ -354,17 +356,31 @@ class _Flight:
 # apply EXACTLY the trained model's norm/head rules or served logits
 # silently diverge from training)
 
+# what every step counts of itself on the device: ServingMetrics counters
+# fed by the first columns of the packed read-back past the verdict, in
+# order, before the family's own
+_STEP_COUNTERS = ("steps_argmax_only", "steps_topk")
+
+
 def _mask_and_scale(logits, temps, topks):
     """The exact top-k mask + temperature transform ``_sample`` draws
     from, rank-polymorphic over leading axes — the speculation
     verifier shares it so the acceptance distribution can never drift
-    from the sampler's."""
-    v = logits.shape[-1]
-    srt = jnp.sort(logits, axis=-1)                       # ascending
-    kidx = jnp.clip(v - topks, 0, v - 1)
-    kth = jnp.take_along_axis(srt, kidx[..., None], axis=-1)[..., 0]
-    masked = jnp.where((topks > 0)[..., None] & (logits < kth[..., None]),
-                       _NEG_INF, logits)
+    from the sampler's.
+
+    A row with ``topks > 0`` keeps its ``topks`` largest logits and
+    whatever ties the last of them; the threshold is found without a
+    sort (``ops/topk.kth_largest``: the same value ``sort(logits)[V -
+    k]`` holds, so the same entries stay), and only when some row of the
+    call asks for one: the other rows of such a call pay one compare."""
+    bits = sortable(logits)
+    want = jnp.clip(topks, 1, logits.shape[-1]).astype(jnp.int32)
+    # 0 lies under every float's bits: a call with no top-k masks nothing
+    kth = jax.lax.cond(jnp.any(topks > 0),
+                       lambda: kth_largest(bits, want),
+                       lambda: jnp.zeros(topks.shape, jnp.uint32))
+    below = (topks > 0)[..., None] & (bits < kth[..., None])
+    masked = jnp.where(below, _NEG_INF, logits)
     return masked / jnp.maximum(temps, 1e-6)[..., None]
 
 
@@ -372,11 +388,26 @@ def _sample(logits, temps, topks, key):
     """logits [T, V] float32; per-row temperature/top-k; greedy when
     temperature <= 0 (the fused decode+sampling step of arxiv
     2502.17728 — sampling stays inside the compiled program so no
-    [T, V] logits tensor crosses to the host)."""
+    [T, V] logits tensor crosses to the host).
+
+    The work follows what the rows ask for: when no row has a
+    temperature the call is an arg-max and nothing else — no mask, no
+    scale, no random bits. The step hands a row that nobody reads (a
+    free lane, whose sampling parameters stay in the carried state until
+    the slot is placed again) a temperature of 0, and a top-k only to a
+    row that samples. A row's draw comes from the categorical over ALL
+    the call's rows, so it depends on the shape of the call: the fused
+    step (``B*G + 1`` rows) and the decode-only step (``B*G``) give a
+    sampled lane the same distribution under two streams of bits."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = _mask_and_scale(logits, temps, topks)
-    sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
-    return jnp.where(temps <= 0, greedy, sampled)
+
+    def draw():
+        scaled = _mask_and_scale(logits, temps, topks)
+        sampled = jax.random.categorical(key, scaled, axis=-1).astype(
+            jnp.int32)
+        return jnp.where(temps <= 0, greedy, sampled)
+
+    return jax.lax.cond(jnp.any(temps > 0), draw, lambda: greedy)
 
 
 class DecodeEngine:
@@ -584,6 +615,8 @@ class DecodeEngine:
         self._in_flight = np.zeros((max_batch,), np.int32)
         self._runs_ahead = self.spec_k == 0
         self.steps_run_ahead = 0    # dispatched with the last unread
+        self.steps_argmax_only = 0  # no live row sampled (device's count)
+        self.steps_topk = 0         # a threshold search ran (device's)
 
         # the admission seam: a deque by default, or any deque-shaped
         # queue (append/appendleft/popleft/len/[0]) — the door's QoS
@@ -731,7 +764,19 @@ class DecodeEngine:
         derives from the carried seed — the host uploads nothing per
         steady-state decode step and reads back one packed ``[B, spec_k
         + 4]`` bundle (tokens | emit_count | finished | accept_len), a
-        column wider for each counter the family's layers feed.
+        column wider for each of ``_STEP_COUNTERS`` and for each counter
+        the family's layers feed.
+
+        The head and the sampler do what the step's live rows ask for
+        (``head_sample``): logits are made for the decode rows and the
+        ONE chunk row that is read, and ``_sample`` is an arg-max unless
+        a live lane — or the lane whose chunk rides along — has a
+        temperature. Greedy tokens do not depend on any of it. A sampled
+        lane's draw comes from the categorical over the call's rows, so
+        the first token of a sampled prompt (drawn in a fused step, among
+        ``B*G + 1`` rows) and a decode token drawn beside a chunk come
+        from another stream of bits than in a decode-only step: the same
+        distribution, as between the two shapes before.
 
         Compiled at exactly TWO shapes for the replica's lifetime
         (decode-only, and with a prompt chunk riding along): any
@@ -768,8 +813,6 @@ class DecodeEngine:
         active = row_act.reshape(B * G)
         tables = jnp.broadcast_to(tables_s[:, None, :],
                                   (B, G, bps)).reshape(B * G, bps)
-        temps = jnp.broadcast_to(temps_s[:, None], (B, G)).reshape(B * G)
-        topks = jnp.broadcast_to(topks_s[:, None], (B, G)).reshape(B * G)
         if chunk is not None:
             # chunk rows: tokens uploaded, everything else derived from
             # the prefilling slot's carried state (table row, sampling
@@ -784,10 +827,6 @@ class DecodeEngine:
             tables = jnp.concatenate(
                 [tables, jnp.broadcast_to(tables_s[c_slot][None, :],
                                           (C, bps))], axis=0)
-            temps = jnp.concatenate(
-                [temps, jnp.broadcast_to(temps_s[c_slot], (C,))])
-            topks = jnp.concatenate(
-                [topks, jnp.broadcast_to(topks_s[c_slot], (C,))])
         # inactive draft rows can sit past the end of the table/rope
         # range; clip (identity for every live row) and let the active
         # mask discard their output
@@ -820,6 +859,25 @@ class DecodeEngine:
                 "chunk_slot": None if chunk is None else c_slot,
                 "chunk_n": None if chunk is None else c_n})
         with jax.named_scope("head_sample"):
+            # ---- the rows that are read: every decode row, and of the
+            # chunk's rows the ONE at the chunk's tip (its sample is the
+            # prompt's first token when the chunk is the prompt's last)
+            live = active[:B * G]
+            temps = jnp.broadcast_to(temps_s[:, None], (B, G)).reshape(B * G)
+            topks = jnp.broadcast_to(topks_s[:, None], (B, G)).reshape(B * G)
+            if chunk is not None:
+                h = jnp.concatenate(
+                    [h[:B * G], jax.lax.dynamic_slice_in_dim(
+                        h, B * G + c_n - 1, 1)])
+                live = jnp.concatenate([live, (c_n > 0)[None]])
+                temps = jnp.concatenate([temps, temps_s[c_slot][None]])
+                topks = jnp.concatenate([topks, topks_s[c_slot][None]])
+            # ... and what they ask of the sampler: a free lane keeps its
+            # parameters in the carried state until the slot is placed
+            # again, and asks for nothing; a greedy row has no use for a
+            # top-k
+            temps = jnp.where(live, temps, 0.0)
+            topks = jnp.where(temps > 0, topks, 0)
             h = _norm(h, params["final_norm_w"], params.get("final_norm_b"),
                       cfg)
             if self._relaxed_weights and self._q_head:
@@ -833,68 +891,93 @@ class DecodeEngine:
             key = jax.random.PRNGKey(state["seed"])
             c_first = None
             if S == 0:
-                # no speculation: one sample per row, bitwise the
-                # pre-speculation engine (same _sample over the same rows
-                # with the same key)
+                # no speculation: one sample per row. A decode-only step
+                # is bitwise the pre-speculation engine (same _sample over
+                # the same rows with the same key); a fused step draws a
+                # sampled row from the categorical of its B + 1 rows
                 sampled = _sample(logits, temps, topks, key)
                 out = sampled[:B][:, None]                      # [B, 1]
                 accept = jnp.zeros((B,), jnp.int32)
                 if chunk is not None:
-                    c_first = sampled[B * G + c_n - 1]
+                    c_first = sampled[B * G]
             else:
                 ku, kr_, kc_ = jax.random.split(key, 3)
                 dec_logits = logits[:B * G].reshape(B, G, -1)
                 V = dec_logits.shape[-1]
                 greedy_tok = jnp.argmax(dec_logits, axis=-1).astype(
                     jnp.int32)                                  # [B, G]
-                # target distribution per row: the exact _sample transform
-                # (top-k mask, temperature) in probability space
-                row_top = jnp.broadcast_to(topks_s[:, None], (B, G))
-                row_tmp = jnp.broadcast_to(temps_s[:, None], (B, G))
-                scaled = _mask_and_scale(dec_logits, row_tmp, row_top)
-                probs = jax.nn.softmax(scaled, axis=-1)         # [B, G, V]
-                # acceptance: greedy lanes by argmax equality; sampled
-                # lanes by rejection sampling — the n-gram draft is a point
-                # mass, so accept iff u < p_target(draft)
-                u = jax.random.uniform(ku, (B, S))
-                p_draft = jnp.take_along_axis(
-                    probs[:, :S], drafts[..., None], axis=2)[..., 0]
-                greedy_lane = temps_s <= 0
-                ok = jnp.where(greedy_lane[:, None],
-                               drafts == greedy_tok[:, :S], u < p_draft)
-                ok = ok & (jnp.arange(S)[None, :] < draft_lens[:, None])
-                accept = jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1),
-                                 axis=1)                        # [B] 0..S
-                # the bonus token at group index `accept`: greedy lanes
-                # take the argmax; sampled lanes draw from the target with
-                # a rejected draft token removed and renormalized (exact
-                # speculative sampling — all-accepted lanes sample the
-                # unmodified target)
-                p_a = jnp.take_along_axis(
-                    probs, jnp.broadcast_to(accept[:, None, None],
-                                            (B, 1, V)), axis=1)[:, 0]
-                g_a = jnp.take_along_axis(greedy_tok, accept[:, None],
-                                          axis=1)[:, 0]
-                rejected = accept < draft_lens
-                d_a = jnp.take_along_axis(
-                    drafts, jnp.minimum(accept, S - 1)[:, None],
-                    axis=1)[:, 0]
-                adj = jnp.where(rejected[:, None] &
-                                (jnp.arange(V)[None, :] == d_a[:, None]),
-                                0.0, p_a)
-                adj = adj / jnp.maximum(adj.sum(-1, keepdims=True), 1e-30)
-                samp_a = jax.random.categorical(
-                    kr_, jnp.log(jnp.maximum(adj, 1e-38)),
-                    axis=-1).astype(jnp.int32)
-                final = jnp.where(greedy_lane, g_a, samp_a)     # [B]
+                drafted = jnp.arange(S)[None, :] < draft_lens[:, None]
+                agree = drafted & (drafts == greedy_tok[:, :S])
+
+                def accepted(ok):                               # [B] 0..S
+                    return jnp.sum(jnp.cumprod(ok.astype(jnp.int32), axis=1),
+                                   axis=1)
+
+                def greedy_at(a):
+                    return jnp.take_along_axis(greedy_tok, a[:, None],
+                                               axis=1)[:, 0]
+
+                def verify_greedy():
+                    # greedy lanes accept by argmax equality and take the
+                    # argmax at group index `accept` as their bonus token
+                    a = accepted(agree)
+                    return a, greedy_at(a)
+
+                def verify_sampled():
+                    # target distribution per row: the exact _sample
+                    # transform (top-k mask, temperature) in probability
+                    # space
+                    scaled = _mask_and_scale(
+                        dec_logits, temps[:B * G].reshape(B, G),
+                        topks[:B * G].reshape(B, G))
+                    probs = jax.nn.softmax(scaled, axis=-1)     # [B, G, V]
+                    # sampled lanes accept by rejection sampling — the
+                    # n-gram draft is a point mass, so accept iff
+                    # u < p_target(draft)
+                    u = jax.random.uniform(ku, (B, S))
+                    p_draft = jnp.take_along_axis(
+                        probs[:, :S], drafts[..., None], axis=2)[..., 0]
+                    greedy_lane = temps_s <= 0
+                    a = accepted(jnp.where(greedy_lane[:, None], agree,
+                                           drafted & (u < p_draft)))
+                    # the bonus token at group index `accept`: sampled
+                    # lanes draw from the target with a rejected draft
+                    # token removed and renormalized (exact speculative
+                    # sampling — all-accepted lanes sample the unmodified
+                    # target)
+                    p_a = jnp.take_along_axis(
+                        probs, jnp.broadcast_to(a[:, None, None],
+                                                (B, 1, V)), axis=1)[:, 0]
+                    rejected = a < draft_lens
+                    d_a = jnp.take_along_axis(
+                        drafts, jnp.minimum(a, S - 1)[:, None],
+                        axis=1)[:, 0]
+                    adj = jnp.where(rejected[:, None] &
+                                    (jnp.arange(V)[None, :] == d_a[:, None]),
+                                    0.0, p_a)
+                    adj = adj / jnp.maximum(adj.sum(-1, keepdims=True),
+                                            1e-30)
+                    samp_a = jax.random.categorical(
+                        kr_, jnp.log(jnp.maximum(adj, 1e-38)),
+                        axis=-1).astype(jnp.int32)
+                    return a, jnp.where(greedy_lane, greedy_at(a), samp_a)
+
+                # the softmax and the rejection run only when a live lane
+                # samples (the same question _sample asks of its rows)
+                accept, final = jax.lax.cond(
+                    jnp.any(temps[:B * G] > 0), verify_sampled,
+                    verify_greedy)
                 draft_pad = jnp.concatenate(
                     [drafts, jnp.zeros((B, 1), jnp.int32)], axis=1)
                 out = jnp.where(gj[None, :] < accept[:, None],
                                 draft_pad, final[:, None])      # [B, G]
                 if chunk is not None:
-                    c_sampled = _sample(logits[B * G:], temps[B * G:],
-                                        topks[B * G:], kc_)
-                    c_first = c_sampled[c_n - 1]
+                    c_first = _sample(logits[B * G:], temps[B * G:],
+                                      topks[B * G:], kc_)[0]
+            # what the sampler was asked for, as the device saw it: the
+            # step took the arg-max-only arm; a threshold search ran
+            asked = jnp.stack([~jnp.any(temps > 0),
+                               jnp.any(topks > 0)]).astype(jnp.int32)
 
             # ---- in-graph stop-condition scan: budget clamp, stop_token
             # cut, lane retirement — the host reads the verdict, it does
@@ -931,13 +1014,13 @@ class DecodeEngine:
                 [out, n_emit[:, None], finished.astype(jnp.int32)[:, None],
                  accept[:, None]],
                 axis=1)                                         # [B, G + 3]
-            n_stats = len(self._family.counters)
-            if n_stats:
-                # the layers' own counts: a column each, in row 0
-                packed = jnp.concatenate(
-                    [packed,
-                     jnp.zeros((B, n_stats), jnp.int32).at[0].set(stats)],
-                    axis=1)
+            # the step's own counts and the layers': a column each, in
+            # row 0 (``_STEP_COUNTERS`` then the family's ``counters``)
+            counts = jnp.concatenate([asked, stats]) \
+                if self._family.counters else asked
+            packed = jnp.concatenate(
+                [packed, jnp.zeros((B, counts.shape[0]),
+                                   jnp.int32).at[0].set(counts)], axis=1)
         if chunk is None:
             return (*pools, new_state, packed)
         return (*pools, new_state, packed, c_first)
@@ -1637,7 +1720,8 @@ class DecodeEngine:
         soon as this step ends, and the device is not waiting on it."""
         with self._phase("engine.readback"):
             # the ONE device→host read of the step: [B, G+3] =
-            # tokens | emit_count | finished | accept_len
+            # tokens | emit_count | finished | accept_len, then the
+            # device's counts in row 0
             packed = np.asarray(flight.packed)
         if self._flight is not None:
             # the step behind this one starts on the device about now
@@ -1683,9 +1767,12 @@ class DecodeEngine:
         G = self.spec_k + 1
         self.steps += 1
         self._chunk_fill = flight.n_valid
-        for j, name in enumerate(self._family.counters if self.metrics
-                                 else ()):
-            getattr(self.metrics, name).incr(int(packed[0, G + 3 + j]))
+        self.steps_argmax_only += int(packed[0, G + 3])
+        self.steps_topk += int(packed[0, G + 4])
+        if self.metrics:
+            for j, name in enumerate(_STEP_COUNTERS
+                                     + self._family.counters):
+                getattr(self.metrics, name).incr(int(packed[0, G + 3 + j]))
         emitted = 0
         self.occupancy_log.append(len(flight.rows))
         if len(self.occupancy_log) > 100_000:

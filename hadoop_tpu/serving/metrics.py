@@ -26,6 +26,13 @@ class ServingMetrics:
                              thread keeps one step in flight; against
                              the step count, the share of steps whose
                              host work ran under the device's)
+    - ``steps_argmax_only`` / ``steps_topk``  steps whose sampler was an
+                             arg-max and nothing else (no live lane had
+                             a temperature), and steps in which a top-k
+                             threshold was searched for (a live sampled
+                             lane had ``top_k > 0``) — counted by the
+                             step itself on the device, read with its
+                             bundle
     - ``tokens_out``         generated tokens (monotonic; tokens/s is the
                              derivative any sink can take)
     - ``requests`` / ``preemptions`` lifetime counters
@@ -133,6 +140,12 @@ class ServingMetrics:
         self.steps_run_ahead = reg.counter(
             "steps_run_ahead",
             "steps dispatched before the step ahead of them was read")
+        self.steps_argmax_only = reg.counter(
+            "steps_argmax_only",
+            "steps in which no live lane sampled: arg-max only")
+        self.steps_topk = reg.counter(
+            "steps_topk",
+            "steps in which a top-k threshold was searched for")
         # the scheduler loop's own time, one family with the bounded
         # label set of engine.PHASES (inline literals: the label lint
         # proves the bound), and the TTFT timeline's three stages

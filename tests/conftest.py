@@ -55,6 +55,51 @@ def pytest_configure(config):
         "markers", "slow: excluded from the tier-1 run (-m 'not slow')")
 
 
+@pytest.fixture(scope="session")
+def tap_logits():
+    """``tap_logits(engine, taps)``: every row's float32 logits, appended
+    to ``taps`` once a step — the rows the family's layers hand back,
+    through the engine's own final norm and head. (The step itself makes
+    logits only for the rows it reads: the lanes' and the one at a
+    chunk's tip.) Call it before the engine's first step."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from hadoop_tpu.serving import engine as engine_mod
+
+    def tap(eng, taps):
+        run = eng._family.run_layers
+
+        def tapped(params, h, pools, lane, rows):
+            out = run(params, h, pools, lane, rows)
+            x = engine_mod._norm(out[0], params["final_norm_w"],
+                                 params.get("final_norm_b"), eng.cfg)
+            logits = x @ engine_mod.head_matrix(params, eng.cfg, x.dtype)
+            jax.debug.callback(lambda v: taps.append(np.asarray(v)),
+                               logits.astype(jnp.float32), ordered=True)
+            return out
+
+        eng._family.run_layers = tapped
+
+    return tap
+
+
+@pytest.fixture(scope="session")
+def jaxpr_eqns():
+    """``jaxpr_eqns(jaxpr)``: (primitive name, first output's shape) of
+    every equation, those of its sub-jaxprs (branches, loop bodies,
+    jitted calls) too."""
+    def walk(jaxpr, found=None):
+        found = [] if found is None else found
+        for eqn in jaxpr.eqns:
+            found.append((eqn.primitive.name, eqn.outvars[0].aval.shape))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, found)
+        return found
+
+    return walk
+
+
 @pytest.fixture(autouse=True)
 def _reset_global_state():
     """Each test gets a clean config registry and metrics system."""

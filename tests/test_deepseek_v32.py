@@ -23,7 +23,6 @@ from hadoop_tpu.models.config import ModelConfig, get_config
 from hadoop_tpu.models.moe import moe_share, route_grouped
 from hadoop_tpu.ops.rope import yarn_inv_frequencies, yarn_mscale
 from hadoop_tpu.ops.sparse_mla import exact_topk
-from hadoop_tpu.serving import engine as engine_mod
 from hadoop_tpu.serving.engine import DecodeEngine, SamplingParams
 from hadoop_tpu.serving.metrics import ServingMetrics
 
@@ -54,34 +53,25 @@ def make_params(model=MODEL, seed=SEED):
 
 
 @pytest.fixture(scope="module")
-def served():
-    """One engine serves every case; its step's logits are tapped where
-    ``_sample`` receives them, so the comparison is on logits."""
+def served(tap_logits):
+    """One engine serves every case; every row's logits are tapped where
+    the layers hand their rows back (``conftest.tap_logits``), so the comparison
+    is on logits."""
     cfg = F.model_config(MODEL, {"context": 256})
     taps = []
-    real = engine_mod._sample
+    eng = DecodeEngine(make_params(), cfg, max_batch=2, block_size=4,
+                       max_context=256, prefill_chunk=8,
+                       metrics=ServingMetrics("serving.test.dsv32"))
+    tap_logits(eng, taps)
+    step_fn, eng.chunks_seen = eng._step_fn, []
 
-    def tapped(logits, temps, topks, key):
-        jax.debug.callback(lambda x: taps.append(np.asarray(x)), logits,
-                           ordered=True)
-        return real(logits, temps, topks, key)
+    def spy(params, kp, vp, state, drafts, lens, chunk):
+        eng.chunks_seen.append(
+            None if chunk is None else [int(v) for v in chunk[1]])
+        return step_fn(params, kp, vp, state, drafts, lens, chunk)
 
-    engine_mod._sample = tapped
-    try:
-        eng = DecodeEngine(make_params(), cfg, max_batch=2, block_size=4,
-                           max_context=256, prefill_chunk=8,
-                           metrics=ServingMetrics("serving.test.dsv32"))
-        step_fn, eng.chunks_seen = eng._step_fn, []
-
-        def spy(params, kp, vp, state, drafts, lens, chunk):
-            eng.chunks_seen.append(
-                None if chunk is None else [int(v) for v in chunk[1]])
-            return step_fn(params, kp, vp, state, drafts, lens, chunk)
-
-        eng._step_fn = spy
-        yield eng, taps
-    finally:
-        engine_mod._sample = real
+    eng._step_fn = spy
+    return eng, taps
 
 
 def reference_logits(seq):

@@ -107,6 +107,11 @@ def test_the_thread_serves_a_stepped_engines_tokens(model, sampling):
     assert _served(eng, submit(eng)) == want
     assert eng.steps == ref.steps
     assert eng.steps_run_ahead >= eng.steps - 2
+    # the device's record of its sampler's arm, read a step late: every
+    # step an arg-max when nobody samples, every step a search otherwise
+    arms = (0, eng.steps) if sampling else (eng.steps, 0)
+    assert (eng.steps_argmax_only, eng.steps_topk) == arms == (
+        ref.steps_argmax_only, ref.steps_topk)
     # still two compiles an engine
     assert (eng.decode_compiles, eng.prefill_compiles) == (1, 1)
     assert (ref.decode_compiles, ref.prefill_compiles) == (1, 1)

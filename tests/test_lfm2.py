@@ -24,7 +24,6 @@ from chipbench.families import lfm2_moe as F
 from hadoop_tpu.models import lfm2
 from hadoop_tpu.models.config import ModelConfig, get_config
 from hadoop_tpu.models.moe import moe_share, route_grouped
-from hadoop_tpu.serving import engine as engine_mod
 from hadoop_tpu.serving.engine import DecodeEngine, SamplingParams
 from hadoop_tpu.serving.metrics import ServingMetrics
 
@@ -61,32 +60,22 @@ def make_engine(**kw):
 
 
 @pytest.fixture(scope="module")
-def served():
-    """One engine serves the logit cases; its step's logits are tapped
-    where ``_sample`` receives them."""
+def served(tap_logits):
+    """One engine serves the logit cases; every row's logits are tapped
+    where the layers hand their rows back (``conftest.tap_logits``)."""
     taps = []
-    real = engine_mod._sample
+    eng = make_engine()
+    tap_logits(eng, taps)
+    step_fn, eng.chunks_seen = eng._step_fn, []
 
-    def tapped(logits, temps, topks, key):
-        jax.debug.callback(lambda x: taps.append(np.asarray(x)), logits,
-                           ordered=True)
-        return real(logits, temps, topks, key)
+    def spy(params, *rest):
+        chunk = rest[-1]
+        eng.chunks_seen.append(
+            None if chunk is None else [int(v) for v in chunk[1]])
+        return step_fn(params, *rest)
 
-    engine_mod._sample = tapped
-    try:
-        eng = make_engine()
-        step_fn, eng.chunks_seen = eng._step_fn, []
-
-        def spy(params, *rest):
-            chunk = rest[-1]
-            eng.chunks_seen.append(
-                None if chunk is None else [int(v) for v in chunk[1]])
-            return step_fn(params, *rest)
-
-        eng._step_fn = spy
-        yield eng, taps
-    finally:
-        engine_mod._sample = real
+    eng._step_fn = spy
+    return eng, taps
 
 
 def reference_logits(seq):

@@ -19,11 +19,13 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from hadoop_tpu.conf import Configuration
 from hadoop_tpu.models.config import get_config
 from hadoop_tpu.models.decoder import forward, init_params
+from hadoop_tpu.serving import engine as engine_mod
 from hadoop_tpu.serving.engine import (BlockPool, DecodeEngine,
                                        PrefixCache, SamplingParams)
 
@@ -267,6 +269,113 @@ def test_per_request_sampling_params(tiny_model):
     assert greedy.wait(0) == ref
     assert topk1.wait(0) == ref
     assert all(0 <= t < cfg.vocab_size for t in free.wait(0))
+
+
+# ---------------------------------- the head and the sampler, by what is asked
+
+@pytest.mark.parametrize("spec_k", [0, 2])
+@pytest.mark.parametrize("shape", ["decode", "fused"])
+def test_step_holds_no_sort_and_a_head_of_the_rows_it_reads(
+        tiny_model, jaxpr_eqns, shape, spec_k):
+    """Neither shape of the step sorts the vocabulary, and the fused
+    shape's head matmul has ``B*G + 1`` rows — the lanes' and the ONE
+    chunk row whose sample is read — not ``B*G + C``."""
+    params, cfg = tiny_model
+    b, c, g = 3, 8, spec_k + 1
+    eng = DecodeEngine(params, cfg, max_batch=b, block_size=4,
+                       max_context=32, prefill_chunk=c, speculate_k=spec_k)
+    chunk = None if shape == "decode" else (
+        jnp.zeros((c,), jnp.int32), jnp.asarray([0, 0, c], jnp.int32))
+    closed = jax.make_jaxpr(eng._step_impl)(
+        eng.params, *eng._pools, eng._dstate, eng._dz_drafts, eng._dz_lens,
+        chunk)
+    prims = jaxpr_eqns(closed.jaxpr)
+    assert "sort" not in {name for name, _ in prims}
+    heads = {s[0] for name, s in prims
+             if name == "dot_general" and s[-1:] == (cfg.vocab_size,)}
+    assert heads == {b * g + (shape == "fused")}
+
+
+def _stepped(eng, *requests):
+    """Step until the requests are done; per step: (which of them were
+    still live when it began, steps_argmax_only's gain, steps_topk's)."""
+    log = []
+    while not all(r.done.is_set() for r in requests):
+        live = tuple(not r.done.is_set() for r in requests)
+        before = eng.steps, eng.steps_argmax_only, eng.steps_topk
+        eng.step()
+        if eng.steps > before[0]:
+            log.append((live, eng.steps_argmax_only - before[1],
+                        eng.steps_topk - before[2]))
+    return log
+
+
+def test_steps_count_what_the_sampler_was_asked_for(tiny_model):
+    """``steps_argmax_only`` / ``steps_topk`` are the device's own record
+    of the arm each step took: every step of an all-greedy run; none
+    while a sampled lane is live or its prompt chunk rides; again once
+    that lane has finished; never for a slot that is not live, whatever
+    parameters it still carries; the search only for a live sampled
+    top-k."""
+    params, cfg = tiny_model
+    m = _metrics()
+    eng = DecodeEngine(params, cfg, max_batch=3, block_size=4,
+                       max_context=48, prefill_chunk=4, metrics=m)
+    prompt = [11, 12, 13, 14, 15, 16, 17, 18, 19, 20]      # three chunks
+    ref = _reference_greedy(params, cfg, prompt, 14)
+
+    # (a) all greedy — a greedy lane with a top-k asks for no search
+    a = eng.submit(prompt, SamplingParams(max_new_tokens=5))
+    b = eng.submit([5, 6], SamplingParams(max_new_tokens=3, top_k=4))
+    log = _stepped(eng, a, b)
+    assert len(log) >= 6 and all(x[1:] == (1, 0) for x in log)
+    assert a.wait(0) == ref[:5]
+
+    # (b) a sampled lane beside a greedy one, and the steps after it
+    # (the sampled prompt first: its chunk rides the first step)
+    s = eng.submit([7, 8, 9], SamplingParams(max_new_tokens=3,
+                                             temperature=0.9))
+    g = eng.submit(prompt, SamplingParams(max_new_tokens=14))
+    log = _stepped(eng, g, s)
+    while_s = [x for x in log if x[0][1]]
+    after_s = [x for x in log if not x[0][1]]
+    assert len(while_s) >= 3 and all(x[1:] == (0, 0) for x in while_s)
+    assert len(after_s) >= 4 and all(x[1:] == (1, 0) for x in after_s)
+    assert g.wait(0) == ref                 # greedy beside it: unmoved
+
+    # (c) a sampled prompt alone: its chunk (the tail the prefix cache
+    # does not hold) switches the arm on before any lane decodes, and
+    # its top-k the search
+    t = eng.submit(prompt, SamplingParams(max_new_tokens=4,
+                                          temperature=0.7, top_k=3))
+    log = _stepped(eng, t)
+    assert len(log) >= 4 and all(x[1:] == (0, 1) for x in log)
+    assert all(0 <= tok < cfg.vocab_size for tok in t.wait(0))
+
+    # (d) top_k=1 at a temperature is the arg-max, by the search
+    k1 = eng.submit(prompt, SamplingParams(max_new_tokens=5,
+                                           temperature=1.0, top_k=1))
+    log = _stepped(eng, k1)
+    assert all(x[1:] == (0, 1) for x in log) and k1.wait(0) == ref[:5]
+
+    # (e) a lane the device has retired keeps its parameters until the
+    # host's release event lands (one step later, when the loop runs
+    # ahead): such a slot asks for nothing
+    stale = np.asarray([2, 0, 0, 0, 5, 0, 0, -1], np.int32)
+    eng._dstate = engine_mod._SET_SLOT(
+        eng._dstate, stale, np.zeros((eng.blocks_per_seq,), np.int32),
+        np.float32(0.9))
+    e = eng.submit(prompt, SamplingParams(max_new_tokens=5))
+    log = _stepped(eng, e)
+    assert all(x[1:] == (1, 0) for x in log) and e.wait(0) == ref[:5]
+    temps, topks, active = jax.device_get(
+        [eng._dstate[k] for k in ("temps", "topks", "active")])
+    assert temps[2] > 0 and topks[2] == 5 and not active[2]
+
+    snap = m.snapshot()
+    assert snap["steps_argmax_only"] == eng.steps_argmax_only > 0
+    assert snap["steps_topk"] == eng.steps_topk > 0
+    assert eng.decode_compiles == 1 and eng.prefill_compiles == 1
 
 
 def test_warm_prefix_cache_stays_exact_match(tiny_model):

@@ -197,13 +197,13 @@ def test_a_family_is_asked_by_conf_key_what_it_refuses(toy):
 PINS = {
     "tiny": ((4, 9, 4, 2, 16), (4, 9, 4, 2, 16), 4096,
              "bf7f3adbf5a543b373d3e78f082448bcd7bbcdfb587dad4a484122dda7c8f185",
-             (2, 4), set()),
+             (2, 6), set()),
     "tiny-moe": ((2, 9, 4, 2, 16), (2, 9, 4, 2, 16), 2048,
                  "19670316828032032e0608387260f35eb30dce94605cc2967180fe981732c4b8",
-                 (2, 4), {"a2a_codec", "expert_capacity"}),
+                 (2, 6), {"a2a_codec", "expert_capacity"}),
     "tiny-dsv32": ((3, 9, 4, 128), (3, 9, 4, 16), 6912,
                    "a2045a68068ed982674bd8b7b33b6b2cd267f1a2ca9e359ee30acb0e52705e91",
-                   (2, 6), {"experts_from", "experts_routed"}),
+                   (2, 8), {"experts_from", "experts_routed"}),
 }
 # (``tiny-lfm2`` has three pools and a lane state: tests/test_lfm2.py)
 PLANE_KEYS = {"dtype", "expert_bytes", "expert_shards", "experts",
@@ -229,6 +229,8 @@ def test_what_the_engine_derives_is_what_it_held_before(name):
     out = jax.eval_shape(eng._step_impl, eng.params, eng._kp, eng._vp,
                          eng._dstate, eng._dz_drafts, eng._dz_lens, None)
     assert out[3].shape == packed
-    assert out[3].shape[1] == 4 + len(eng._family.counters)
+    # the step's own counts ride the bundle before the family's
+    assert out[3].shape[1] == 4 + len(engine_mod._STEP_COUNTERS) \
+        + len(eng._family.counters)
     assert set(eng.weight_plane()) == PLANE_KEYS | extra
     eng.stop()
