@@ -16,7 +16,7 @@ class ModelConfig:
     explicitly so tiny test/dryrun configs and real configs share one code
     path (static shapes only — required for XLA).
     """
-    # gpt2 | llama | mixtral | deepseek_v32 | lfm2_moe
+    # gpt2 | llama | mixtral | deepseek_v32 | lfm2_moe | ouro
     family: str = "llama"
     vocab_size: int = 32000
     d_model: int = 4096
@@ -77,12 +77,32 @@ class ModelConfig:
     layer_types: Tuple[str, ...] = ()
     conv_kernel: int = 0
     router_norm_eps: float = 0.0
+    # ---- family "ouro" (serving only): the llama layer with a norm
+    # after each sub-layer as well as before it (``sandwich_norm``:
+    # ``x += norm(Attn(norm(x))); x += norm(MLP(norm(x)))``), and the
+    # SAME ``n_layers`` layers run ``n_passes`` times a token over one
+    # set of weights, the final norm closing every pass. Each pass
+    # caches its own K and V: a token keeps ``n_passes * n_layers``
+    # entries, pass ``t`` (from 0) of layer ``l`` in slot ``t * n_layers
+    # + l``. An exit gate (two leaves of the tree) gives every pass a
+    # probability; at ``early_exit_threshold`` 1.0, the published value
+    # and the only one built, every token takes the last pass.
+    n_passes: int = 1
+    sandwich_norm: bool = False
+    early_exit_threshold: float = 1.0
 
     def __post_init__(self):
         if self.family == "deepseek_v32":
             _validate_deepseek_v32(self)
         elif self.family == "lfm2_moe":
             _validate_lfm2_moe(self)
+        elif self.family == "ouro":
+            _validate_ouro(self)
+        elif self.n_passes != 1 or self.sandwich_norm:
+            raise ValueError(
+                f"family={self.family!r}: n_passes and sandwich_norm are "
+                "family 'ouro''s (no other family's training or serving "
+                "layer reads them)")
 
     @property
     def head_dim(self) -> int:
@@ -171,13 +191,42 @@ def _validate_lfm2_moe(c: ModelConfig) -> None:
          "RoPE, RMSNorm, SwiGLU and a tied head are the architecture")
 
 
+def _validate_ouro(c: ModelConfig) -> None:
+    def need(ok: bool, what: str) -> None:
+        if not ok:
+            raise ValueError(f"family='ouro': {what}")
+    need(c.n_passes >= 1, f"n_passes={c.n_passes} (total_ut_steps) must "
+         "be >= 1")
+    need(c.sandwich_norm, "sandwich_norm (a norm after each sub-layer "
+         "too) is the architecture")
+    need(c.d_model % c.n_heads == 0 and c.n_heads % c.n_kv_heads == 0
+         and c.head_dim % 2 == 0,
+         f"d_model={c.d_model} must divide into n_heads={c.n_heads} even "
+         f"heads and n_kv_heads={c.n_kv_heads} must divide n_heads")
+    need(c.use_rope and c.use_rmsnorm and c.use_swiglu
+         and not c.tie_embeddings and not c.is_moe,
+         "RoPE, RMSNorm, a dense SwiGLU MLP and an untied head are the "
+         "architecture")
+    if c.early_exit_threshold != 1.0:
+        # a row that leaves the loop after fewer passes is another
+        # program (rows of one step at different depths), not a setting
+        raise NotImplementedError(
+            f"family='ouro': early_exit_threshold="
+            f"{c.early_exit_threshold} is not built: below 1.0 a token "
+            "stops after the first pass whose cumulative exit "
+            "probability reaches it, and only the published 1.0 (every "
+            "token takes all n_passes) has a path")
+
+
 # families with a serving path only, and what a training entry point
 # would have to have for them
 SERVING_ONLY = {
     "deepseek_v32": "latent attention, sparse selection or held-expert "
                     "layer",
     "lfm2_moe": "gated short convolution, per-head q/k norm or "
-                "resident-expert layer"}
+                "resident-expert layer",
+    "ouro": "pass loop over one set of weights, sandwich norm or loss "
+            "over exit passes"}
 
 
 def refuse_training(cfg: ModelConfig, where: str) -> None:
@@ -258,6 +307,12 @@ PRESETS = {
         n_dense_layers=1, conv_kernel=3, router_norm_eps=1e-6,
         layer_types=("conv", "full_attention", "conv", "conv",
                      "full_attention", "conv", "conv")),
+    # 3 sandwich-normed layers run 3 times over: 9 K/V slots a token
+    "tiny-ouro": ModelConfig(
+        family="ouro", vocab_size=256, d_model=64, n_layers=3, n_heads=4,
+        n_kv_heads=4, d_ff=128, max_seq=256, norm_eps=1e-6,
+        rope_theta=10000.0, dtype="float32", n_passes=3,
+        sandwich_norm=True),
     "tiny-gpt2": _gpt2(vocab_size=256, d_model=64, n_layers=2, n_heads=4,
                        n_kv_heads=4, d_ff=256, max_seq=128, dtype="float32"),
 }

@@ -523,7 +523,7 @@ class DecodeEngine:
         # the tier manager owns the radix index and the cold tiers;
         # the engine stays the device owner (extract/inject below)
         self.kvstore = TieredKVCache(
-            self.pool, layers=cfg.n_layers,
+            self.pool, layers=self._family.page_slots,
             kv_heads=self._family.salt_layout[0],
             head_dim=self._family.salt_layout[1], dtype=cfg.jax_dtype,
             enabled=prefix_cache, host_bytes=kv_host_bytes,
@@ -1734,7 +1734,8 @@ class DecodeEngine:
         """The live-page share of this step's attention, from the host's
         mirrors: pages its live rows attend to (a row at position ``p``
         reads the pages of ``p + 1`` tokens) against every row's whole
-        table; then what only the family counts."""
+        table — table pages, each as deep as the family's pools — the
+        pool's fill, then what only the family counts."""
         bs = self.block_size
         j = np.arange(self.spec_k + 1)
         lanes = np.asarray([slot for slot, _ in rows], np.intp)
@@ -1748,6 +1749,11 @@ class DecodeEngine:
             n_rows += self.prefill_chunk
         self.metrics.attn_pages_read.incr(int(np.sum(-(-lens // bs))))
         self.metrics.attn_pages_dense.incr(n_rows * self.blocks_per_seq)
+        # the pool's fill, step by step: pages held (by a lane or by the
+        # prefix cache) against the pages there are
+        self.metrics.kv_pages_live_steps.incr(
+            self.pool.num_usable - self.pool.num_free)
+        self.metrics.kv_pages_pool_steps.incr(self.pool.num_usable)
         self._family.count_step(self.metrics, lens,
                                 self._chains(rows, at, pre, n_valid))
 

@@ -37,7 +37,9 @@ class Family:
     # shape]`` in ``cfg.jax_dtype``. A pool spans the layers that read
     # it (all of them, or those of one kind), and a page holds there
     # whatever the family keeps of its tokens: an entry a token, or
-    # something of a fixed size a page.
+    # something of a fixed size a page. ``layers`` counts SLOTS, not
+    # layers of weights: a stack run several times over one set of
+    # weights keeps an entry a (pass, layer).
     def pools(self, block_size: int) -> Sequence[Tuple[int, Tuple[int, ...]]]:
         raise NotImplementedError
 
@@ -60,6 +62,13 @@ class Family:
     # the (kv_heads, head_dim) pair the chain salt is made of: prefixes
     # persisted to the DFS tier are keyed by it, so it never changes
     salt_layout: Tuple[int, int]
+
+    @property
+    def page_slots(self) -> int:
+        """How deep a page is where the cold tiers move it and the chain
+        salt names it: the layers of weights, unless a token keeps more
+        entries than the stack has layers."""
+        return self.cfg.n_layers
     # every pool's spec on a tp mesh (replicated unless the family says)
     pool_spec = PartitionSpec()
     # how many local chips the expert stacks split over (0: no experts)
@@ -117,11 +126,12 @@ class Family:
 from hadoop_tpu.serving.families.gqa import PagedKVFamily  # noqa: E402
 from hadoop_tpu.serving.families.latent import LatentFamily  # noqa: E402
 from hadoop_tpu.serving.families.lfm2 import ConvStateFamily  # noqa: E402
+from hadoop_tpu.serving.families.looped import LoopedKVFamily  # noqa: E402
 
 FAMILIES: Dict[str, type] = {
     "gpt2": PagedKVFamily, "llama": PagedKVFamily,
     "mixtral": PagedKVFamily, "deepseek_v32": LatentFamily,
-    "lfm2_moe": ConvStateFamily}
+    "lfm2_moe": ConvStateFamily, "ouro": LoopedKVFamily}
 
 
 def family_for(cfg: ModelConfig, asked: Mapping[str, Any],

@@ -6,6 +6,8 @@ weight-plane aware (``serving.parity=relaxed``: int8 + scale groups).
 Both pools ride the ONE layer scan as its carry, viewed ``[layers *
 blocks, bs, hkv, dh]``: layer ``l`` writes and reads its pages where
 they lie, at ``l * blocks + page`` (``tests/test_engine_pool_carry.py``).
+``cfg.sandwich_norm`` adds a norm after each sub-layer; ``looped.py``
+runs this stack several times a token over pools that many times deeper.
 """
 
 from __future__ import annotations
@@ -149,9 +151,12 @@ class PagedKVFamily(Family):
                          ye.astype(jnp.float32))
         return y2d.astype(x.dtype)
 
-    def run_layers(self, params, h, pools, lane, rows):
+    def run_stack(self, params, h, kc, vc, rows, bases):
+        """Every layer once over the step's rows. ``kc`` / ``vc``: the
+        pools viewed ``[slots * blocks, bs, hkv, dh]``, carried through
+        the ONE layer scan; layer ``l`` writes and reads its pages at
+        ``bases[l] + page``."""
         cfg = self.cfg
-        kp, vp = pools
         t = h.shape[0]
         hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         pos, cos, sin = rows["pos"], rows["cos"], rows["sin"]
@@ -180,24 +185,32 @@ class PagedKVFamily(Family):
                 attn = paged_attention(q, kc, vc, base + tables, lens,
                                        scale, impl=self._attn_impl)
             with jax.named_scope("attn_proj"):
-                h2 = h + self._wdot(attn.reshape(t, hq * dh),
-                                    lp["wo"]).astype(h.dtype)
+                a = self._wdot(attn.reshape(t, hq * dh), lp["wo"])
+                if cfg.sandwich_norm:
+                    a = _norm(a, lp["attn_post_norm_w"], None, cfg)
+                h2 = h + a.astype(h.dtype)
             with jax.named_scope("mlp"):
                 x2 = _norm(h2, lp["mlp_norm_w"], lp.get("mlp_norm_b"),
                            cfg)
-                return (h2 + self._mlp(x2, lp).astype(h.dtype), kc,
-                        vc), None
+                m = self._mlp(x2, lp)
+                if cfg.sandwich_norm:
+                    m = _norm(m, lp["mlp_post_norm_w"], None, cfg)
+                return (h2 + m.astype(h.dtype), kc, vc), None
 
-        pool_shape = kp.shape
-        n_blocks = pool_shape[1]
-        kp = kp.reshape((-1,) + pool_shape[2:])
-        vp = vp.reshape((-1,) + pool_shape[2:])
         # comm_scale: the trace-time comm ledgers see one body trace of
         # the scan; the hardware runs it n_layers times per step — the
         # MoE a2a sites record honest per-step executions/bytes
         with comm_scale(cfg.n_layers):
-            (h, kp, vp), _ = jax.lax.scan(
-                layer, (h, kp, vp),
-                (params["layers"],
-                 jnp.arange(cfg.n_layers, dtype=jnp.int32) * n_blocks))
+            (h, kc, vc), _ = jax.lax.scan(
+                layer, (h, kc, vc), (params["layers"], bases))
+        return h, kc, vc
+
+    def run_layers(self, params, h, pools, lane, rows):
+        kp, vp = pools
+        pool_shape = kp.shape
+        n_blocks = pool_shape[1]
+        h, kp, vp = self.run_stack(
+            params, h, kp.reshape((-1,) + pool_shape[2:]),
+            vp.reshape((-1,) + pool_shape[2:]), rows,
+            jnp.arange(self.cfg.n_layers, dtype=jnp.int32) * n_blocks)
         return h, (kp.reshape(pool_shape), vp.reshape(pool_shape)), lane, ()

@@ -25,8 +25,8 @@ from typing import Optional, Tuple
 
 import jax
 
+from hadoop_tpu.models import init_params_for
 from hadoop_tpu.models.config import ModelConfig
-from hadoop_tpu.models.decoder import init_params
 from hadoop_tpu.parallel.checkpoint import latest_step, load_checkpoint
 
 log = logging.getLogger(__name__)
@@ -68,7 +68,7 @@ def load_serving_params(fs, base_dir: str, cfg: ModelConfig, *,
             raise FileNotFoundError(f"no checkpoints under {base_dir}")
     manifest = json.loads(fs.read_all(
         f"{base_dir}/step_{step:012d}/manifest.json").decode())
-    shapes = jax.eval_shape(lambda k: init_params(k, cfg),
+    shapes = jax.eval_shape(lambda k: init_params_for(cfg)(k, cfg),
                             jax.random.PRNGKey(0))
     wrapped = any(name.startswith("['params']")
                   for name in manifest["leaves"])

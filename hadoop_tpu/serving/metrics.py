@@ -59,6 +59,15 @@ class ServingMetrics:
                              live rows attended to, against the pages a
                              walk of every row's whole table would have
                              read; their ratio is the live-page share
+    - ``kv_pages_live_steps`` / ``kv_pages_pool_steps``  pages of the
+                             pool held at each step (by a lane or by the
+                             prefix cache) against the pages it has,
+                             both summed over steps: their ratio is the
+                             pool's mean fill as the steps saw it
+    - ``loop_passes``        (a stack run several times over one set of
+                             weights) passes the steps ran — counted by
+                             the step itself on the device; over the
+                             step count it is the passes a token takes
     - ``attn_entries_live`` / ``attn_entries_selected``  (sparse
                              selection) context entries the steps' live
                              rows could attend to, against the entries
@@ -241,6 +250,17 @@ class ServingMetrics:
         self.attn_pages_dense = reg.counter(
             "attn_pages_dense",
             "KV pages a walk of every row's whole block table would read")
+        self.kv_pages_live_steps = reg.counter(
+            "kv_pages_live_steps",
+            "KV pages held (lanes and prefix cache), summed over steps")
+        self.kv_pages_pool_steps = reg.counter(
+            "kv_pages_pool_steps",
+            "KV pages the pool has, summed over steps")
+        # a stack run several times over one set of weights (zero for
+        # families without a pass loop)
+        self.loop_passes = reg.counter(
+            "loop_passes",
+            "passes over the layer stack, summed over steps (device's)")
         # sparse selection and the held share of a wider router (zero
         # for families without them)
         self.attn_entries_live = reg.counter(

@@ -211,21 +211,24 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("rows,hq,dtype", [(16, 32, "bfloat16"),
-                                           (32, 32, "bfloat16"),
-                                           (16, 16, "float32")])
-def test_kernel_compiles_for_v5e_at_serving_widths(one_chip, rows, hq,
-                                                   dtype):
+@pytest.mark.parametrize("rows,hq,hkv,pages,dtype", [
+    (16, 32, 8, 3072, "bfloat16"), (32, 32, 8, 3072, "bfloat16"),
+    (16, 16, 8, 3072, "float32"),
+    (16, 16, 16, 192 * 352, "bfloat16"), (80, 16, 16, 192 * 352, "bfloat16")])
+def test_kernel_compiles_for_v5e_at_serving_widths(one_chip, rows, hq, hkv,
+                                                   pages, dtype):
     """Mistral / Mixtral (32 query heads on 8 KV heads of 128) and the
     flagship presets (16 on 8), decode-only and fused row counts, the
-    benchmark's 3072-page pool of 16-token pages, context 2048."""
+    benchmark's 3072-page pool of 16-token pages, context 2048; and the
+    looped family's (16 heads on 16 KV heads, no grouping) over its pool
+    viewed ``[192 slots * 352 pages, ...]``, at 16 and 16 + 64 rows."""
     from jax.experimental.compilation_cache import compilation_cache
     dtype = jnp.dtype(dtype)
 
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    pool = sds((3072, 16, 8, 128), dtype)
+    pool = sds((pages, 16, hkv, 128), dtype)
     fn = jax.jit(lambda q, kc, vc, tables, lens: paged_attention(
         q, kc, vc, tables, lens, 128 ** -0.5, impl="flash"))
     # what is compiled for a described chip cannot be read back here

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hadoop_tpu.models import decoder, deepseek
+from hadoop_tpu.models import decoder, deepseek, ouro
 from hadoop_tpu.models.config import PRESETS, get_config
 from hadoop_tpu.serving import engine as engine_mod
 from hadoop_tpu.serving import families
@@ -204,6 +204,11 @@ PINS = {
     "tiny-dsv32": ((3, 9, 4, 128), (3, 9, 4, 16), 6912,
                    "a2045a68068ed982674bd8b7b33b6b2cd267f1a2ca9e359ee30acb0e52705e91",
                    (2, 8), {"experts_from", "experts_routed"}),
+    # pools passes x layers = 9 slots deep over 3 layers of weights, and
+    # the salt names the slots; one stats column (``loop_passes``)
+    "tiny-ouro": ((9, 9, 4, 4, 16), (9, 9, 4, 4, 16), 18432,
+                  "76c56516387c332e5ecf7e904bdeee0fce1fb029feded73fd65e75b0520eb476",
+                  (2, 7), set()),
 }
 # (``tiny-lfm2`` has three pools and a lane state: tests/test_lfm2.py)
 PLANE_KEYS = {"dtype", "expert_bytes", "expert_shards", "experts",
@@ -216,8 +221,8 @@ PLANE_KEYS = {"dtype", "expert_bytes", "expert_shards", "experts",
 def test_what_the_engine_derives_is_what_it_held_before(name):
     kp_shape, vp_shape, nbytes, salt, packed, extra = PINS[name]
     cfg = get_config(name)
-    init = deepseek.init_params if name == "tiny-dsv32" \
-        else decoder.init_params
+    init = {"tiny-dsv32": deepseek.init_params,
+            "tiny-ouro": ouro.init_params}.get(name, decoder.init_params)
     eng = DecodeEngine(init(jax.random.PRNGKey(0), cfg), cfg, max_batch=2,
                        block_size=4, num_blocks=9, max_context=32,
                        prefill_chunk=8)
@@ -226,6 +231,9 @@ def test_what_the_engine_derives_is_what_it_held_before(name):
     assert eng.block_nbytes * 9 == eng._kp.nbytes + eng._vp.nbytes
     assert len(eng._pools) == 2 and eng._dstate["lane"] is None
     assert eng.kvstore.chain_salt.hex() == salt
+    # a page is as deep as the family's pools, where the tiers move it too
+    assert eng.kvstore.block_shape[0] == eng._family.page_slots \
+        == kp_shape[0]
     out = jax.eval_shape(eng._step_impl, eng.params, eng._kp, eng._vp,
                          eng._dstate, eng._dz_drafts, eng._dz_lens, None)
     assert out[3].shape == packed
