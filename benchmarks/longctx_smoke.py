@@ -126,8 +126,11 @@ def child(quick: bool = False) -> dict:
             MiniDFSCluster(num_datanodes=1, conf=dconf,
                            base_dir=os.path.join(tmp, "dfs")) as c:
         c.wait_active()
+        # a copy: under a budget the engine frees the stacks it
+        # re-places, and the plane below reads them by name
         engine = DecodeEngine(
-            params, cfg, block_size=bs, max_context=64,
+            jax.tree_util.tree_map(jax.numpy.copy, params), cfg,
+            block_size=bs, max_context=64,
             prefill_chunk=8, hbm_bytes=hbm_bytes,
             kv_host_bytes=host_bytes, kv_store_fs=c.get_filesystem(),
             kv_store_dir="/kvcache", metrics=ServingMetrics())
@@ -294,7 +297,8 @@ def child(quick: bool = False) -> dict:
 
     # ---- int8 codec arm: chain stored int8 in the host ring
     engine8 = DecodeEngine(
-        params, cfg, block_size=bs, max_context=64, prefill_chunk=8,
+        jax.tree_util.tree_map(jax.numpy.copy, params), cfg,
+        block_size=bs, max_context=64, prefill_chunk=8,
         hbm_bytes=hbm_bytes,
         kv_host_bytes=(prompt_len // bs + 8) * block_nbytes,
         kv_codec="int8", metrics=ServingMetrics())

@@ -442,7 +442,8 @@ def run_quantized(preset="tiny", requests=24, max_new=12, block_size=4,
             "prefill_compiles": eng.prefill_compiles,
         }
 
-    f32 = arm(params)
+    # a copy: under a budget the engine frees the stacks it re-places
+    f32 = arm(jax.tree_util.tree_map(jax.numpy.copy, params))
     int8 = arm(qparams, qreport["quantize_seconds"])
     guard = run_weight_ab(cfg, params, qparams, seed=seed, wp=wp)
     cap_ratio = int8["lanes_x_context"] / max(1, f32["lanes_x_context"])

@@ -123,6 +123,13 @@ Design (TPU-first, same rules as the trainer):
   context of the f32 plane at the same budget. Bitwise
   (the default) compiles zero quantized code: tpulint's
   ``parity/relaxed-gated`` checker holds every qdot/qrows/qhead call.
+  A family may bring leaves, once, into the form its matmuls consume
+  (``Family.place_weights``: the dense family's ``wq`` / ``wk`` / ``wv``
+  joined into ``wqkv``), in place of the loaded ones: the bytes do not
+  change. Under ``hbm_bytes`` the budget counts the weights once, so
+  the tree is then the engine's own and the replaced leaves are freed
+  at construction, whoever else names them; without it the caller's
+  tree is left as it was.
 
 - **Long-context lane.** With a ``serving/longctx`` plane attached
   (``attach_longctx`` — ``serving.parity=relaxed`` only), prompts of
@@ -534,6 +541,12 @@ class DecodeEngine:
             extract=self._extract_block)
         self.prefix_cache = self.kvstore.radix
 
+        # under a byte budget the tree is the engine's: the pool was
+        # sized as if the weights were resident ONCE, so a leaf the
+        # family replaces is freed before the pools are made, and a
+        # caller that goes on using its tree hands over a copy
+        params = self._family.place_weights(params,
+                                            owned=bool(self.hbm_bytes))
         self._mesh = None
         if plan is not None:
             from hadoop_tpu.parallel.mesh import (make_mesh, param_specs,

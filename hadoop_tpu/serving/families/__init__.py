@@ -87,6 +87,14 @@ class Family:
         asked for that this family is not built for (at construction,
         and again when a long-context plane is attached)."""
 
+    def place_weights(self, params, owned: bool):
+        """``params`` with the leaves ``run_layers`` reads brought, once,
+        into the form its matmuls consume — in place of the loaded
+        leaves, never beside them: the tree's bytes do not change.
+        ``owned``: the engine holds the tree under a byte budget, so a
+        replaced leaf is freed here and now, whoever else names it."""
+        return params
+
     def place_experts(self, params):
         """(params with the expert stacks split over ``expert_shards``
         chips, the sharding the step's carried buffers then take)."""

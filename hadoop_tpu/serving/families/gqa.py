@@ -49,6 +49,16 @@ def _shard_expert_stacks(params, shards: int):
     return {**params, "layers": layers}, NamedSharding(mesh, P())
 
 
+# the attention input projections, in the order they are joined along N
+# into the ONE leaf ``wqkv`` that ``run_stack``'s matmul streams
+QKV = ("wq", "wk", "wv")
+
+
+@jax.jit
+def _join(*stacks):
+    return jnp.concatenate(stacks, axis=-1)
+
+
 def _rope_at(x, cos, sin, pos):
     """Rotate one token per row: x [T, H, Dh], pos [T]."""
     c = cos[pos][:, None, :]
@@ -77,6 +87,10 @@ class PagedKVFamily(Family):
             cfg, capacity_factor=float(moe_capacity_factor)) \
             if cfg.is_moe and moe_capacity_factor else cfg
         self._relaxed_weights = bool(asked.get(RELAXED))
+        # two planes read the projections by name and keep the tree as
+        # loaded: int8 scale groups, and a tp cut along N (which would
+        # cut a joined axis across q, k and v)
+        self._joins_qkv = not (asked.get(RELAXED) or asked.get(TP_PLAN))
         # the Pallas kernel is a one-device program: a pool sharded over
         # the engine's mesh takes the portable path under GSPMD
         self._attn_impl = "ref" if asked.get(TP_PLAN) else "auto"
@@ -89,6 +103,26 @@ class PagedKVFamily(Family):
         # a token's K and V per KV head, in every layer
         page = (block_size, self.cfg.n_kv_heads, self.cfg.head_dim)
         return [(self.cfg.n_layers, page)] * 2
+
+    def place_weights(self, params, owned: bool):
+        """``wq``, ``wk``, ``wv`` ``[L, K, N*]`` joined into ``wqkv`` ``[L,
+        K, Nq + Nk + Nv]``, in their place: the same bytes. Reshaped to
+        heads, ``x @ wq`` wants the weight K-minor, and the compiler
+        relays a stack that is not (whole, every step, under the looped
+        family's pass loop: 1.2 GB of temporaries; a layer's slice in
+        VMEM, every layer, without it). One matmul split after it
+        streams the stack from HBM as ``w_gate`` is streamed."""
+        if not self._joins_qkv:
+            return params
+        layers = dict(params["layers"])
+        stacks = [layers.pop(k) for k in QKV]
+        layers["wqkv"] = _join(*stacks)
+        if owned:
+            # donation cannot free them (no output has a stack's shape)
+            layers["wqkv"].block_until_ready()
+            for w in stacks:
+                w.delete()
+        return {**params, "layers": layers}
 
     def place_experts(self, params):
         if self.expert_shards > 1:
@@ -170,9 +204,14 @@ class PagedKVFamily(Family):
             lp, base = xs
             with jax.named_scope("attn_proj"):
                 x = _norm(h, lp["attn_norm_w"], lp.get("attn_norm_b"), cfg)
-                q = self._wdot(x, lp["wq"]).reshape(t, hq, dh)
-                k = self._wdot(x, lp["wk"]).reshape(t, hkv, dh)
-                v = self._wdot(x, lp["wv"]).reshape(t, hkv, dh)
+                if "wqkv" in lp:    # placed: place_weights
+                    q, k, v = jnp.split(self._wdot(x, lp["wqkv"]),
+                                        [hq * dh, (hq + hkv) * dh], axis=-1)
+                else:
+                    q, k, v = (self._wdot(x, lp[n]) for n in QKV)
+                q = q.reshape(t, hq, dh)
+                k = k.reshape(t, hkv, dh)
+                v = v.reshape(t, hkv, dh)
                 if cfg.use_rope:
                     q = _rope_at(q, cos, sin, pos)
                     k = _rope_at(k, cos, sin, pos)

@@ -173,6 +173,10 @@ def test_longctx_end_to_end_matches_single_chip(tiny_model):
                        kv_host_bytes=1 << 22, metrics=ServingMetrics())
     plane = _mk_plane(params, cfg, eng)
     eng.attach_longctx(plane)
+    # the engine's tree is placed for its own step (wqkv); the plane
+    # reads the projections by name from the tree it was given
+    assert "wqkv" in eng.params["layers"]
+    assert "wq" in plane.decoder.params["layers"]
     try:
         prompt = _prompt(cfg, 150)
         req = eng.submit(prompt, SamplingParams(max_new_tokens=6))

@@ -8,6 +8,10 @@ pages, with the GQA group kept together and K/V left in their dtype —
 the portable path as it runs, the Pallas kernel through the interpreter.
 """
 
+import dataclasses
+import re
+from unittest import mock
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -272,3 +276,87 @@ def test_packed_pages_compile_for_v5e_with_no_pool_copied(one_chip, rows):
         compilation_cache.reset_cache()
     # a pool is 336 MB: what is made is a chunk's gathered pages
     assert compiled.memory_analysis().temp_size_in_bytes < 300 << 20
+
+
+# the two dense paged cells' layers at their published widths, two layers
+# of them: (family, d_model, heads, KV heads, d_ff, passes, pool pages)
+STEP_SHAPES = {
+    "mistral-7b": ("llama", 4096, 32, 8, 14336, 1, 3072),
+    "ouro-2.6b": ("ouro", 2048, 16, 16, 5632, 4, 352),
+}
+
+
+@pytest.mark.parametrize("chunk", [0, 64], ids=["decode", "fused"])
+@pytest.mark.parametrize("model", sorted(STEP_SHAPES))
+def test_the_step_relays_no_projection_weight_on_v5e(one_chip, model, chunk):
+    """The family's layers over abstract arguments, the tree placed as
+    the engine places it (``place_weights``), compiled for the described
+    chip: no ``copy`` of a stacked or sliced projection weight, and fewer
+    temporaries than one stack. As loaded, ``(x @ wq).reshape(t, hq,
+    dh)`` wants ``wq`` K-minor: the looped family's step relaid three
+    whole stacks a step (hoisted out of both loops, their results
+    temporaries), the plain one a layer's ``wq``, ``wk``, ``wv`` in VMEM
+    in every layer."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from hadoop_tpu.models import init_params_for
+    from hadoop_tpu.serving import families
+    fam, d, hq, hkv, dff, passes, pages = STEP_SHAPES[model]
+    base = get_config("tiny-ouro" if fam == "ouro" else "tiny")
+    cfg = dataclasses.replace(
+        base, d_model=d, n_heads=hq, n_kv_heads=hkv, d_ff=dff, n_layers=2,
+        n_passes=passes, max_seq=2048, dtype="bfloat16")
+    family = families.family_for(cfg, {})
+    lanes, bs, bps = 16, 16, 2048 // 16
+    t = lanes + chunk
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: family.place_weights(init_params_for(cfg)(k, cfg), False),
+        jax.random.PRNGKey(0)))
+    pools = tuple(sds((slots, pages) + tuple(page), cfg.jax_dtype)
+                  for slots, page in family.pools(bs))
+    i32 = jnp.int32
+
+    def step(params, pools, h, pos, blk, off, lens, tables, tables_s, c):
+        cos, sin = family.rope_tables()
+        return family.run_layers(params, h, pools, None, {
+            "pos": pos, "blk": blk, "off": off, "active": lens > 0,
+            "lens": lens, "tables": tables, "tables_s": tables_s,
+            "B": lanes, "G": 1, "block": bs, "cos": cos, "sin": sin,
+            "chunk_slot": c[0] if chunk else None,
+            "chunk_n": c[1] if chunk else None})
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        # the engine's own choice on a chip: the Pallas attention kernel
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            lowered = jax.jit(step, donate_argnums=(1,)).lower(
+                params, pools, sds((t, d), cfg.jax_dtype), sds((t,), i32),
+                sds((t,), i32), sds((t,), i32), sds((t,), i32),
+                sds((t, bps), i32), sds((lanes, bps), i32), sds((2,), i32))
+        compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    layers = params["layers"]
+    assert "wqkv" in layers and "wq" not in layers
+    weights = {(n,) + tuple(dims)
+               for w in (layers["wqkv"], layers["wo"])
+               for n in (1, w.shape[0])
+               for dims in (w.shape[1:], w.shape[:0:-1])}
+    # the loaded tree's, which the parent relaid
+    weights |= {(n, d, width) for n in (1, 2)
+                for width in (hq * cfg.head_dim, hkv * cfg.head_dim)}
+    copied = {tuple(int(v) for v in m.split(","))
+              for m in re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)}
+    assert not copied & weights, copied & weights
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < layers["wo"].size * layers["wo"].dtype.itemsize

@@ -12,7 +12,11 @@
 - what the engine derives from a family is pinned to the values the
   engine before the seam held (pool shapes, a page's bytes, the chain
   salt that DFS-persisted prefixes are keyed by, the read-back's width,
-  ``weight_plane()``'s keys).
+  ``weight_plane()``'s keys);
+- the dense paged family places its tree once, at construction
+  (``place_weights``: ``wq``, ``wk``, ``wv`` joined into ``wqkv``, in
+  their place): the same tokens, bytes and pool as over the tree as
+  loaded, which the planes that read the projections by name keep.
 """
 
 import dataclasses
@@ -25,12 +29,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hadoop_tpu.models import decoder, deepseek, ouro
+from hadoop_tpu.models import decoder, deepseek, init_params_for, ouro
 from hadoop_tpu.models.config import PRESETS, get_config
 from hadoop_tpu.serving import engine as engine_mod
 from hadoop_tpu.serving import families
 from hadoop_tpu.serving.engine import DecodeEngine, SamplingParams
+from hadoop_tpu.serving.families.gqa import QKV, PagedKVFamily
 from hadoop_tpu.serving.metrics import ServingMetrics
+from hadoop_tpu.serving.weightplane import resident_weight_bytes
 
 
 def test_engine_source_names_no_family():
@@ -242,3 +248,146 @@ def test_what_the_engine_derives_is_what_it_held_before(name):
         + len(eng._family.counters)
     assert set(eng.weight_plane()) == PLANE_KEYS | extra
     eng.stop()
+
+
+# ------------------------------------- the projections, placed at load
+
+def loaded_engine(params, cfg, **kw):
+    """An engine over the tree as loaded: three matmuls a layer."""
+    with mock.patch.object(PagedKVFamily, "place_weights",
+                           families.Family.place_weights):
+        return DecodeEngine(params, cfg, **kw)
+
+
+def copy_of(tree):
+    return jax.tree_util.tree_map(jnp.copy, tree)
+
+
+# repeats, so that the n-gram lane has drafts to propose
+PROMPTS = [[5, 9, 2, 5, 9, 2, 5, 9, 2, 5, 9], [17, 3, 200, 41, 8]]
+KW = dict(max_batch=2, block_size=4, max_context=64, prefill_chunk=8)
+
+
+def served(eng, max_new=10):
+    try:
+        return eng.generate(PROMPTS, SamplingParams(max_new_tokens=max_new))
+    finally:
+        eng.stop()
+
+
+def reference_greedy(fwd, params, prompt, max_new):
+    seq = list(prompt)
+    for _ in range(max_new):
+        logits = fwd(params, jnp.asarray([seq + [0] * (32 - len(seq))]))
+        seq.append(int(jnp.argmax(logits[0, len(seq) - 1])))
+    return seq[len(prompt):]
+
+
+@pytest.mark.parametrize("name,spec_k", [
+    ("tiny", 0), ("tiny", 2), ("tiny-gpt2", 0), ("tiny-gpt2", 2),
+    ("tiny-moe", 0), ("tiny-moe", 2), ("tiny-ouro", 0)])
+def test_the_placed_engine_serves_what_the_loaded_tree_serves(name, spec_k):
+    cfg = get_config(name)
+    params = init_params_for(cfg)(jax.random.PRNGKey(11), cfg)
+    placed = DecodeEngine(params, cfg, speculate_k=spec_k, **KW)
+    loaded = loaded_engine(params, cfg, speculate_k=spec_k, **KW)
+    layers = placed.params["layers"]
+    assert not set(QKV) & set(layers)
+    assert set(QKV) <= set(loaded.params["layers"])
+    np.testing.assert_array_equal(
+        np.asarray(layers["wqkv"]),
+        np.concatenate([np.asarray(params["layers"][k]) for k in QKV], -1))
+    # in their place, not beside them: the tree's bytes did not change
+    assert placed.weight_bytes == loaded.weight_bytes \
+        == resident_weight_bytes(placed.params)
+    assert placed._weight_desc == loaded._weight_desc
+    assert placed.weight_plane() == loaded.weight_plane()
+    assert placed.pool.num_blocks == loaded.pool.num_blocks
+    # no budget was given: the caller's tree is still the caller's
+    assert not any(params["layers"][k].is_deleted() for k in QKV)
+    out = served(placed)
+    assert out == served(loaded)
+    if spec_k:
+        assert placed.spec_proposed > 0
+    if name in ("tiny", "tiny-gpt2"):
+        fwd = jax.jit(lambda p, t: decoder.forward(p, t, cfg))
+        assert out == [reference_greedy(fwd, params, p, 10)
+                       for p in PROMPTS]
+
+
+def test_under_a_budget_the_replaced_stacks_are_freed_and_the_pool_is_the_same():
+    """``hbm_bytes`` counts the weights once: the pool is what the tree
+    as loaded leaves room for, and the stacks the joined leaf replaces
+    are freed (jit donation cannot: no output has a stack's shape) before
+    the pools are made, though the caller still names them."""
+    cfg = get_config("tiny")
+    params = decoder.init_params(jax.random.PRNGKey(11), cfg)
+    kw = dict(block_size=4, max_context=64, prefill_chunk=8)
+    probe = loaded_engine(params, cfg, **kw)
+    budget = probe.weight_bytes + 40 * probe.block_nbytes + 17
+    probe.stop()
+    mine, theirs = copy_of(params), copy_of(params)
+    placed = DecodeEngine(mine, cfg, hbm_bytes=budget, **kw)
+    loaded = loaded_engine(theirs, cfg, hbm_bytes=budget, **kw)
+    assert all(mine["layers"][k].is_deleted() for k in QKV)
+    assert not any(leaf.is_deleted() for k, leaf in mine["layers"].items()
+                   if k not in QKV)
+    assert not any(theirs["layers"][k].is_deleted() for k in QKV)
+    assert placed.pool.num_blocks == loaded.pool.num_blocks == 40
+    assert placed.max_batch == loaded.max_batch
+    assert placed.weight_plane() == loaded.weight_plane()
+    assert resident_weight_bytes(placed.params) == placed.weight_bytes
+    assert served(placed) == served(loaded)
+    # a budget the weights overflow is refused before the tree is touched
+    with pytest.raises(ValueError, match="hbm"):
+        DecodeEngine(params, cfg, hbm_bytes=probe.weight_bytes, **kw)
+    assert not any(params["layers"][k].is_deleted() for k in QKV)
+
+
+@pytest.mark.parametrize("name", ["tiny", "tiny-ouro"])
+def test_a_checkpoint_through_the_loader_is_placed_and_serves_the_same(
+        tmp_path, name):
+    from hadoop_tpu.fs import LocalFileSystem
+    from hadoop_tpu.parallel.checkpoint import save_checkpoint
+    from hadoop_tpu.serving.loader import load_serving_params
+    cfg = get_config(name)
+    params = init_params_for(cfg)(jax.random.PRNGKey(3), cfg)
+    fs = LocalFileSystem()
+    save_checkpoint(fs, f"{tmp_path}/m", 2, {"params": params})
+    got, _ = load_serving_params(fs, f"{tmp_path}/m", cfg)
+    eng = DecodeEngine(got, cfg, **KW)
+    assert "wqkv" in eng.params["layers"]
+    assert served(eng) == served(loaded_engine(params, cfg, **KW))
+
+
+def _relaxed(params, cfg):
+    from hadoop_tpu.serving import weightplane as wp
+    policy = wp.WeightPlaneConfig(tier="relaxed", group=16)
+    qparams, _ = wp.quantize_params(params, cfg, policy)
+    return DecodeEngine(qparams, cfg, **KW), \
+        DecodeEngine(wp.dequantize_params(qparams, cfg), cfg, **KW)
+
+
+def _tp(params, cfg):
+    from hadoop_tpu.parallel.mesh import MeshPlan
+    return DecodeEngine(params, cfg, plan=MeshPlan(tp=2), **KW), \
+        DecodeEngine(params, cfg, **KW)
+
+
+@pytest.mark.parametrize("plane", [_relaxed, _tp])
+def test_a_plane_that_reads_the_projections_by_name_keeps_the_loaded_tree(
+        plane):
+    """int8 scale groups (``weightplane`` quantizes ``wq`` by name) and a
+    tp cut along N (``parallel.mesh.param_specs``): today's three
+    matmuls over the tree as loaded, and the same tokens as the placed
+    engine over the same values. (The long-context plane reads a tree of
+    its own — the engine's only under ``serving.parity=relaxed``, where
+    it is the loaded one: tests/test_longctx.py serves through placed
+    engines.)"""
+    cfg = get_config("tiny")
+    params = decoder.init_params(jax.random.PRNGKey(11), cfg)
+    eng, placed = plane(params, cfg)
+    assert set(QKV) <= set(eng.params["layers"])
+    assert "wqkv" not in eng.params["layers"]
+    assert "wqkv" in placed.params["layers"]
+    assert served(eng) == served(placed)

@@ -217,8 +217,10 @@ def test_hbm_budget_converts_weight_bytes_into_lanes(tiny_model):
     bnb = 2 * cfg.n_layers * bs * cfg.n_kv_heads * cfg.head_dim * 4
     budget = wp.resident_weight_bytes(params) + \
         (2 * (mc // bs) + 2) * bnb
-    e32 = DecodeEngine(params, cfg, block_size=bs, max_context=mc,
-                       hbm_bytes=budget)
+    # under a budget the engine takes the tree for its own (the stacks
+    # it re-places are freed): the shared fixture hands over a copy
+    e32 = DecodeEngine(jax.tree_util.tree_map(jnp.copy, params), cfg,
+                       block_size=bs, max_context=mc, hbm_bytes=budget)
     e8 = DecodeEngine(qp, cfg, block_size=bs, max_context=mc,
                       hbm_bytes=budget)
     assert e32.max_batch == 2
