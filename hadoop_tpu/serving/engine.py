@@ -180,10 +180,18 @@ from hadoop_tpu.serving.weightplane import (describe_tree,
                                             qhead, qrows)
 from hadoop_tpu.tracing.tracer import (current_context, global_tracer,
                                        phase)
+from hadoop_tpu.util.misc import PauseMonitor
 
 log = logging.getLogger(__name__)
 
 _NEG_INF = -1e30
+
+# The process's stall witness (util.misc.PauseMonitor) as the engine runs
+# it: a tick every 0.1 s, and a tick later than the threshold is a stall.
+# The threshold sits above every oversleep of the chip host's sound runs
+# (PERF.md §6, PR 37) and under iteration_seconds' 0.256 s bucket.
+STALL_TICK_S = 0.1
+STALL_THRESHOLD_S = 0.2
 
 # the phases of one scheduler iteration. They TILE it — nothing encloses
 # them — so that on a profiler trace each idle gap of the device falls in
@@ -1953,6 +1961,12 @@ class DecodeEngine:
         self._thread = threading.Thread(target=self._run_loop,
                                         name="decode-engine", daemon=True)
         self._thread.start()
+        # the process's stall witness (one a process, shared): it reads
+        # this thread's schedstat and ``phase_s``; the loop tells it
+        # nothing
+        PauseMonitor.watch_process(
+            self, self._thread, self.phase_s, self.metrics,
+            threshold_s=STALL_THRESHOLD_S, interval_s=STALL_TICK_S)
 
     def stop(self, drain: bool = False, timeout: float = 30.0) -> None:
         """``drain=True``: keep decoding until every queued and running
@@ -1986,6 +2000,7 @@ class DecodeEngine:
         if self._thread is not None:
             self._thread.join(timeout=timeout)
             self._thread = None
+        PauseMonitor.unwatch_process(self)
         # only touch slot/pool state under the scheduler lock — a step
         # still stuck in compilation past the join timeout must not race
         # a double-free of its KV pages; if the lock can't be had the

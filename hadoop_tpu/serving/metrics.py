@@ -112,8 +112,36 @@ class ServingMetrics:
     - ``iteration_seconds``  histogram of the time between the starts
                              of two consecutive device steps of a busy
                              engine (waiting for work and compiles left
-                             out) — a stall of the whole process shows
-                             here, where ``decode_step`` cannot see it
+                             out) — a stall of the loop or of the whole
+                             process shows here, where ``decode_step``
+                             cannot see it; the second kind is measured
+                             and named by ``process_stalled_seconds``
+    - ``process_stalls`` / ``process_stalled_seconds`` /
+      ``process_stalled_seconds{cause=suspended|gc|throttled|
+      cpu_starved|memory|io|gil|frozen|unknown}``  freezes of the whole
+                             process as ``util.misc.PauseMonitor`` saw
+                             them (a tick of its sleeper later than the
+                             threshold), their seconds, and the seconds
+                             by what the record says froze it (one prom
+                             family, ``htpu_serving_engine_process_
+                             stalled_seconds_total``); 0 in a sound run
+    - ``process_tick_oversleep_seconds``  histogram of how late every
+                             tick of that sleeper woke: the threshold
+                             is chosen over its tail
+    - ``process_gc_seconds`` / ``engine_thread_run_delay_seconds`` /
+      ``process_cpu_throttled_seconds`` /
+      ``process_pressure_seconds{resource=cpu|memory|io}`` /
+      ``process_major_faults`` / ``host_steal_seconds``  what the
+                             monitor reads every tick, stalled or not,
+                             so that a run that is slow THROUGHOUT shows
+                             too: seconds inside full collections; the
+                             scheduler thread runnable with no CPU; the
+                             cgroup's CPU quota throttling it; some task
+                             of the host stalled on the resource
+                             (``/proc/pressure`` ``some``); faults that
+                             went to the disk; the hypervisor's steal
+                             (mean of one CPU). A counter whose source
+                             the machine lacks stays 0
     - ``ttft_stage_seconds{stage=queue|prefill_wait|prefill}``  the
                              three intervals that sum to each request's
                              time to first token: submitted → admitted
@@ -171,6 +199,47 @@ class ServingMetrics:
         self.iteration_hist = reg.histogram(
             "iteration_seconds",
             "start of one device step to the next, of a busy engine")
+        # the process under the loop (util.misc.PauseMonitor, started by
+        # DecodeEngine.start): stalls by cause, every tick's oversleep,
+        # and what the OS and the collector charge the process tick by
+        # tick. The label sets are those of misc.CAUSES and of
+        # /proc/pressure, inline for the label lint.
+        self.process_stalls = reg.counter(
+            "process_stalls", "freezes of the whole process")
+        self.process_stalled_seconds = reg.counter(
+            "process_stalled_seconds", "seconds the whole process froze")
+        self.process_stalled_by_cause = {
+            cause: reg.counter(
+                f"process_stalled_seconds_{cause}",
+                "seconds the whole process froze, by what froze it",
+                prom_name="serving_engine_process_stalled_seconds",
+                prom_labels={"cause": cause})
+            for cause in ("suspended", "gc", "throttled", "cpu_starved",
+                          "memory", "io", "gil", "frozen", "unknown")}
+        self.process_tick_oversleep_hist = reg.histogram(
+            "process_tick_oversleep_seconds",
+            "how late each tick of the pause monitor's sleeper woke")
+        # (the monitor's key for the gain, the counter it feeds)
+        self._process_ticks = [
+            ("gc_s", reg.counter(
+                "process_gc_seconds", "seconds inside full collections")),
+            ("engine_run_delay_s", reg.counter(
+                "engine_thread_run_delay_seconds",
+                "scheduler thread runnable and given no CPU")),
+            ("throttled_s", reg.counter(
+                "process_cpu_throttled_seconds",
+                "the cgroup's CPU quota throttled the process")),
+            ("major_faults", reg.counter(
+                "process_major_faults", "page faults that went to disk")),
+            ("steal_s", reg.counter(
+                "host_steal_seconds",
+                "the hypervisor's steal, mean of one CPU")),
+        ] + [(f"{res}_some_s", reg.counter(
+            f"process_pressure_seconds_{res}",
+            "some task of the host stalled on the resource",
+            prom_name="serving_engine_process_pressure_seconds",
+            prom_labels={"resource": res}))
+            for res in ("cpu", "memory", "io")]
         self.ttft_stage_hist = {
             stage: reg.histogram(
                 f"ttft_stage_seconds_{stage}",
@@ -350,6 +419,21 @@ class ServingMetrics:
         self.longctx_prefill_hist = reg.histogram(
             "longctx_prefill_seconds",
             "context-parallel prefill wall time per prompt")
+
+    # util.misc.PauseMonitor's sink (two ServingMetrics over one
+    # registry hold the same counters: the monitor feeds them as one)
+
+    def process_tick(self, oversleep_s: float, gains: dict) -> None:
+        self.process_tick_oversleep_hist.add(max(0.0, oversleep_s))
+        for key, counter in self._process_ticks:
+            if gains.get(key):
+                counter.incr(gains[key])
+
+    def process_stall(self, record: dict) -> None:
+        self.process_stalls.incr()
+        self.process_stalled_seconds.incr(record["seconds"])
+        self.process_stalled_by_cause[record["cause"]].incr(
+            record["seconds"])
 
     def snapshot(self):
         return self.registry.snapshot()

@@ -114,9 +114,11 @@ class Span:
         self.finish()
         return False
 
-    def finish(self) -> None:
+    def finish(self, end: Optional[float] = None) -> None:
+        """``end``: for a span written after the fact (a stall is known
+        only once it is over), its end on ``time.time()``'s clock."""
         if self.end is None:
-            self.end = time.time()
+            self.end = time.time() if end is None else end
             if self._token is not None:
                 _active.reset(self._token)
                 self._token = None
