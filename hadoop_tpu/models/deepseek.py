@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from hadoop_tpu.models.config import ModelConfig
-from hadoop_tpu.models.moe import moe_share
+from hadoop_tpu.models.moe import moe_share, split_experts
 from hadoop_tpu.ops import layer_norm, rms_norm, swiglu
 from hadoop_tpu.ops.rope import yarn_frequencies, yarn_mscale
 from hadoop_tpu.ops.sparse_mla import sparse_mla_attention
@@ -219,11 +219,17 @@ def run_layers(params, h, lat_pool, idx_pool, cfg: ModelConfig, rows):
     idx = idx_pool.reshape((-1,) + shape_idx[2:])
     eps = cfg.norm_eps
 
-    def dense_mlp(x, lp):
+    n_dense = cfg.n_dense_layers
+
+    def dense_mlp(x, lp, layer):
         return swiglu(x @ lp["w_gate"], x @ lp["w_up"]) @ lp["w_down"], 0
 
-    def expert_mlp(x, lp):
-        return moe_share(x, lp, cfg, valid=rows["active"])
+    if n_layers > n_dense:
+        experts, moe_layers = split_experts(params["moe_layers"])
+
+    def expert_mlp(x, lp, layer):
+        return moe_share(x, {**lp, **experts}, cfg, valid=rows["active"],
+                         layer=layer - n_dense)
 
     def body(scope, ffn):
         def one_layer(carry, xs):
@@ -232,12 +238,11 @@ def run_layers(params, h, lat_pool, idx_pool, cfg: ModelConfig, rows):
             h, lat, idx = _attention(h, lp, cfg, lat, idx, layer * n_blocks,
                                      rows)
             with jax.named_scope(scope):
-                y, st = ffn(rms_norm(h, lp["mlp_norm_w"], eps), lp)
+                y, st = ffn(rms_norm(h, lp["mlp_norm_w"], eps), lp, layer)
                 return (h + y.astype(h.dtype), lat, idx, stats + st), None
         return one_layer
 
     carry = (h, lat, idx, jnp.zeros((2,), jnp.int32))
-    n_dense = cfg.n_dense_layers
     if n_dense:
         carry, _ = jax.lax.scan(
             body("mlp", dense_mlp), carry, (params["dense_layers"],
@@ -245,7 +250,6 @@ def run_layers(params, h, lat_pool, idx_pool, cfg: ModelConfig, rows):
     if n_layers > n_dense:
         carry, _ = jax.lax.scan(
             body("moe", expert_mlp), carry,
-            (params["moe_layers"],
-             jnp.arange(n_dense, n_layers, dtype=jnp.int32)))
+            (moe_layers, jnp.arange(n_dense, n_layers, dtype=jnp.int32)))
     h, lat, idx, stats = carry
     return h, lat.reshape(shape_lat), idx.reshape(shape_idx), stats

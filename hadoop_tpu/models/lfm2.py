@@ -50,7 +50,7 @@ import jax.numpy as jnp
 
 from hadoop_tpu.models.config import ModelConfig
 from hadoop_tpu.models.deepseek import _rope_rows
-from hadoop_tpu.models.moe import moe_share
+from hadoop_tpu.models.moe import moe_share, split_experts
 from hadoop_tpu.ops import rms_norm, swiglu
 from hadoop_tpu.ops.paged_attention import paged_attention_packed
 
@@ -243,14 +243,12 @@ def run_layers(params, h, pools, lane, cfg: ModelConfig, rows):
 
     def dense_ffn(x, lp):
         with jax.named_scope("mlp"):
-            return swiglu(x @ lp["w_gate"], x @ lp["w_up"]) @ lp["w_down"], 0
-
-    def expert_ffn(x, lp):
-        return moe_share(x, lp, cfg, valid=rows["active"], busiest=True)
+            return swiglu(x @ lp["w_gate"], x @ lp["w_up"]) @ lp["w_down"]
 
     def body(op, ffn):
         ops, ffns = params[OP_STACKS[op]], params[FFN_STACKS[ffn]]
-        feed = dense_ffn if ffn == "dense" else expert_ffn
+        if ffn == "moe":
+            experts, ffns = split_experts(ffns)
 
         def one_layer(carry, xs):
             h, kc, vc, tail, lane, stats = carry
@@ -263,11 +261,16 @@ def run_layers(params, h, pools, lane, cfg: ModelConfig, rows):
                 h, kc, vc = _attention(h, lp, cfg, kc, vc, oi * n_blocks,
                                        rows)
             lp = jax.tree_util.tree_map(lambda a: a[fi], ffns)
-            y, st = feed(rms_norm(h, lp["ffn_norm_w"], eps), lp)
+            x = rms_norm(h, lp["ffn_norm_w"], eps)
             if ffn == "moe":
+                y, st = moe_share(x, {**lp, **experts}, cfg,
+                                  valid=rows["active"], busiest=True,
+                                  layer=fi)
                 # assignments and experts hit add up over layers; the
                 # busiest expert's rows too (a sum of per-layer maxima)
                 stats = stats + st
+            else:
+                y = dense_ffn(x, lp)
             return (h + y.astype(h.dtype), kc, vc, tail, lane, stats), None
         return one_layer
 

@@ -286,28 +286,17 @@ STEP_SHAPES = {
 }
 
 
-@pytest.mark.parametrize("chunk", [0, 64], ids=["decode", "fused"])
-@pytest.mark.parametrize("model", sorted(STEP_SHAPES))
-def test_the_step_relays_no_projection_weight_on_v5e(one_chip, model, chunk):
-    """The family's layers over abstract arguments, the tree placed as
-    the engine places it (``place_weights``), compiled for the described
-    chip: no ``copy`` of a stacked or sliced projection weight, and fewer
-    temporaries than one stack. As loaded, ``(x @ wq).reshape(t, hq,
-    dh)`` wants ``wq`` K-minor: the looped family's step relaid three
-    whole stacks a step (hoisted out of both loops, their results
-    temporaries), the plain one a layer's ``wq``, ``wk``, ``wv`` in VMEM
-    in every layer."""
+def _compile_layers_for(one_chip, cfg, lanes, chunk, pages, context):
+    """(abstract params as the engine places them, the family's
+    ``run_layers`` over ``lanes + chunk`` abstract rows compiled for the
+    described chip): the engine's own choices on a chip — the Pallas
+    kernels, the pools donated."""
     from jax.experimental.compilation_cache import compilation_cache
 
     from hadoop_tpu.models import init_params_for
     from hadoop_tpu.serving import families
-    fam, d, hq, hkv, dff, passes, pages = STEP_SHAPES[model]
-    base = get_config("tiny-ouro" if fam == "ouro" else "tiny")
-    cfg = dataclasses.replace(
-        base, d_model=d, n_heads=hq, n_kv_heads=hkv, d_ff=dff, n_layers=2,
-        n_passes=passes, max_seq=2048, dtype="bfloat16")
     family = families.family_for(cfg, {})
-    lanes, bs, bps = 16, 16, 2048 // 16
+    bs, bps = 16, context // 16
     t = lanes + chunk
 
     def sds(shape, dt):
@@ -321,29 +310,54 @@ def test_the_step_relays_no_projection_weight_on_v5e(one_chip, model, chunk):
         jax.random.PRNGKey(0)))
     pools = tuple(sds((slots, pages) + tuple(page), cfg.jax_dtype)
                   for slots, page in family.pools(bs))
+    lane = family.lane_state(lanes)
+    lane = None if lane is None else sds(lane, cfg.jax_dtype)
     i32 = jnp.int32
 
-    def step(params, pools, h, pos, blk, off, lens, tables, tables_s, c):
+    def step(params, pools, lane, h, pos, blk, off, lens, tables, tables_s,
+             c):
         cos, sin = family.rope_tables()
-        return family.run_layers(params, h, pools, None, {
+        return family.run_layers(params, h, pools, lane, {
             "pos": pos, "blk": blk, "off": off, "active": lens > 0,
             "lens": lens, "tables": tables, "tables_s": tables_s,
             "B": lanes, "G": 1, "block": bs, "cos": cos, "sin": sin,
             "chunk_slot": c[0] if chunk else None,
             "chunk_n": c[1] if chunk else None})
 
+    # what is compiled for a described chip cannot be read back here
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        # the engine's own choice on a chip: the Pallas attention kernel
         with mock.patch.object(jax, "default_backend", lambda: "tpu"):
             lowered = jax.jit(step, donate_argnums=(1,)).lower(
-                params, pools, sds((t, d), cfg.jax_dtype), sds((t,), i32),
+                params, pools, lane, sds((t, cfg.d_model), cfg.jax_dtype),
                 sds((t,), i32), sds((t,), i32), sds((t,), i32),
-                sds((t, bps), i32), sds((lanes, bps), i32), sds((2,), i32))
+                sds((t,), i32), sds((t, bps), i32), sds((lanes, bps), i32),
+                sds((2,), i32))
         compiled = lowered.compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
+    return params, compiled
+
+
+@pytest.mark.parametrize("chunk", [0, 64], ids=["decode", "fused"])
+@pytest.mark.parametrize("model", sorted(STEP_SHAPES))
+def test_the_step_relays_no_projection_weight_on_v5e(one_chip, model, chunk):
+    """The family's layers over abstract arguments, the tree placed as
+    the engine places it (``place_weights``), compiled for the described
+    chip: no ``copy`` of a stacked or sliced projection weight, and fewer
+    temporaries than one stack. As loaded, ``(x @ wq).reshape(t, hq,
+    dh)`` wants ``wq`` K-minor: the looped family's step relaid three
+    whole stacks a step (hoisted out of both loops, their results
+    temporaries), the plain one a layer's ``wq``, ``wk``, ``wv`` in VMEM
+    in every layer."""
+    fam, d, hq, hkv, dff, passes, pages = STEP_SHAPES[model]
+    base = get_config("tiny-ouro" if fam == "ouro" else "tiny")
+    cfg = dataclasses.replace(
+        base, d_model=d, n_heads=hq, n_kv_heads=hkv, d_ff=dff, n_layers=2,
+        n_passes=passes, max_seq=2048, dtype="bfloat16")
+    params, compiled = _compile_layers_for(one_chip, cfg, 16, chunk, pages,
+                                           2048)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     layers = params["layers"]
@@ -360,3 +374,58 @@ def test_the_step_relays_no_projection_weight_on_v5e(one_chip, model, chunk):
     assert not copied & weights, copied & weights
     assert compiled.memory_analysis().temp_size_in_bytes \
         < layers["wo"].size * layers["wo"].dtype.itemsize
+
+
+# the two held-expert cells' layers at their published widths, two expert
+# layers and no dense one: (preset, the widths, lanes, chunk, pool pages,
+# context, the most temporaries in MB). One leaf of a layer's experts is
+# 403 MB in the agent cell and 470 MB in longdoc; longdoc's attention
+# makes 137 (decode-only) / 259 MB (fused) of its own, with or without
+# experts (the index scores, the gathered latents, ``wo``)
+EXPERT_STEP_SHAPES = {
+    "lfm2-24b-a2b": ("tiny-lfm2", dict(
+        d_model=2048, n_heads=32, n_kv_heads=8, d_ff=11776, n_experts=64,
+        n_routed_experts=64, top_k=4, d_ff_expert=1536,
+        layer_types=("full_attention", "conv")), 64, 128, 1024, 8192, 64),
+    "deepseek-v3.2": ("tiny-dsv32", dict(
+        d_model=7168, n_heads=128, d_ff=18432, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, index_n_heads=64, index_head_dim=128,
+        index_topk=2048, n_experts=16, n_routed_experts=256, top_k=8,
+        n_group=8, topk_group=4, d_ff_expert=2048,
+        rope_original_max_seq=4096), 32, 256, 1024, 36864, 259 + 64),
+}
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["decode", "fused"])
+@pytest.mark.parametrize("model", sorted(EXPERT_STEP_SHAPES))
+def test_the_step_copies_no_expert_stack_on_v5e(one_chip, model, fused):
+    """Both held-expert families' ``run_layers`` over abstract arguments
+    at the cells' widths (64 / 192 and 32 / 288 rows), compiled for the
+    described chip: the grouped kernel is there, no ``copy`` or
+    ``dynamic-slice`` makes an expert stack or one layer's experts, and
+    the temporaries stay under one layer's leaf. A layer's experts
+    sliced from the stack and handed to the kernel are materialised:
+    three such copies a layer."""
+    preset, widths, lanes, chunk, pages, context, temp_mb = \
+        EXPERT_STEP_SHAPES[model]
+    cfg = dataclasses.replace(
+        get_config(preset), n_layers=2, n_dense_layers=0, max_seq=context,
+        dtype="bfloat16", **widths)
+    params, compiled = _compile_layers_for(
+        one_chip, cfg, lanes, chunk if fused else 0, pages, context)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    experts = params["moe_layers"]
+    assert experts["w_gate"].shape == (2, cfg.n_experts, cfg.d_model,
+                                       cfg.d_ff_expert)
+    stacks = {tuple(lead) + tuple(dims)
+              for shape in (experts["w_gate"].shape, experts["w_down"].shape)
+              for lead in ((2, shape[1]), (2 * shape[1],), (1, shape[1]),
+                           (shape[1],))
+              for dims in (shape[2:], shape[:1:-1])}
+    made = {tuple(int(v) for v in m.split(","))
+            for m in re.findall(
+                r"= \w+\[([\d,]+)\]\S* (?:copy|dynamic-slice)\(", text)}
+    assert not made & stacks, made & stacks
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_mb << 20
