@@ -15,10 +15,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 REHEARSAL = "chipbench/tests/rehearsal/BENCHMARK.json"
 # read from the engine's own record: window deltas of its phases, its TTFT
 # stages, its iteration histogram and two counters of its registry
-ENGINE = {"engine.queue_wait_ms.chat", "engine.prefill_wait_ms.chat",
-          "engine.prefill_service_ms.chat", "engine.step_busy_ms.chat",
-          "engine.host_ms_per_step.chat", "engine.iter_max_ms.chat",
-          "engine.attn_live_page_share.chat"}
+ENGINE = {"engine.queue_wait_ms", "engine.prefill_wait_ms",
+          "engine.prefill_service_ms", "engine.step_busy_ms",
+          "engine.host_ms_per_step", "engine.iter_max_ms",
+          "engine.attn_live_page_share"}
 
 
 def run_cell(workload, *more, cwd=ROOT, seed=3000000007):
@@ -59,11 +59,11 @@ def test_rehearsal_runs_end_to_end_and_names_the_cpu(workload, trace):
         assert all(got[k] >= 0 for k in ENGINE)
         # host time is part of busy time; the longest iteration reads as
         # the upper bound of its bucket; live pages are some of all pages
-        assert got["engine.host_ms_per_step.chat"] \
-            <= got["engine.step_busy_ms.chat"]
-        assert got["engine.iter_max_ms.chat"] in [
+        assert got["engine.host_ms_per_step"] \
+            <= got["engine.step_busy_ms"]
+        assert got["engine.iter_max_ms"] in [
             0.25 * 2 ** i for i in range(20)]
-        assert 0 < got["engine.attn_live_page_share.chat"] <= 100
+        assert 0 < got["engine.attn_live_page_share"] <= 100
     for name, c in line["check"].items():
         assert c["value"] <= c["limit"], name
     assert p.stderr.strip().splitlines()[-1].startswith("correct=True")

@@ -8,10 +8,10 @@ import pytest
 from chipbench import harness, readers
 from chipbench.serve_cell import STAGES, _counters, window_deltas
 
-ENGINE = ["engine.queue_wait_ms.chat", "engine.prefill_wait_ms.chat",
-          "engine.prefill_service_ms.chat", "engine.step_busy_ms.chat",
-          "engine.host_ms_per_step.chat", "engine.iter_max_ms.chat",
-          "engine.attn_live_page_share.chat"]
+ENGINE = ["engine.queue_wait_ms", "engine.prefill_wait_ms",
+          "engine.prefill_service_ms", "engine.step_busy_ms",
+          "engine.host_ms_per_step", "engine.iter_max_ms",
+          "engine.attn_live_page_share"]
 
 
 class _Hist:
@@ -85,13 +85,13 @@ def test_window_deltas_of_a_hand_made_record():
     m.iteration_hist.counts[6] += 1         # after the window closed
     obs = dict(window_deltas(a, b), steps=100)
     assert _read(obs) == pytest.approx({
-        "engine.queue_wait_ms.chat": 125.0,
-        "engine.prefill_wait_ms.chat": 500.0,
-        "engine.prefill_service_ms.chat": 1500.0,
-        "engine.step_busy_ms.chat": 430.0,
-        "engine.host_ms_per_step.chat": 30.0,
-        "engine.iter_max_ms.chat": 2048.0,
-        "engine.attn_live_page_share.chat": 8.0})
+        "engine.queue_wait_ms": 125.0,
+        "engine.prefill_wait_ms": 500.0,
+        "engine.prefill_service_ms": 1500.0,
+        "engine.step_busy_ms": 430.0,
+        "engine.host_ms_per_step": 30.0,
+        "engine.iter_max_ms": 2048.0,
+        "engine.attn_live_page_share": 8.0})
     assert obs["phase_window_s"]["engine.wait"] == 2.0
     # every number of the registry that is one at both ends, by its name;
     # nothing for a flag, a label, or a name one end lacks

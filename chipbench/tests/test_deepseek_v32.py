@@ -68,10 +68,10 @@ def test_the_rehearsed_cell_prints_its_line():
     assert line["correct"] and line["failed"] == 0 and line["rehearsal"]
     got = {k: v["value"] for k, v in line["metrics"].items()}
     # a CPU speaks only under the names of program counters
-    assert 0 < got["engine.dsa_selected_share.longdoc"] < 100
-    assert 0 < got["engine.moe_local_share.longdoc"] < 100
-    assert got["compile.in_window.longdoc"] == 0
-    assert got["kv.prefix_hit_share.longdoc"] > 50
+    assert 0 < got["engine.dsa_selected_share"] < 100
+    assert 0 < got["engine.moe_local_share"] < 100
+    assert got["compile.in_window"] == 0
+    assert got["kv.prefix_hit_share"] > 50
     assert not any("roofline" in k or "device" in k for k in got)
 
 

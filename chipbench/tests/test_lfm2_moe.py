@@ -91,11 +91,11 @@ def test_the_rehearsed_cell_prints_its_line():
     assert line["correct"] and line["failed"] == 0 and line["rehearsal"]
     got = {k: v["value"] for k, v in line["metrics"].items()}
     # a CPU speaks only under the names of program counters
-    assert got["kv.state_restore_share.agent"] == 100.0
-    assert got["engine.moe_load_imbalance.agent"] >= 1.0
-    assert got["engine.moe_experts_hit_share.agent"] > 0
-    assert got["compile.in_window.agent"] == 0
-    assert got["kv.prefix_hit_share.agent"] > 50
+    assert got["kv.state_restore_share"] == 100.0
+    assert got["engine.moe_load_imbalance"] >= 1.0
+    assert got["engine.moe_experts_hit_share"] > 0
+    assert got["compile.in_window"] == 0
+    assert got["kv.prefix_hit_share"] > 50
     assert not any("roofline" in k or "device" in k for k in got)
 
 

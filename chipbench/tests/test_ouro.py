@@ -90,11 +90,18 @@ def test_the_rehearsed_cell_prints_its_line():
     assert line["correct"] and line["failed"] == 0 and line["rehearsal"]
     got = {k: v["value"] for k, v in line["metrics"].items()}
     # a CPU speaks only under the names of program counters
-    assert got["engine.loop_passes_per_step.reason"] == 3.0
-    assert 0 < got["kv.pool_fill_share.reason"] < 100
-    assert got["engine.preemptions.reason"] == 0
-    assert got["compile.in_window.reason"] == 0
-    assert got["kv.prefix_hit_share.reason"] > 50
+    assert got["engine.loop_passes_per_step"] == 3.0
+    assert 0 < got["kv.pool_fill_share"] < 100
+    assert got["engine.preemptions"] == 0
+    assert got["compile.in_window"] == 0
+    assert got["kv.prefix_hit_share"] > 50
+    # the quantities this cell shares with the other serving cells' lists
+    assert got["engine.host_ms_per_step"] <= got["engine.step_busy_ms"]
+    assert 0 <= got["engine.run_ahead_share"] <= 100
+    assert got["engine.argmax_only_share"] == 100.0
+    assert got["engine.prefill_wait_ms"] >= 0
+    assert got["engine.iter_max_ms"] > 0
+    assert got["engine.backlog_open"] >= 0 and got["engine.backlog_close"] >= 0
     assert not any("roofline" in k or "device" in k for k in got)
 
 
