@@ -222,9 +222,12 @@ def _attention(h, lp, cfg: ModelConfig, kc, vc, base, rows):
         vc = vc.at[page, rows["off"]].set(
             v.reshape(t, hkv * dh).astype(vc.dtype))
     with jax.named_scope("attn"):
-        # read AFTER the scatter: a chunk row sees the rows before it
+        # read AFTER the scatter: a chunk row sees the rows before it.
+        # The lanes' rows come first, each with its own table; the
+        # chunk's rows after them share their request's
         attn = paged_attention_packed(q, kc, vc, base + rows["tables"],
-                                      rows["lens"], dh ** -0.5)
+                                      rows["lens"], dh ** -0.5,
+                                      lanes=rows["B"] * rows["G"])
     with jax.named_scope("attn_proj"):
         return h + (attn.reshape(t, hq * dh) @ lp["wo"]).astype(h.dtype), \
             kc, vc

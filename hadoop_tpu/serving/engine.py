@@ -1764,9 +1764,11 @@ class DecodeEngine:
         lens = at[:, None] + 1 + j
         lens = lens[j <= self._draft_lens[lanes, None]]
         n_rows = self.max_batch * j.size
+        chunk_lens = np.zeros((0,), np.int64)
         if pre is not None:
-            lens = np.concatenate(
-                [lens, pre._prefill_pos + 1 + np.arange(n_valid)])
+            cj = np.arange(self.prefill_chunk)
+            chunk_lens = np.where(cj < n_valid, pre._prefill_pos + 1 + cj, 0)
+            lens = np.concatenate([lens, chunk_lens[:n_valid]])
             n_rows += self.prefill_chunk
         self.metrics.attn_pages_read.incr(int(np.sum(-(-lens // bs))))
         self.metrics.attn_pages_dense.incr(n_rows * self.blocks_per_seq)
@@ -1776,7 +1778,8 @@ class DecodeEngine:
             self.pool.num_usable - self.pool.num_free)
         self.metrics.kv_pages_pool_steps.incr(self.pool.num_usable)
         self._family.count_step(self.metrics, lens,
-                                self._chains(rows, at, pre, n_valid))
+                                self._chains(rows, at, pre, n_valid),
+                                chunk_lens, (bs, self.blocks_per_seq))
 
     def _chains(self, rows, at, pre: Optional[GenRequest], n_valid: int):
         """(first page, pages held) per live request; lazy."""

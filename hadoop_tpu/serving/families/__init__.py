@@ -120,10 +120,14 @@ class Family:
         is int32 ``[len(counters)]``, or ``()`` for none."""
         raise NotImplementedError
 
-    def count_step(self, metrics, lens, chains: Iterable) -> None:
+    def count_step(self, metrics, lens, chains: Iterable, chunk_lens,
+                   table: Tuple[int, int]) -> None:
         """Once a step, on the host, before dispatch: count what only
-        this family has. ``lens``: the live rows' lengths; ``chains``
-        yields ``(first page, pages held)`` per live request, lazily."""
+        this family has. ``lens``: the live rows' lengths, the lanes'
+        then the prefill chunk's; ``chains`` yields ``(first page, pages
+        held)`` per live request, lazily; ``chunk_lens``: every row of
+        the prefill chunk, 0 for an inactive one (none without a chunk);
+        ``table``: (tokens a page, pages a table)."""
 
     def describe_experts(self, rows: int) -> Dict[str, Any]:
         """What ``weight_plane()`` says of the experts beyond their

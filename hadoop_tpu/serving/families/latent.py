@@ -57,7 +57,7 @@ class LatentFamily(Family):
             params, h, *pools, self.cfg, {**rows, "groups": groups})
         return h, (kp, vp), lane, stats
 
-    def count_step(self, metrics, lens, chains) -> None:
+    def count_step(self, metrics, lens, chains, chunk_lens, table) -> None:
         # the sparse selection: entries the live rows could attend to
         # against the entries they keep (a layer; every layer alike)
         cfg = self.cfg

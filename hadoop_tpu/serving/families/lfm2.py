@@ -11,6 +11,7 @@ import numpy as np
 
 from hadoop_tpu.models import lfm2
 from hadoop_tpu.ops import rope_frequencies
+from hadoop_tpu.ops.paged_attention import packed_pages_fetched
 from hadoop_tpu.serving.families import Family
 
 _NO_PATH = ("the per-kind pools, the pages' state tails and the lanes' "
@@ -70,11 +71,16 @@ class ConvStateFamily(Family):
     def run_layers(self, params, h, pools, lane, rows):
         return lfm2.run_layers(params, h, pools, lane, self.cfg, rows)
 
-    def count_step(self, metrics, lens, chains) -> None:
+    def count_step(self, metrics, lens, chains, chunk_lens, table) -> None:
         cfg = self.cfg
         metrics.moe_assignments.incr(
             int(np.size(lens)) * cfg.top_k
             * (cfg.n_layers - cfg.n_dense_layers))
+        # what the packed kernel moves for the rows (what the walk off
+        # the TPU reads is every row to the longest)
+        lanes = lens[:np.size(lens) - np.count_nonzero(chunk_lens)]
+        metrics.attn_pages_fetched.incr(
+            packed_pages_fetched(lanes, chunk_lens, *table, cfg.n_heads))
 
     def describe_experts(self, rows: int):
         return {"experts_routed": self.cfg.n_routed_experts,

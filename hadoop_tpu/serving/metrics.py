@@ -59,6 +59,13 @@ class ServingMetrics:
                              live rows attended to, against the pages a
                              walk of every row's whole table would have
                              read; their ratio is the live-page share
+    - ``attn_pages_fetched``  (pages whose token keeps its KV heads in one
+                             row) table pages the packed kernel DMAs for
+                             those rows: a lane's whole chunks of pages
+                             up to its own length, a prefill chunk's
+                             once a group of rows; over
+                             ``attn_pages_read`` it is what the kernel
+                             moves for what the rows attend to
     - ``kv_pages_live_steps`` / ``kv_pages_pool_steps``  pages of the
                              pool held at each step (by a lane or by the
                              prefix cache) against the pages it has,
@@ -319,6 +326,9 @@ class ServingMetrics:
         self.attn_pages_dense = reg.counter(
             "attn_pages_dense",
             "KV pages a walk of every row's whole block table would read")
+        self.attn_pages_fetched = reg.counter(
+            "attn_pages_fetched",
+            "table pages the packed attention kernel DMAs for those rows")
         self.kv_pages_live_steps = reg.counter(
             "kv_pages_live_steps",
             "KV pages held (lanes and prefix cache), summed over steps")

@@ -11,6 +11,7 @@ validator.
 """
 
 import dataclasses
+import functools
 from unittest import mock
 
 import jax
@@ -24,6 +25,7 @@ from chipbench.families import lfm2_moe as F
 from hadoop_tpu.models import lfm2
 from hadoop_tpu.models.config import ModelConfig, get_config
 from hadoop_tpu.models.moe import moe_share, route_grouped
+from hadoop_tpu.ops.attention import attention_impl_traces
 from hadoop_tpu.serving.engine import DecodeEngine, SamplingParams
 from hadoop_tpu.serving.metrics import ServingMetrics
 
@@ -63,8 +65,13 @@ def make_engine(**kw):
 def served(tap_logits):
     """One engine serves the logit cases; every row's logits are tapped
     where the layers hand their rows back (``conftest.tap_logits``)."""
+    return tapped(make_engine(), tap_logits)
+
+
+def tapped(eng, tap_logits):
+    """(eng, taps): the engine's rows' logits tapped, and the chunk each
+    compiled step was handed noted in ``eng.chunks_seen``."""
     taps = []
-    eng = make_engine()
     tap_logits(eng, taps)
     step_fn, eng.chunks_seen = eng._step_fn, []
 
@@ -78,13 +85,13 @@ def served(tap_logits):
     return eng, taps
 
 
-def reference_logits(seq):
+def reference_logits(seq, model=MODEL):
     tokens = np.zeros((1, S_REF), np.int32)
     tokens[0, :len(seq)] = seq
-    x = F.hidden_states(MODEL, SEED, tokens)[0, :len(seq)]
-    top = F._top(W.seed_key(SEED), W.freeze(MODEL))
+    x = F.hidden_states(model, SEED, tokens)[0, :len(seq)]
+    top = F._top(W.seed_key(SEED), W.freeze(model))
     h = reference.rms_norm(jnp.asarray(x), top["final_norm_w"],
-                           MODEL["norm_eps"])
+                           model["norm_eps"])
     return np.asarray(reference.mm(h, top["embed"].T))
 
 
@@ -119,9 +126,9 @@ def drive(eng, taps, requests, until=None, got=None):
     return got
 
 
-def check_against_reference(req, rows, tol=TOL):
+def check_against_reference(req, rows, tol=TOL, model=MODEL):
     seq = req.prompt + req.out_tokens
-    ref = reference_logits(seq)
+    ref = reference_logits(seq, model)
     assert rows, "no logits were tapped for the request"
     worst = max(float(np.abs(rows[p] - ref[p]).max()) for p in rows)
     assert worst < tol, worst
@@ -258,6 +265,71 @@ def test_compile_once_counters_and_pools(served):
     plane = eng.weight_plane()
     assert plane["experts"] == 16 and plane["experts_routed"] == 16
     assert plane["expert_bytes"] == 6 * 16 * 3 * 64 * 32 * 4
+
+
+# ------------ (i) the TPU's packed kernel, in the interpreter, serving
+
+# heads the packed kernel tiles: 8 query heads on 4 KV heads of 32, a
+# packed row of 128 lanes, pages of 8 tokens
+WIDE = {**MODEL, "hidden_size": 256, "num_attention_heads": 8,
+        "num_key_value_heads": 4}
+WIDE_BLOCK = 8
+
+
+@pytest.fixture
+def served_by_kernel(tap_logits, monkeypatch):
+    """An engine whose attention layers run ``_packed_kernel`` through
+    Pallas's interpreter in place of the walk a CPU takes — swapped in
+    the family's module, as a TPU backend would choose it — over chunks
+    of two pages, the prefill chunk's 8 rows in groups of 4."""
+    from hadoop_tpu.ops import paged_attention as pa
+    monkeypatch.setattr(lfm2, "paged_attention_packed", functools.partial(
+        pa.paged_attention_packed, impl="flash", interpret=True))
+    monkeypatch.setattr(pa, "CHUNK_TOKENS", 2 * WIDE_BLOCK)
+    monkeypatch.setattr(pa, "GROUP_QUERY_ROWS", 4 * 8)
+    eng = DecodeEngine(
+        make_params(WIDE), F.model_config(WIDE, {"context": 256}),
+        max_batch=LANES, block_size=WIDE_BLOCK, max_context=256,
+        prefill_chunk=CHUNK,
+        metrics=ServingMetrics("serving.test.lfm2.kernel"))
+    return tapped(eng, tap_logits)
+
+
+def test_the_packed_kernel_serves_cold_prefill_then_decode(served_by_kernel):
+    """A prompt that crosses pages, kernel chunks and prefill chunks,
+    then decoding past another page: every row's logits are the
+    reference's, and the step was traced to the kernel."""
+    eng, taps = served_by_kernel
+    before = attention_impl_traces().get("paged_packed_flash", 0)
+    req = submit(eng, tokens(21, 21), 14)
+    rows = drive(eng, taps, [req])[id(req)]
+    assert check_against_reference(req, rows, model=WIDE) >= 21 + 14 - 1
+    # both shapes, each over the two attention layers' scan
+    assert attention_impl_traces()["paged_packed_flash"] > before
+    assert eng.decode_compiles == 1 and eng.prefill_compiles == 1
+
+
+def test_the_packed_kernel_decodes_lanes_beside_a_prefill(served_by_kernel):
+    """Two lanes decode, each to its own length, in the steps that carry
+    a third request's four prefill chunks."""
+    eng, taps = served_by_kernel
+    a, b = submit(eng, tokens(8, 6), 40), submit(eng, tokens(9, 19), 40)
+    got = drive(eng, taps, [a, b],
+                until=lambda: a.out_tokens and b.out_tokens)
+    c = submit(eng, tokens(10, 29), 10)          # four chunks of 8
+    fused = []
+    real = eng._step_fn
+
+    def counting(params, *rest):
+        if rest[-1] is not None:
+            fused.append(int(eng._active.sum()))
+        return real(params, *rest)
+
+    with mock.patch.object(eng, "_step_fn", counting):
+        got = drive(eng, taps, [a, b, c], got=got)
+    assert len(fused) == 4 and min(fused) == 2     # both decoded beside it
+    for req in (a, b, c):
+        check_against_reference(req, got[id(req)], model=WIDE)
 
 
 # --------------------------------------------- (d) run-ahead on and off

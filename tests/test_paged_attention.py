@@ -9,6 +9,7 @@ the portable path as it runs, the Pallas kernel through the interpreter.
 """
 
 import dataclasses
+import functools
 import re
 from unittest import mock
 
@@ -133,6 +134,131 @@ def test_packed_pages_match_the_dense_reference(hkv, n_rep, dh, dtype, bs,
     np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
 
 
+# the packed kernel's shapes, (hkv, n_rep, dh): the agent cell's 32 query
+# heads on 8 KV heads of 64 (a packed row of 512), and the smallest row a
+# tile takes (128 lanes, 8 query heads)
+PACKED_SHAPES = {"cell": (8, 4, 64), "small": (2, 4, 64)}
+PACKED_BS, PACKED_BPS, PACKED_LANES = 16, 6, 10
+
+
+def _packed_case(case, ctok):
+    """(lens, chunk rows' lens or None) of ``case``. Lanes of every
+    length the walk meets: dead, one token, a page's edges, a chunk's
+    edges, the whole table. A prefill chunk of 16 rows of one request,
+    the last 5 inactive: at its prompt's start, and continued across a
+    chunk of pages."""
+    bs, full = PACKED_BS, PACKED_BS * PACKED_BPS
+    lanes = {"lanes": [0, 1, bs - 1, bs, bs + 1, ctok - 1, ctok, ctok + 1,
+                       full, 0],
+             "chunk-start": [0, 5, ctok + 3, 0, full, 1, 0, 2 * bs, 0, 7],
+             "chunk-continued": [bs + 2, 0, 0, 3, 0, full - 1, 0, 0, 0, 0]}
+    start = {"lanes": None, "chunk-start": 0, "chunk-continued": ctok - 6}
+    s0 = start[case]
+    chunk = None if s0 is None else [s0 + 1 + j if j < 11 else 0
+                                     for j in range(16)]
+    return lanes[case], chunk
+
+
+@pytest.mark.parametrize("case", ["lanes", "chunk-start", "chunk-continued"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", sorted(PACKED_SHAPES))
+def test_packed_kernel_reads_each_group_to_its_own_length(shape, dtype, case,
+                                                          monkeypatch):
+    """``_packed_kernel`` through the interpreter against the dense
+    reference and against the portable walk: each lane its own group, a
+    chunk's rows in groups of 4 sharing their request's table (the
+    fourth group all inactive). Then every position past a group's
+    longest length — in its last chunk of pages and in the pages after
+    it — is poisoned with NaN: the kernel reads none of them, so a lane
+    no longer walks to the call's longest row."""
+    from hadoop_tpu.ops import paged_attention as mod
+    bs = PACKED_BS
+    monkeypatch.setattr(mod, "CHUNK_TOKENS", 2 * bs)
+    hkv, n_rep, dh = PACKED_SHAPES[shape]
+    hq, width = hkv * n_rep, hkv * dh
+    monkeypatch.setattr(mod, "GROUP_QUERY_ROWS", 4 * hq)
+    dtype = jnp.dtype(dtype)
+    lane_lens, chunk = _packed_case(case, 2 * bs)
+    # every group its own pages, so that what one may not read is no
+    # other's to read
+    groups = len(lane_lens) + (chunk is not None)
+    pages = 1 + np.random.RandomState(3).permutation(groups * PACKED_BPS)
+    group_tables = pages.reshape(groups, PACKED_BPS).astype(np.int32)
+    tables = group_tables[:len(lane_lens)]
+    lens = np.asarray(lane_lens, np.int32)
+    if chunk is not None:
+        tables = np.concatenate([tables,
+                                 np.repeat(group_tables[-1:], 16, axis=0)])
+        lens = np.concatenate([lens, np.asarray(chunk, np.int32)])
+    t, blocks = len(lens), groups * PACKED_BPS + 1
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(hkv), 3)
+    q = jax.random.normal(kq, (t, hq, dh), jnp.float32).astype(dtype)
+    kc = jax.random.normal(kk, (blocks, bs, hkv, dh),
+                           jnp.float32).astype(dtype)
+    vc = jax.random.normal(kv, (blocks, bs, hkv, dh),
+                           jnp.float32).astype(dtype)
+    scale = dh ** -0.5
+    def run(kc, vc, impl):
+        # a fresh partial a call: traced anew, whatever ran before
+        return jax.jit(functools.partial(
+            paged_attention_packed, scale=scale, lanes=len(lane_lens),
+            impl=impl, interpret=impl == "flash"))(
+                q, kc.reshape(blocks, bs, width),
+                vc.reshape(blocks, bs, width), tables, lens)
+
+    before = attention_impl_traces().get("paged_packed_flash", 0)
+    got = run(kc, vc, "flash")
+    assert attention_impl_traces()["paged_packed_flash"] == before + 1
+    assert got.shape == (t, hq, dh) and got.dtype == dtype
+    walk = run(kc, vc, "ref")
+    got = np.asarray(got.astype(jnp.float32))
+    want = np.asarray(dense_reference(q, kc, vc, tables, lens, scale))
+    assert np.isfinite(got).all()
+    assert not got[lens == 0].any(), "a row with no context reads zeros"
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+    np.testing.assert_allclose(got, np.asarray(walk.astype(jnp.float32)),
+                               atol=tol, rtol=tol)
+    # poison: each group's positions from its longest length on
+    tops = list(lane_lens) + ([max(chunk)] if chunk is not None else [])
+    kn = np.array(kc.astype(jnp.float32))
+    vn = np.array(vc.astype(jnp.float32))
+    for table, top in zip(group_tables, tops):
+        for pos in range(top, PACKED_BPS * bs):
+            kn[table[pos // bs], pos % bs] = np.nan
+            vn[table[pos // bs], pos % bs] = np.nan
+    again = run(jnp.asarray(kn, dtype), jnp.asarray(vn, dtype), "flash")
+    np.testing.assert_array_equal(np.asarray(again.astype(jnp.float32)), got)
+
+
+def test_packed_kernel_is_chosen_by_shape_and_backend():
+    """The walk off the TPU and on a shape the kernel cannot tile; the
+    kernel forced on such a shape says so; the chunk's groups are as
+    large as the query rows allow."""
+    from hadoop_tpu.ops.paged_attention import (group_rows,
+                                                packed_kernel_supported)
+    assert packed_kernel_supported((64, 32, 64), (20480, 16, 512),
+                                   jnp.bfloat16)
+    assert packed_kernel_supported((64, 8, 64), (64, 8, 128), jnp.float32)
+    assert not packed_kernel_supported((64, 8, 64), (64, 8, 128),
+                                       jnp.bfloat16)      # half a tile
+    assert not packed_kernel_supported((4, 4, 16), (9, 4, 32), jnp.float32)
+    assert group_rows(128, 32) == 32 and group_rows(8, 32) == 8
+    assert group_rows(12, 512) == 2 and group_rows(7, 2048) == 1
+    q = jnp.zeros((3, 4, 16))
+    kc = jnp.zeros((9, 4, 32))
+    tables, lens = jnp.zeros((3, 2), jnp.int32), jnp.ones((3,), jnp.int32)
+    with pytest.raises(ValueError, match="does not support"):
+        paged_attention_packed(q, kc, kc, tables, lens, 1.0, impl="flash")
+    before = attention_impl_traces()
+    paged_attention_packed(jnp.zeros((3, 8, 64)), jnp.zeros((9, 16, 128)),
+                           jnp.zeros((9, 16, 128)), tables, lens, 1.0)
+    after = attention_impl_traces()
+    assert after["paged_packed"] == before.get("paged_packed", 0) + 1
+    assert after.get("paged_packed_flash", 0) \
+        == before.get("paged_packed_flash", 0)
+
+
 def test_kernel_is_chosen_by_shape_and_backend():
     """``auto`` takes the portable path off the TPU; ``flash`` forced on
     a shape the kernel cannot tile says so instead of falling back."""
@@ -195,6 +321,35 @@ def test_engine_counts_attended_and_dense_pages():
     assert eng.steps == 2 + new - 1
     assert eng.metrics.attn_pages_read.value() == read
     assert eng.metrics.attn_pages_dense.value() == dense
+
+
+def test_engine_counts_the_pages_the_packed_kernel_fetches(monkeypatch):
+    """``attn_pages_fetched`` (the short-convolution family): a lane
+    fetches whole chunks of pages up to its own length; a prefill
+    chunk's rows share their request's pages, fetched once for each
+    group of rows up to the group's longest — here chunks of two 4-token
+    pages and groups of 4 of the chunk's 8 rows."""
+    from hadoop_tpu.models import lfm2
+    from hadoop_tpu.ops import paged_attention as mod
+    monkeypatch.setattr(mod, "CHUNK_TOKENS", 8)
+    cfg = get_config("tiny-lfm2")
+    monkeypatch.setattr(mod, "GROUP_QUERY_ROWS", 4 * cfg.n_heads)
+    bs, chunk, lanes, ctx = 4, 8, 2, 32
+    eng = DecodeEngine(lfm2.init_params(jax.random.PRNGKey(0), cfg), cfg,
+                       max_batch=lanes, block_size=bs, max_context=ctx,
+                       prefill_chunk=chunk, metrics=ServingMetrics())
+    prompt, new = list(range(1, 11)), 4     # 10 tokens: chunks of 8 + 2
+    eng.generate([prompt], SamplingParams(max_new_tokens=new))
+    fetched = lambda n: -(-n // 8) * 2      # whole chunks of 2 pages
+    # chunk 1: rows 1..4 | 5..8; chunk 2: rows 9, 10 | none live; then
+    # the lane at positions 10..12
+    want = fetched(4) + fetched(8) + fetched(10)
+    want += sum(fetched(len(prompt) + i + 1) for i in range(new - 1))
+    assert eng.steps == 2 + new - 1
+    assert eng.metrics.attn_pages_fetched.value() == want == 20
+    read = sum(-(-(p + 1) // bs) for p in range(len(prompt)))
+    assert eng.metrics.attn_pages_read.value() \
+        == read + sum(-(-(len(prompt) + i + 1) // bs) for i in range(new - 1))
 
 
 # ------------------------------------------- the chip's compiler, no chip
@@ -265,7 +420,7 @@ def test_packed_pages_compile_for_v5e_with_no_pool_copied(one_chip, rows):
 
     pool = sds((2 * 10240, 16, 512), bf16)
     fn = jax.jit(lambda q, kc, vc, tables, lens: paged_attention_packed(
-        q, kc, vc, tables, lens, 64 ** -0.5))
+        q, kc, vc, tables, lens, 64 ** -0.5, impl="ref"))
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         compiled = fn.lower(sds((rows, 32, 64), bf16), pool, pool,
@@ -276,6 +431,36 @@ def test_packed_pages_compile_for_v5e_with_no_pool_copied(one_chip, rows):
         compilation_cache.reset_cache()
     # a pool is 336 MB: what is made is a chunk's gathered pages
     assert compiled.memory_analysis().temp_size_in_bytes < 300 << 20
+
+
+@pytest.mark.parametrize("rows", [64, 192])
+def test_packed_kernel_compiles_for_v5e_with_no_context_gathered(one_chip,
+                                                                 rows):
+    """The packed kernel at the agent cell's widths (the pool of the test
+    above, context 8192): 64 lanes, and 64 lanes + a 128-row chunk split
+    at the lanes. The pool is read where it lies, page by page into
+    VMEM: the temporaries hold no gathered context (a chunk of 256
+    tokens for 192 rows is 100 MB)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    bf16 = jnp.bfloat16
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds((2 * 10240, 16, 512), bf16)
+    fn = jax.jit(lambda q, kc, vc, tables, lens: paged_attention_packed(
+        q, kc, vc, tables, lens, 64 ** -0.5, lanes=64, impl="flash"))
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = fn.lower(sds((rows, 32, 64), bf16), pool, pool,
+                            sds((rows, 512), jnp.int32),
+                            sds((rows,), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 1 + (rows > 64)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
 
 
 # the two dense paged cells' layers at their published widths, two layers
